@@ -820,11 +820,13 @@ def _recovery_point(
 def _served_chaos_point(
     chaos_requests: int = 24, overload_requests: int = 24
 ) -> None:
-    """End-to-end front-door trajectory point: spawn the real ``--mode serve``
-    subprocess with network chaos armed on both sides plus one injected
-    flusher crash, then drive it with the retrying client fleet.
+    """End-to-end front-door trajectory point: the real ``--mode serve``
+    front door, built from its own flags and served in this process, with
+    network chaos armed on both sides plus one injected flusher crash, then
+    driven with the retrying client fleet.  One process holds the device
+    for the server and the fleet alike.
 
-    Two runs share one server (amortizing jax startup):
+    Two runs share one server (amortizing its warmup):
 
     * ``served_chaos`` — conn drops, truncated frames, stalled reads, and a
       one-shot device failure mid-run; the exactly-once guarantee
@@ -836,30 +838,34 @@ def _served_chaos_point(
     """
     import asyncio
 
-    from repro.launch.client import (
-        FleetConfig, run_fleet, spawn_server, stop_server,
-    )
+    from repro.launch.client import FleetConfig, run_fleet
+    from repro.launch.serve import parse_args
+    from repro.launch.server import build_front, close_front, make_server
     from repro.runtime.resilience import FailureInjector
 
-    proc, port = spawn_server([
+    args = parse_args([
+        "--mode", "serve",
         "--channels", "2", "--out-channels", "4", "--image-size", "6",
         "--kappa", "2", "--tenants", "3", "--warm-batch", "4",
         "--max-pending-rows", "48", "--max-delay-ms", "5",
         "--chaos", "--chaos-rate", "0.1", "--chaos-seed", "7",
         "--inject-failure", "device",
     ])
-    try:
+    front = build_front(args)
+
+    async def fleets(server) -> None:
+        port = server.port
         chaos = FailureInjector(
             network_phases={"write", "read", "stall"},
             network_rate=0.1, stall_ms=50.0, seed=11,
         )
         t0 = time.perf_counter()
-        rep = asyncio.run(run_fleet(FleetConfig(
+        rep = await run_fleet(FleetConfig(
             port=port, requests=chaos_requests, clients=4, tenants=3,
             batch=2, channels=2, image_size=6, trace="uniform:300",
             timeout_ms=30000.0, attempt_timeout_ms=1500.0, max_attempts=8,
             seed=3, fleet_id="bench-chaos", chaos=chaos,
-        )))
+        ))
         dt = time.perf_counter() - t0
         rep.assert_exactly_once()
         ok = rep.counts().get("ok", 0)
@@ -874,7 +880,7 @@ def _served_chaos_point(
             f"hedges={rep.hedges} drops={rep.conn_drops} exactly_once",
         )
 
-        rep2 = asyncio.run(run_fleet(FleetConfig(
+        rep2 = await run_fleet(FleetConfig(
             port=port, requests=overload_requests, clients=8, tenants=3,
             batch=4, channels=2, image_size=6,
             trace=f"burst:{overload_requests}@1",
@@ -884,7 +890,7 @@ def _served_chaos_point(
             # still resolves on the first OVERLOADED frame regardless.
             timeout_ms=30000.0, attempt_timeout_ms=2000.0, max_attempts=4,
             seed=5, fleet_id="bench-over",
-        )))
+        ))
         rep2.assert_exactly_once()
         shed = rep2.counts().get("rejected:OVERLOADED", 0)
         ok2 = rep2.counts().get("ok", 0)
@@ -899,9 +905,20 @@ def _served_chaos_point(
             (p99 if ok2 else 0.0) * 1e3,
             f"ok={ok2} shed={shed} typed_rejections p99_bounded",
         )
-    finally:
-        rc = stop_server(proc)
-        assert rc == 0, f"server exited {rc} after SIGTERM (drain lost rids?)"
+
+    async def serve() -> int:
+        server = make_server(front, args)
+        await server.start()
+        try:
+            await fleets(server)
+        finally:
+            lost = await server.drain_and_stop(
+                timeout=args.drain_timeout_ms / 1e3
+            )
+        return lost
+
+    rc = close_front(front, asyncio.run(serve()))
+    assert rc == 0, f"server exited {rc} after the drain (lost rids?)"
 
 
 def run() -> None:

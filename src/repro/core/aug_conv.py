@@ -82,11 +82,13 @@ def build_aug_conv(
     C = conv_as_matrix(kernels, geom).astype(np.float64)
 
     # Blockwise M^{-1} @ C  — M^{-1} is block-diag(inv core, ... kappa times).
+    # np.matmul runs on BLAS; np.einsum's own loops take minutes at the
+    # paper's geometry (a 3072x3072 core against 65,536 columns).
     q = core.q
     blocks = C.reshape(core.kappa, q, geom.out_features)
-    fused = np.einsum(
-        "ij,kjl->kil", core.inverse.astype(np.float64), blocks
-    ).reshape(geom.in_features, geom.out_features)
+    fused = np.matmul(core.inverse.astype(np.float64), blocks).reshape(
+        geom.in_features, geom.out_features
+    )
 
     if isinstance(perm_seed, np.ndarray):
         perm = perm_seed
@@ -105,4 +107,6 @@ def apply_aug_conv(tr: jax.Array, aug: AugConv | jax.Array) -> jax.Array:
     ``repro.kernels.aug_gemm`` implements as a Pallas TPU kernel.
     """
     mat = aug.matrix if isinstance(aug, AugConv) else aug
-    return tr @ jnp.asarray(mat, tr.dtype)
+    # HIGHEST: on a TPU, XLA's default rounds fp32 operands to bf16.
+    return jnp.matmul(tr, jnp.asarray(mat, tr.dtype),
+                      precision=jax.lax.Precision.HIGHEST)

@@ -7,8 +7,8 @@ morphed data is ``T^r = D^r @ M``.
 
 We never materialize ``M``: because the same core repeats along the diagonal,
 ``D^r @ M`` is exactly ``reshape(D^r, (kappa, q)) @ M'`` — a *repeated
-block-diagonal GEMM*.  That identity is the provider-side compute hot-spot and
-is what `repro.kernels.block_diag` implements as a Pallas TPU kernel; this
+block-diagonal GEMM*.  That identity is the provider-side compute hot-spot;
+`repro.kernels.ops.morph_rows` runs it through the Pallas GEMM kernel.  This
 module is the reference/pure-jnp path and also owns core generation.
 
 Core generation modes:
@@ -115,7 +115,9 @@ def morph(xr: jax.Array, core: MorphCore | jax.Array, kappa: int | None = None) 
     q = mat.shape[0]
     lead = xr.shape[:-1]
     blocks = xr.reshape(*lead, k, q)
-    out = jnp.einsum("...kq,qr->...kr", blocks, jnp.asarray(mat, xr.dtype))
+    # HIGHEST: on a TPU, XLA's default rounds fp32 operands to bf16.
+    out = jnp.einsum("...kq,qr->...kr", blocks, jnp.asarray(mat, xr.dtype),
+                     precision=jax.lax.Precision.HIGHEST)
     return out.reshape(*lead, k * q)
 
 

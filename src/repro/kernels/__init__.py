@@ -1,14 +1,15 @@
 """Pallas TPU kernels for MoLe's compute hot-spots (validated interpret=True).
 
-  block_diag  — provider-side morphing: repeated-block-diagonal GEMM (eq. 2-4)
-  aug_gemm    — developer-side Aug-Conv forward: T @ C^{ac} (eq. 5)
+  aug_gemm    — tiled GEMM: developer-side Aug-Conv forward T @ C^{ac}
+                (eq. 5), and provider-side morphing (eq. 2-4) on rows cut
+                to the core width
   grouped     — slot-indexed grouped GEMMs: the gather-free delivery hot path
                 (per-tenant secrets read in place from the stacked slot table
                 via scalar-prefetched index maps)
   wkv6        — chunked RWKV-6 linear-attention scan (rwkv6_3b long-context)
 
 Each kernel has a pure-jnp oracle in ``ref.py``; ``ops.py`` holds the jit'd
-public wrappers with reference fallback for non-tileable shapes.
+public wrappers and the backend dispatch.
 """
 from .dispatch import BACKENDS, resolve_backend
 from .ops import (

@@ -3,6 +3,8 @@
 This is the dense matmul the developer runs every forward step after MoLe
 replaces the first conv layer (paper §3.3 / eq. 5): morphed rows
 ``T (B, alpha m^2)`` against the fused matrix ``C^{ac} (alpha m^2, beta n^2)``.
+The provider-side morph runs through it too (``ops.morph_rows``), on rows cut
+to the width of the secret core.
 
 TPU mapping: classic three-level tiling, MXU-aligned blocks, fp32 VMEM
 accumulator, contraction axis innermost (sequential) so each output tile is
@@ -14,12 +16,24 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+
+def mxu_dot(a, b):
+    """One MXU tile product, accumulated in fp32.
+
+    fp32 operands contract at full fp32 precision (``HIGHEST``): left at
+    the default, Mosaic may round them to bf16 on the MXU, and delivered
+    features must match a float32 convolution (paper eq. 5).  Mosaic
+    refuses that precision for bf16 operands, whose products are exact in
+    the fp32 accumulator anyway.
+    """
+    precision = lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jnp.dot(
+        a, b, preferred_element_type=jnp.float32, precision=precision
+    )
 
 
 def _kernel(t_ref, c_ref, o_ref, acc_ref, *, n_kk: int):
@@ -29,9 +43,7 @@ def _kernel(t_ref, c_ref, o_ref, acc_ref, *, n_kk: int):
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        t_ref[...], c_ref[...], preferred_element_type=jnp.float32
-    )
+    acc_ref[...] += mxu_dot(t_ref[...], c_ref[...])
 
     @pl.when(kk == n_kk - 1)
     def _():
@@ -45,7 +57,7 @@ def aug_gemm(
     bm: int = 128,
     bn: int = 128,
     bk: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     B, K = t.shape
     K2, N = c_ac.shape
@@ -53,15 +65,6 @@ def aug_gemm(
     bm, bn, bk = min(bm, B), min(bn, N), min(bk, K)
     assert B % bm == 0 and N % bn == 0 and K % bk == 0, (B, bm, N, bn, K, bk)
     n_kk = K // bk
-
-    kwargs = {}
-    if pltpu is not None:
-        from .dispatch import tpu_compiler_params
-
-        kwargs["scratch_shapes"] = [pltpu.VMEM((bm, bn), jnp.float32)]
-        cp = tpu_compiler_params(("parallel", "parallel", "arbitrary"))
-        if cp is not None:
-            kwargs["compiler_params"] = cp
 
     return pl.pallas_call(
         functools.partial(_kernel, n_kk=n_kk),
@@ -72,6 +75,9 @@ def aug_gemm(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, N), t.dtype),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
     )(t, c_ac)

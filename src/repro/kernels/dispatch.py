@@ -6,18 +6,12 @@ Three backends implement the same math for every public kernel entry point:
   ``interpret``  Pallas kernels in interpret mode — CPU validation of the
                  kernel bodies themselves (slow: the grid runs in Python).
   ``jnp``        pure-jnp reference (``kernels.ref``) — XLA-fused; the fast
-                 correct path on CPU and the fallback for non-tileable shapes.
+                 correct path on CPU.
 
 Resolution order for ``resolve_backend(None)``:
 
   1. ``REPRO_KERNEL_BACKEND`` env var if set to one of the names above;
-  2. legacy ``REPRO_PALLAS_INTERPRET=0`` → ``pallas`` (kept so existing TPU
-     launch scripts don't break);
-  3. auto: ``pallas`` when a TPU backend is active, else ``jnp``.
-
-This replaces the old hard-coded ``interpret=True`` default: on CPU the hot
-path now runs the XLA reference instead of interpreting the kernel grid in
-Python, and on TPU it compiles to Mosaic without any env flag.
+  2. auto: ``pallas`` when a TPU backend is active, else ``jnp``.
 """
 from __future__ import annotations
 
@@ -25,28 +19,7 @@ import os
 
 import jax
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
 BACKENDS = ("pallas", "interpret", "jnp")
-
-
-def tpu_compiler_params(dimension_semantics: tuple[str, ...]):
-    """Version-portable ``pltpu.(TPU)CompilerParams`` construction.
-
-    The class was renamed ``TPUCompilerParams`` -> ``CompilerParams`` across
-    jax releases; returns None when the pallas TPU extension is unavailable.
-    """
-    if pltpu is None:
-        return None
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if cls is None:  # pragma: no cover
-        return None
-    return cls(dimension_semantics=dimension_semantics)
 
 
 def resolve_backend(backend: str | None = None) -> str:
@@ -59,8 +32,6 @@ def resolve_backend(backend: str | None = None) -> str:
         raise ValueError(
             f"unknown kernel backend {backend!r}; expected one of {BACKENDS} or 'auto'"
         )
-    if os.environ.get("REPRO_PALLAS_INTERPRET") == "0":
-        return "pallas"
     return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 

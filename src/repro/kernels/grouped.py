@@ -2,8 +2,8 @@
 
 The engine's microbatch carries a ``(G,)`` vector of *slot indices* into the
 registry's stacked per-tenant secrets (``cores (S, q, q)``, ``augs
-(S, K, N)``).  The batched kernels in ``block_diag.py`` / ``aug_gemm.py``
-need the per-group secrets materialized as ``(G, ...)`` arrays first — an
+(S, K, N)``).  The batched GEMM in ``aug_gemm.py`` (vmapped over groups)
+needs the per-group secrets materialized as ``(G, ...)`` arrays first — an
 HBM gather copy that ROADMAP measured as the difference between 0.8x and
 4.9x vs per-request delivery at 16 tenants whenever ``gidx != arange(S)``.
 
@@ -16,10 +16,10 @@ all cost the same as the identity; monotone indices (the queue slot-sorts
 microbatches) additionally let Mosaic reuse a resident tile when adjacent
 groups share a slot.
 
-Grid layout mirrors the unbatched kernels with a leading group dimension:
-
-  * ``grouped_block_diag_matmul``: grid (G, B/bm, kappa, q/bn, q/bk)
-  * ``grouped_aug_gemm``:          grid (G, B/bm, N/bn, K/bk)
+``grouped_aug_gemm`` serves both delivery GEMMs: Aug-Conv on ``(G, B, K)``
+rows, and the morph on rows cut to the core width, ``(G, B * kappa, q)``
+(``ops.morph_rows_grouped``).  Its grid mirrors the unbatched kernel with a
+leading group dimension: (G, B/bm, N/bn, K/bk).
 
 The contraction axis stays innermost ("arbitrary"), accumulated in an fp32
 VMEM scratch; the group axis is "arbitrary" too because its block mapping
@@ -32,109 +32,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific helpers are import-safe on CPU
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from .aug_gemm import mxu_dot
 
 __all__ = [
-    "grouped_block_diag_matmul",
     "grouped_aug_gemm",
     "grouped_row_gemm",
 ]
-
-
-def _require_pltpu():
-    if pltpu is None:  # pragma: no cover - CPU containers ship pallas.tpu
-        raise RuntimeError(
-            "grouped kernels need jax.experimental.pallas.tpu "
-            "(scalar prefetch); use the jnp reference backend instead"
-        )
-
-
-def _grid_kwargs(dimension_semantics: tuple[str, ...]) -> dict:
-    from .dispatch import tpu_compiler_params
-
-    cp = tpu_compiler_params(dimension_semantics)
-    return {} if cp is None else {"compiler_params": cp}
-
-
-def _bd_kernel(gidx_ref, x_ref, m_ref, o_ref, acc_ref, *, n_kk: int):
-    del gidx_ref  # consumed by the index_maps, not the body
-    kk = pl.program_id(4)
-
-    @pl.when(kk == 0)
-    def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += jnp.dot(
-        x_ref[0], m_ref[0], preferred_element_type=jnp.float32
-    )
-
-    @pl.when(kk == n_kk - 1)
-    def _():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
-
-
-def grouped_block_diag_matmul(
-    x: jax.Array,        # (G, B, F) with F = kappa * q
-    gidx: jax.Array,     # (G,) int32 slot index per group
-    cores: jax.Array,    # (S, q, q) stacked per-slot morph cores
-    kappa: int,
-    *,
-    bm: int = 128,
-    bn: int = 128,
-    bk: int = 128,
-    interpret: bool = True,
-) -> jax.Array:
-    """Per-group repeated-block-diagonal morph, secrets read in place.
-
-    ``y[g] = reshape(x[g], (B, kappa, q)) @ cores[gidx[g]]`` — the grouped
-    twin of ``block_diag.block_diag_matmul``, with the core tile of slot
-    ``gidx[g]`` DMA'd straight out of the ``(S, q, q)`` stack.
-    """
-    _require_pltpu()
-    G, B, F = x.shape
-    q = cores.shape[-1]
-    assert F == kappa * q, (F, kappa, q)
-    bm = min(bm, B)
-    bn = min(bn, q)
-    bk = min(bk, q)
-    assert B % bm == 0 and q % bn == 0 and q % bk == 0, (B, bm, q, bn, bk)
-    n_kk = q // bk
-
-    grid = (G, B // bm, kappa, q // bn, n_kk)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            # x viewed as (G, B, kappa*q): column block k*q + kk*bk.
-            pl.BlockSpec(
-                (1, bm, bk),
-                lambda g, i, k, j, kk, gidx_ref: (g, i, k * n_kk + kk),
-            ),
-            # The gather-free read: block row = this group's slot.
-            pl.BlockSpec(
-                (1, bk, bn),
-                lambda g, i, k, j, kk, gidx_ref: (gidx_ref[g], kk, j),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, bm, bn),
-            lambda g, i, k, j, kk, gidx_ref: (g, i, k * (q // bn) + j),
-        ),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_bd_kernel, n_kk=n_kk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((G, B, F), x.dtype),
-        interpret=interpret,
-        **_grid_kwargs(
-            ("arbitrary", "parallel", "parallel", "parallel", "arbitrary")
-        ),
-    )(gidx, x, cores)
 
 
 def _aug_kernel(gidx_ref, t_ref, c_ref, o_ref, acc_ref, *, n_kk: int):
@@ -145,9 +50,7 @@ def _aug_kernel(gidx_ref, t_ref, c_ref, o_ref, acc_ref, *, n_kk: int):
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jnp.dot(
-        t_ref[0], c_ref[0], preferred_element_type=jnp.float32
-    )
+    acc_ref[...] += mxu_dot(t_ref[0], c_ref[0])
 
     @pl.when(kk == n_kk - 1)
     def _():
@@ -162,14 +65,13 @@ def grouped_aug_gemm(
     bm: int = 128,
     bn: int = 128,
     bk: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Per-group Aug-Conv forward ``t[g] @ c_acs[gidx[g]]``, secrets in place.
 
     The grouped twin of ``aug_gemm.aug_gemm`` — this is the GEMM whose
     ``(G, K, N)`` weight gather dominated the non-identity delivery path.
     """
-    _require_pltpu()
     G, B, K = t.shape
     N = c_acs.shape[-1]
     assert c_acs.shape[1] == K, (t.shape, c_acs.shape)
@@ -199,7 +101,9 @@ def grouped_aug_gemm(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, B, N), t.dtype),
         interpret=interpret,
-        **_grid_kwargs(("arbitrary", "parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "parallel", "parallel", "arbitrary")
+        ),
     )(gidx, t, c_acs)
 
 
@@ -210,7 +114,7 @@ def grouped_row_gemm(
     *,
     bn: int = 128,
     bk: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Decode-shaped grouped GEMM: ``h[r] @ tables[gidx[r]]`` -> (R, N).
 

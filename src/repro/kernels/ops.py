@@ -1,19 +1,24 @@
 """jit'd public wrappers for the Pallas kernels, with backend dispatch.
 
-Backend selection (see :mod:`repro.kernels.dispatch`) replaces the old
-hard-coded ``interpret=True``:
+Backend selection (see :mod:`repro.kernels.dispatch`):
 
   * ``pallas``     — compiled Pallas (real TPU),
   * ``interpret``  — Pallas interpret mode (CPU kernel validation),
   * ``jnp``        — pure-jnp reference (``ref.py``; the fast CPU path).
 
 ``backend=None`` resolves via ``REPRO_KERNEL_BACKEND`` / hardware auto-detect.
-Shapes that don't satisfy the kernels' tiling constraints fall back to the
-reference on any backend (same math, XLA-fused) so the public API is total.
+On the two Pallas backends every GEMM entry point runs its kernel, never the
+reference, at every width: the row axis is zero-padded up to the kernels'
+row tile (and the padding sliced off the result), and a weight axis off the
+128-lane grid is one whole-axis block.
 
-The ``*_batched`` entry points are the delivery-engine hot path: a leading
-*group* axis carries per-tenant secrets (one morph core / one Aug-Conv matrix
-per group), executed as a single fused batched GEMM.
+The morph ``x @ blockdiag(core, ..., core)`` (paper eq. 2-4) runs as the
+same GEMM kernel as Aug-Conv: cut each row of ``kappa * q`` features into
+``kappa`` rows of ``q`` and multiply them by the ``q x q`` core.
+
+The ``*_grouped`` entry points are the delivery-engine hot path: a leading
+*group* axis carries per-tenant secrets read in place from a stacked slot
+table (one morph core / one Aug-Conv matrix per slot).
 """
 from __future__ import annotations
 
@@ -24,13 +29,8 @@ import jax.numpy as jnp
 
 from . import ref
 from .aug_gemm import aug_gemm
-from .block_diag import block_diag_matmul
 from .dispatch import pallas_interpret, resolve_backend
-from .grouped import (
-    grouped_aug_gemm,
-    grouped_block_diag_matmul,
-    grouped_row_gemm,
-)
+from .grouped import grouped_aug_gemm, grouped_row_gemm
 
 __all__ = [
     "morph_rows",
@@ -47,15 +47,42 @@ __all__ = [
     "lm_head_rows_grouped",
 ]
 
+# Mosaic's block rule: a block's last axis is a multiple of the 128-lane
+# vreg width or spans the whole array axis; its second-to-last axis is a
+# multiple of the 8-row sublane tile or spans the whole axis.
+_LANE, _SUBLANE = 128, 8
 
-def _morph_tileable(R: int, q: int) -> bool:
-    """Conservative tiling check for ``block_diag_matmul``.
 
-    ``R % 8`` keeps row tiles MXU-aligned (bm = min(128, R) would otherwise
-    accept any R < 128, handing Mosaic a misaligned tile on real TPU).
+def _lane_tile(dim: int, cap: int) -> int:
+    """Block extent along a weight axis of ``dim`` elements: the largest
+    multiple of 128 up to ``cap`` dividing ``dim``, else the whole axis."""
+    for t in range(min(cap, dim) // _LANE * _LANE, 0, -_LANE):
+        if dim % t == 0:
+            return t
+    return dim
+
+
+def _gemm_tiles(K: int, N: int) -> tuple[int, int]:
+    """(bn, bk) for a ``(rows, K) @ (K, N)`` GEMM: both weight axes are
+    whole array axes, so every width has a legal block."""
+    return _lane_tile(N, 128), _lane_tile(K, 512)
+
+
+def _pad_rows(x: jax.Array, axis: int) -> tuple[jax.Array, int, int]:
+    """Zero-pad the row axis up to the kernels' row tile.
+
+    Returns ``(padded, rows, bm)``: rows round up to the 8-row sublane tile,
+    and past 128 to a multiple of the 128-row block.  Padding rows are zero,
+    so they produce zero rows that the caller slices off.
     """
-    bm, bn = min(128, R), min(128, q)
-    return R >= 8 and R % 8 == 0 and R % bm == 0 and q % bn == 0
+    rows = x.shape[axis]
+    bm = min(128, -(-rows // _SUBLANE) * _SUBLANE)
+    padded = -(-rows // bm) * bm
+    if padded != rows:
+        widths = [(0, 0)] * x.ndim
+        widths[axis] = (0, padded - rows)
+        x = jnp.pad(x, widths)
+    return x, rows, bm
 
 
 def morph_rows(
@@ -67,14 +94,10 @@ def morph_rows(
 
 @partial(jax.jit, static_argnames=("kappa", "backend"))
 def _morph_rows(x, core, kappa, backend):
-    R, _ = x.shape
+    if backend == "jnp":
+        return ref.block_diag_matmul_ref(x, core, kappa)
     q = core.shape[0]
-    if backend != "jnp" and _morph_tileable(R, q):
-        return block_diag_matmul(
-            x, core, kappa, bm=min(128, R), bn=min(128, q), bk=min(128, q),
-            interpret=pallas_interpret(backend),
-        )
-    return ref.block_diag_matmul_ref(x, core, kappa)
+    return _gemm(x.reshape(-1, q), core, backend).reshape(x.shape)
 
 
 def aug_conv_forward(
@@ -86,14 +109,18 @@ def aug_conv_forward(
 
 @partial(jax.jit, static_argnames=("backend",))
 def _aug_conv_forward(t, c_ac, backend):
-    B, K = t.shape
-    N = c_ac.shape[1]
-    bm, bn, bk = min(128, B), min(128, N), min(512, K)
-    if backend != "jnp" and B % bm == 0 and N % bn == 0 and K % bk == 0:
-        return aug_gemm(
-            t, c_ac, bm=bm, bn=bn, bk=bk, interpret=pallas_interpret(backend)
-        )
-    return ref.aug_gemm_ref(t, c_ac)
+    if backend == "jnp":
+        return ref.aug_gemm_ref(t, c_ac)
+    return _gemm(t, c_ac, backend)
+
+
+def _gemm(t, w, backend):
+    """``t (B, K) @ w (K, N)`` through the Pallas GEMM kernel."""
+    bn, bk = _gemm_tiles(*w.shape)
+    t, B, bm = _pad_rows(t, 0)
+    return aug_gemm(
+        t, w, bm=bm, bn=bn, bk=bk, interpret=pallas_interpret(backend)
+    )[:B]
 
 
 def morph_rows_batched(
@@ -102,25 +129,19 @@ def morph_rows_batched(
     """Per-group morphing: x (G, B, kappa*q) with cores (G, q, q).
 
     Each group carries one tenant's secret core; Pallas backends vmap the
-    single-core kernel over the group axis so the core tile still stays
-    VMEM-resident per grid instance.
+    GEMM kernel over the group axis.
     """
     return _morph_rows_batched(x, cores, int(kappa), resolve_backend(backend))
 
 
 @partial(jax.jit, static_argnames=("kappa", "backend"))
 def _morph_rows_batched(x, cores, kappa, backend):
-    G, B, F = x.shape
-    q = cores.shape[-1]
-    if backend != "jnp" and _morph_tileable(B, q):
-        interp = pallas_interpret(backend)
-        return jax.vmap(
-            lambda xg, cg: block_diag_matmul(
-                xg, cg, kappa, bm=min(128, B), bn=min(128, q), bk=min(128, q),
-                interpret=interp,
-            )
-        )(x, cores)
-    return ref.block_diag_matmul_batched_ref(x, cores, kappa)
+    if backend == "jnp":
+        return ref.block_diag_matmul_batched_ref(x, cores, kappa)
+    G, q = x.shape[0], cores.shape[-1]
+    return jax.vmap(partial(_gemm, backend=backend))(
+        x.reshape(G, -1, q), cores
+    ).reshape(x.shape)
 
 
 def aug_conv_forward_batched(
@@ -132,15 +153,9 @@ def aug_conv_forward_batched(
 
 @partial(jax.jit, static_argnames=("backend",))
 def _aug_conv_forward_batched(t, c_acs, backend):
-    G, B, K = t.shape
-    N = c_acs.shape[-1]
-    bm, bn, bk = min(128, B), min(128, N), min(512, K)
-    if backend != "jnp" and B % bm == 0 and N % bn == 0 and K % bk == 0:
-        interp = pallas_interpret(backend)
-        return jax.vmap(
-            lambda tg, cg: aug_gemm(tg, cg, bm=bm, bn=bn, bk=bk, interpret=interp)
-        )(t, c_acs)
-    return ref.aug_gemm_batched_ref(t, c_acs)
+    if backend == "jnp":
+        return ref.aug_gemm_batched_ref(t, c_acs)
+    return jax.vmap(partial(_gemm, backend=backend))(t, c_acs)
 
 
 def _safe_gidx(gidx: jax.Array, n_slots: int) -> jax.Array:
@@ -198,15 +213,12 @@ def morph_rows_grouped(
 
 @partial(jax.jit, static_argnames=("kappa", "backend"))
 def _morph_rows_grouped(x, gidx, cores, kappa, backend):
-    G, B, F = x.shape
-    q = cores.shape[-1]
     gidx = _safe_gidx(gidx, cores.shape[0])
-    if backend != "jnp" and _morph_tileable(B, q):
-        return grouped_block_diag_matmul(
-            x, gidx, cores, kappa,
-            bm=min(128, B), bn=min(128, q), bk=min(128, q),
-            interpret=pallas_interpret(backend),
-        )
+    if backend != "jnp":
+        G, q = x.shape[0], cores.shape[-1]
+        return _grouped_gemm(
+            x.reshape(G, -1, q), gidx, cores, backend
+        ).reshape(x.shape)
     return _with_arange_fast_case(
         gidx, cores.shape[0],
         lambda x_, g_: ref.block_diag_matmul_batched_ref(x_, cores, kappa),
@@ -230,21 +242,25 @@ def aug_conv_forward_grouped(
 
 @partial(jax.jit, static_argnames=("backend",))
 def _aug_conv_forward_grouped(t, gidx, c_acs, backend):
-    G, B, K = t.shape
-    N = c_acs.shape[-1]
     gidx = _safe_gidx(gidx, c_acs.shape[0])
-    bm, bn, bk = min(128, B), min(128, N), min(512, K)
-    if backend != "jnp" and B % bm == 0 and N % bn == 0 and K % bk == 0:
-        return grouped_aug_gemm(
-            t, gidx, c_acs, bm=bm, bn=bn, bk=bk,
-            interpret=pallas_interpret(backend),
-        )
+    if backend != "jnp":
+        return _grouped_gemm(t, gidx, c_acs, backend)
     return _with_arange_fast_case(
         gidx, c_acs.shape[0],
         lambda t_, g_: ref.aug_gemm_batched_ref(t_, c_acs),
         lambda t_, g_: ref.aug_gemm_grouped_ref(t_, g_, c_acs),
         t, gidx,
     )
+
+
+def _grouped_gemm(t, gidx, ws, backend):
+    """``t[g] (B, K) @ ws[gidx[g]] (K, N)`` through the grouped Pallas
+    kernel, the stacked weights read in place."""
+    bn, bk = _gemm_tiles(*ws.shape[1:])
+    t, B, bm = _pad_rows(t, 1)
+    return grouped_aug_gemm(
+        t, gidx, ws, bm=bm, bn=bn, bk=bk, interpret=pallas_interpret(backend),
+    )[:, :B]
 
 
 def token_morph_batched(
@@ -374,11 +390,9 @@ def lm_head_rows_grouped(
 
 @partial(jax.jit, static_argnames=("backend",))
 def _lm_head_rows_grouped(h, gidx, heads, backend):
-    R, K = h.shape
-    N = heads.shape[-1]
     gidx = _safe_gidx(gidx, heads.shape[0])
-    bn, bk = min(128, N), min(512, K)
-    if backend != "jnp" and N % bn == 0 and K % bk == 0:
+    if backend != "jnp":
+        bn, bk = _gemm_tiles(*heads.shape[1:])
         return grouped_row_gemm(
             h, gidx, heads, bn=bn, bk=bk,
             interpret=pallas_interpret(backend),

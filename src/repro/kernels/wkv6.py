@@ -21,11 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sf_ref,
@@ -70,7 +66,7 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sf_ref,
 
 def wkv6_chunked(
     r: jax.Array, k: jax.Array, v: jax.Array, logw: jax.Array,
-    u: jax.Array, s0: jax.Array, *, chunk: int = 32, interpret: bool = True,
+    u: jax.Array, s0: jax.Array, *, chunk: int = 32, interpret: bool,
 ) -> tuple[jax.Array, jax.Array]:
     """r/k/v/logw: (BH, T, D); u: (BH, D); s0: (BH, D, D).
 
@@ -85,17 +81,6 @@ def wkv6_chunked(
     vec = pl.BlockSpec((1, D), lambda bh, t: (bh, 0))
     mat = pl.BlockSpec((1, D, D), lambda bh, t: (bh, 0, 0))
 
-    kwargs = {}
-    if pltpu is not None:
-        from .dispatch import tpu_compiler_params
-
-        cp = tpu_compiler_params(("parallel", "arbitrary"))
-        if cp is not None:
-            kwargs["compiler_params"] = cp
-        kwargs["scratch_shapes"] = [pltpu.VMEM((D, D), jnp.float32)]
-    else:  # pragma: no cover
-        raise RuntimeError("pallas tpu backend unavailable")
-
     out, s_fin = pl.pallas_call(
         functools.partial(_kernel, n_t=n_t, L=L, D=D),
         grid=(BH, n_t),
@@ -105,7 +90,10 @@ def wkv6_chunked(
             jax.ShapeDtypeStruct((BH, T, D), r.dtype),
             jax.ShapeDtypeStruct((BH, D, D), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
         interpret=interpret,
-        **kwargs,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
     )(r, k, v, logw, u, s0)
     return out, s_fin

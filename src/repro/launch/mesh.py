@@ -22,15 +22,3 @@ def make_debug_mesh(n_data: int = 2, n_model: int = 2, *, pods: int | None = Non
 def single_device_mesh():
     """1x1 mesh — lets every PartitionSpec validate without extra devices."""
     return jax.make_mesh((1, 1), ("data", "model"))
-
-
-def mesh_context(mesh):
-    """Version-portable "make ``mesh`` ambient" context manager.
-
-    ``jax.set_mesh`` where it exists (jax >= 0.5); on older jax the legacy
-    ``with mesh:`` resource-env context, which ``sharding.hints`` reads back
-    via ``pxla.thread_resources``.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh

@@ -487,7 +487,10 @@ _ENGINE_FLAGS = (
 )
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and validate serve.py flags; every unset flag takes its mode's
+    default and ``args.mode`` is resolved.  ``build_front`` and in-process
+    servers take the result as is."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", default=None, choices=list(_MODES),
                     help="default: lm when --arch is given, else delivery; "
@@ -633,20 +636,28 @@ def main(argv=None):
         args.chaos_rate is not None or args.chaos_seed is not None
     ):
         ap.error("--chaos-rate/--chaos-seed require --chaos")
+    if mode == "lm" and args.arch is None:
+        ap.error("--arch is required with --mode lm")
     for dest, default, _ in _FLAGS.values():
         if getattr(args, dest) is None:
             setattr(args, dest, default)
+    args.mode = mode
+    return args
 
-    if mode == "serve":
+
+def main(argv=None):
+    args = parse_args(argv)
+    if args.mode == "serve":
         from repro.launch.server import run_serve
 
         return run_serve(args)
-    if mode == "delivery":
+    if args.mode == "delivery":
         return run_delivery(args)
-    if args.arch is None:
-        ap.error("--arch is required with --mode lm")
     return run_lm(args)
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

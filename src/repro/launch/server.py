@@ -66,7 +66,10 @@ from repro.runtime.async_engine import (
 )
 from repro.runtime.wire import ProtocolError
 
-__all__ = ["DeliveryServer", "run_serve"]
+__all__ = [
+    "DeliveryServer", "build_front", "close_front", "developer_kernels",
+    "make_server", "run_serve",
+]
 
 _log = logging.getLogger(__name__)
 
@@ -466,6 +469,18 @@ class DeliveryServer:
 # CLI driver (serve.py --mode serve)
 # ---------------------------------------------------------------------------
 
+def developer_kernels(geom, tenants: int, seed: int) -> list[np.ndarray]:
+    """Each served tenant's first-layer conv kernels ``(alpha, beta, p, p)``,
+    drawn from ``seed`` at a 1/sqrt(fan-in) scale, in tenant order."""
+    rng = np.random.default_rng(seed)
+    fan_in = geom.alpha * geom.p * geom.p
+    return [
+        rng.standard_normal((geom.alpha, geom.beta, geom.p, geom.p))
+        .astype(np.float32) / np.sqrt(fan_in)
+        for _ in range(tenants)
+    ]
+
+
 def build_front(args) -> AsyncDeliveryEngine:
     """Build registry + engine + async front door from serve.py flags:
     register ``--tenants`` vision tenants, warm the flush path so the first
@@ -478,20 +493,16 @@ def build_front(args) -> AsyncDeliveryEngine:
         DeliveryRequest, EngineStats, FailureInjector, MoLeDeliveryEngine,
     )
 
-    rng = np.random.default_rng(args.seed)
     geom = ConvGeometry(alpha=args.channels, beta=args.out_channels,
                         m=args.image_size, p=3)
     capacity = args.capacity if args.capacity is not None else args.tenants
     registry = SessionRegistry(geom, kappa=args.kappa, capacity=capacity)
-    fan_in = geom.alpha * geom.p * geom.p
     from repro.launch.serve import _weights_of
 
     weights = _weights_of(args, args.tenants)
-    for i in range(args.tenants):
-        kernels = rng.standard_normal(
-            (geom.alpha, geom.beta, geom.p, geom.p)
-        ).astype(np.float32) / np.sqrt(fan_in)
-        registry.register(f"tenant-{i}", kernels, weight=weights[i])
+    kernels = developer_kernels(geom, args.tenants, args.seed)
+    for i, k in enumerate(kernels):
+        registry.register(f"tenant-{i}", k, weight=weights[i])
 
     engine = MoLeDeliveryEngine(registry, backend=args.backend or None)
     # Warm the (G, B) buckets the fleet's steady state will hit, so served
@@ -552,11 +563,10 @@ def build_front(args) -> AsyncDeliveryEngine:
     return front
 
 
-def run_serve(args) -> dict:
-    """serve.py ``--mode serve``: build the front door, serve until
-    SIGTERM/SIGINT, drain gracefully, exit 0 with zero lost rids."""
-    front = build_front(args)
-    server = DeliveryServer(
+def make_server(front: AsyncDeliveryEngine, args) -> DeliveryServer:
+    """The ``DeliveryServer`` serve.py flags describe, over ``front`` (from
+    :func:`build_front`); not started."""
+    return DeliveryServer(
         front,
         host=args.host, port=args.port,
         max_pending_rows=args.max_pending_rows,
@@ -564,6 +574,13 @@ def run_serve(args) -> dict:
         write_timeout=args.write_timeout_ms / 1e3,
         injector=front.server_injector,
     )
+
+
+def run_serve(args) -> dict:
+    """serve.py ``--mode serve``: build the front door, serve until
+    SIGTERM/SIGINT, drain gracefully, exit 0 with zero lost rids."""
+    front = build_front(args)
+    server = make_server(front, args)
 
     async def _amain() -> int:
         await server.start()
@@ -579,8 +596,7 @@ def run_serve(args) -> dict:
 
     lost = asyncio.run(_amain())
     stats = front.engine.stats
-    with contextlib.suppress(EngineDeadError, TimeoutError):
-        front.close()
+    rc = close_front(front, lost)
     if args.stats:
         print("engine stats:")
         for line in stats.summary().splitlines():
@@ -588,6 +604,24 @@ def run_serve(args) -> dict:
     print(f"drained: lost_rids={lost} shed={stats.shed_requests} "
           f"expired={stats.expired_requests} reconnects={stats.reconnects} "
           f"duplicate_hits={stats.duplicate_hits}", flush=True)
-    if lost:
-        sys.exit(1)
+    if rc:
+        sys.exit(rc)
     return {"lost_rids": lost, "shed": stats.shed_requests}
+
+
+def close_front(front: AsyncDeliveryEngine, lost: int) -> int:
+    """Stop the front door after a drain; return the server's exit code.
+
+    Non-zero when the drain left rids unresolved, when the flusher died (its
+    in-flight requests were failed, not delivered), or when it would not
+    stop.
+    """
+    try:
+        front.close()
+    except TimeoutError as e:
+        print(f"flusher did not stop: {e}", flush=True)
+        return 1
+    if front.failure is not None:
+        print(f"delivery engine died: {front.failure!r}", flush=True)
+        return 1
+    return 1 if lost else 0

@@ -72,17 +72,8 @@ def compressed_psum(x: jax.Array, axis_name: str, mesh) -> jax.Array:
         )
 
     spec = P(*([None] * x.ndim))
-    # check_vma/check_rep=False: the all-gather+sum makes the result
-    # replicated over ``axis_name`` but the variance checker cannot infer
-    # that.  jax < 0.5 has neither jax.shard_map nor the check_vma spelling.
-    if hasattr(jax, "shard_map"):
-        smap = jax.shard_map(
-            inner, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
-        )
-    else:
-        from jax.experimental.shard_map import shard_map
-
-        smap = shard_map(
-            inner, mesh=mesh, in_specs=spec, out_specs=spec, check_rep=False
-        )
-    return smap(x)
+    # check_vma=False: the all-gather+sum makes the result replicated over
+    # ``axis_name`` but the variance checker cannot infer that.
+    return jax.shard_map(
+        inner, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
+    )(x)

@@ -407,6 +407,12 @@ class AsyncDeliveryEngine:
                     )
                 self._cv.wait(timeout=left)
 
+    @property
+    def failure(self) -> BaseException | None:
+        """The error that killed the flusher, or None while it is alive."""
+        with self._cv:
+            return self._dead
+
     def close(self, timeout: float | None = 30.0) -> None:
         """Drain pending work and stop the flusher (idempotent).
 

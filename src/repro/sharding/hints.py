@@ -14,24 +14,8 @@ from jax.sharding import PartitionSpec as P
 
 
 def _mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-    except AttributeError:
-        # jax < 0.5: no abstract-mesh API; fall back to the legacy
-        # ``with mesh:`` resource-env context (see launch.mesh.mesh_context).
-        try:
-            from jax.interpreters import pxla
-
-            m = pxla.thread_resources.env.physical_mesh
-            if m is None or m.empty:
-                return None
-        except Exception:  # pragma: no cover
-            return None
-    except Exception:  # pragma: no cover
-        return None
-    if m is None or not m.axis_names:
-        return None
-    return m
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def hint(x, *axes):
@@ -39,11 +23,7 @@ def hint(x, *axes):
     if m is None:
         return x
     names = set(m.axis_names)
-    # AbstractMesh exposes ``axis_sizes``; the legacy Mesh spells it ``shape``.
-    sizes = (
-        dict(zip(m.axis_names, m.axis_sizes))
-        if hasattr(m, "axis_sizes") else dict(m.shape)
-    )
+    sizes = dict(zip(m.axis_names, m.axis_sizes))
     parts = []
     for dim, a in zip(x.shape, axes):
         if a == "dp":

@@ -44,7 +44,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.configs import get_smoke_config
         from repro.models import Model
         from repro.launch.steps import TrainHParams, make_train_step
-        from repro.launch.mesh import make_debug_mesh, mesh_context
+        from repro.launch.mesh import make_debug_mesh
         from repro.optim import adamw
         from repro.sharding import rules as R
 
@@ -65,7 +65,7 @@ def test_sharded_train_step_matches_single_device():
         is_ax = lambda x: isinstance(x, tuple) and all(isinstance(e, (str, type(None))) for e in x)
         p_sh = jax.tree.map(lambda ax, ab: prules.sharding_for(ax, ab.shape),
                             model.axes(), model.abstract_params(), is_leaf=is_ax)
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             sp = jax.device_put(params, p_sh)
             sb = jax.device_put(batch, NamedSharding(mesh, P(("pod","data"), None)))
             out_p, out_o, out_m = jax.jit(step)(sp, opt, sb)
@@ -82,11 +82,11 @@ def test_sharded_train_step_matches_single_device():
 def test_compressed_psum_matches_psum():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np, json
-        from repro.launch.mesh import make_debug_mesh, mesh_context
+        from repro.launch.mesh import make_debug_mesh
         from repro.optim.compress import compressed_psum
         mesh = make_debug_mesh(2, 2, pods=2)
         x = jnp.asarray(np.random.default_rng(0).standard_normal((64,)).astype(np.float32))
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             got = compressed_psum(x, "pod", mesh)
         want = x * mesh.shape["pod"]
         print(json.dumps({"err": float(jnp.max(jnp.abs(got - want)))}))
@@ -107,7 +107,7 @@ def test_delivery_engine_shards_group_axis_across_devices():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.core import ConvGeometry, SessionRegistry
-        from repro.launch.mesh import make_debug_mesh, mesh_context
+        from repro.launch.mesh import make_debug_mesh
         from repro.runtime import DeliveryRequest, MoLeDeliveryEngine
 
         rng = np.random.default_rng(0)
@@ -126,7 +126,7 @@ def test_delivery_engine_shards_group_axis_across_devices():
                  .astype(np.float32)
             for t in reg.tenant_ids
         }
-        with mesh_context(mesh):
+        with jax.set_mesh(mesh):
             # one microbatch with all 8 tenants: inspect the jitted step's
             # output placement directly
             for t, d in datas.items():

@@ -1,5 +1,8 @@
 """Delivery engine (repro.runtime.engine): multi-tenant isolation, padded
 microbatch equivalence to per-request delivery, and kernel backend dispatch."""
+from functools import partial
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -447,15 +450,39 @@ def test_batched_dispatch_backends_agree(rng):
 
 
 def test_batched_ref_fallback_for_nontileable(rng):
-    """Non-tileable shapes route every backend to the reference math."""
+    """Off-tile shapes: the jnp backend computes the reference; interpret
+    (like compiled Pallas) runs the kernel, never the reference, with a
+    10-wide core as one whole-axis block and 3 rows padded to the 8-row
+    tile."""
     G, B, kappa, q = 2, 3, 3, 10
     x = jnp.asarray(rng.standard_normal((G, B, kappa * q)).astype(np.float32))
     cores = jnp.asarray(rng.standard_normal((G, q, q)).astype(np.float32))
-    want = ref.block_diag_matmul_batched_ref(x, cores, kappa)
-    for be in ("jnp", "interpret"):
+    want = np.asarray(ref.block_diag_matmul_batched_ref(x, cores, kappa))
+    np.testing.assert_allclose(
+        np.asarray(morph_rows_batched(x, cores, kappa, backend="jnp")),
+        want, atol=1e-5,
+    )
+    interp = partial(morph_rows_batched, kappa=kappa, backend="interpret")
+    assert "pallas_call" in str(jax.make_jaxpr(interp)(x, cores))
+    got = interp(x, cores)
+    assert got.shape == (G, B, kappa * q)
+    np.testing.assert_allclose(np.asarray(got), want, atol=1e-4)
+
+
+def test_engine_serves_narrow_core_on_pallas_backends(rng):
+    """A kappa > 1 core narrower than the 128-lane tile (2*6*6 / 2 = 36
+    features) is served by the kernels on the interpret backend, with the
+    same features as per-request delivery."""
+    reg = _registry(rng, tenants=2, kappa=2)
+    eng = MoLeDeliveryEngine(reg, backend="interpret")
+    for i, rows in enumerate((1, 3)):
+        d = rng.standard_normal(
+            (rows, GEOM.alpha, GEOM.m, GEOM.m)
+        ).astype(np.float32)
         np.testing.assert_allclose(
-            np.asarray(morph_rows_batched(x, cores, kappa, backend=be)),
-            np.asarray(want), atol=1e-5,
+            np.asarray(_del(eng, f"t{i}", d)),
+            np.asarray(reg.session(f"t{i}").deliver(jnp.asarray(d))),
+            atol=1e-4,
         )
 
 

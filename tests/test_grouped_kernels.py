@@ -2,8 +2,10 @@
 points): exact equivalence against the materialized-gather oracle for every
 index-vector shape the delivery engine can produce — identity, partial table
 (T < capacity), out-of-order, duplicate slots — on both backend legs (jnp
-reference and Pallas interpret), plus the untileable-shape fallback and the
-padding-index clamp."""
+reference and Pallas interpret), plus row padding and the refusal of
+whole-axis blocks for widths off the 128-lane tile on the Pallas backends,
+and the padding-index clamp."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -15,7 +17,7 @@ from repro.kernels import (
     ref,
     token_morph_grouped,
 )
-from repro.kernels.grouped import grouped_aug_gemm, grouped_block_diag_matmul
+from repro.kernels.grouped import grouped_aug_gemm
 
 BACKENDS = ("jnp", "interpret")
 
@@ -64,25 +66,32 @@ def test_aug_conv_grouped_matches_gather_oracle(rng, backend, name):
 
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_grouped_untileable_shapes_fall_back_to_ref(rng, backend):
-    """B not MXU-aligned routes every backend to the scan reference — the
-    public entry points stay total."""
+    """Shapes off the kernels' tiles.  The jnp backend runs the scan
+    reference for any shape.  The Pallas backends never fall back to it:
+    B = 5 rows are zero-padded to the 8-row tile (and sliced off the
+    result), and a 10-wide core repeated kappa = 3 times, K = 600 and N = 9
+    run as whole-axis blocks."""
     G, B, kappa, q, S = 3, 5, 3, 10, 4
     x = jnp.asarray(rng.standard_normal((G, B, kappa * q)).astype(np.float32))
     cores = jnp.asarray(rng.standard_normal((S, q, q)).astype(np.float32))
     gidx = jnp.asarray(np.array([2, 0, 2], np.int32))
-    np.testing.assert_allclose(
-        np.asarray(morph_rows_grouped(x, gidx, cores, kappa, backend=backend)),
-        np.asarray(ref.block_diag_matmul_batched_ref(x, cores[gidx], kappa)),
-        atol=1e-5,
-    )
-    # Aug fallback: K = 600 breaks the K % bk tiling constraint (bk = 512).
+    # Aug: K = 600 is no multiple of the 128-lane tile, N = 9 is narrower.
     t = jnp.asarray(rng.standard_normal((G, B, 600)).astype(np.float32))
     c = jnp.asarray((rng.standard_normal((S, 600, 9)) / 24).astype(np.float32))
-    np.testing.assert_allclose(
-        np.asarray(aug_conv_forward_grouped(t, gidx, c, backend=backend)),
-        np.asarray(ref.aug_gemm_batched_ref(t, c[gidx])),
-        atol=1e-4,
-    )
+    cases = [
+        (lambda: morph_rows_grouped(x, gidx, cores, kappa, backend=backend),
+         ref.block_diag_matmul_batched_ref(x, cores[gidx], kappa)),
+        (lambda: aug_conv_forward_grouped(t, gidx, c, backend=backend),
+         ref.aug_gemm_batched_ref(t, c[gidx])),
+    ]
+    for run, want in cases:
+        jaxpr = str(jax.make_jaxpr(run)())
+        assert ("pallas_call" in jaxpr) == (backend != "jnp")
+        got = run()
+        assert got.shape == want.shape
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=1e-4
+        )
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
@@ -112,14 +121,16 @@ def test_grouped_pallas_kernels_match_ref_directly(rng, name):
     G, S = len(gidx_np), 6
     gidx = jnp.asarray(gidx_np)
 
+    # The morph: rows cut to the core width, (G, B * kappa, q) @ cores.
     B, kappa, q = 16, 2, 128
     x = jnp.asarray(rng.standard_normal((G, B, kappa * q)).astype(np.float32))
     cores = jnp.asarray(
         (rng.standard_normal((S, q, q)) / np.sqrt(q)).astype(np.float32)
     )
-    got = grouped_block_diag_matmul(
-        x, gidx, cores, kappa, bm=8, bn=64, bk=64, interpret=True
-    )
+    got = grouped_aug_gemm(
+        x.reshape(G, B * kappa, q), gidx, cores, bm=8, bn=64, bk=64,
+        interpret=True,
+    ).reshape(G, B, kappa * q)
     want = ref.block_diag_matmul_grouped_ref(x, gidx, cores, kappa)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
 

@@ -1,4 +1,6 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs ref.py oracles."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,7 +9,6 @@ from _hypothesis_compat import given, settings, st
 
 from repro.kernels import aug_conv_forward, morph_rows, ref
 from repro.kernels.aug_gemm import aug_gemm
-from repro.kernels.block_diag import block_diag_matmul
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-4), (jnp.bfloat16, 2e-1)])
@@ -15,10 +16,10 @@ from repro.kernels.block_diag import block_diag_matmul
     (128, 1, 128), (128, 3, 128), (8, 4, 128), (256, 2, 256), (64, 6, 128),
 ])
 def test_block_diag_sweep(rng, R, kappa, q, dtype, tol):
+    """The repeated-block-diagonal morph through the Pallas GEMM kernel."""
     x = jnp.asarray(rng.standard_normal((R, kappa * q)), dtype)
     core = jnp.asarray(rng.standard_normal((q, q)) / np.sqrt(q), dtype)
-    got = block_diag_matmul(x, core, kappa, bm=min(128, R), bn=min(128, q),
-                            bk=min(128, q), interpret=True)
+    got = morph_rows(x, core, kappa, backend="interpret")
     want = ref.block_diag_matmul_ref(x, core, kappa)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), atol=tol
@@ -47,25 +48,35 @@ def test_block_diag_property(r_blocks, kappa, q_mult, seed):
     R, q = 128 * r_blocks, q_mult
     x = jnp.asarray(g.standard_normal((R, kappa * q)).astype(np.float32))
     core = jnp.asarray((g.standard_normal((q, q)) / np.sqrt(q)).astype(np.float32))
-    got = block_diag_matmul(x, core, kappa, interpret=True)
+    got = morph_rows(x, core, kappa, backend="interpret")
     want = ref.block_diag_matmul_ref(x, core, kappa)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
 
 
 def test_public_wrappers_fallback(rng):
-    """Non-tileable shapes must route to the reference implementation."""
+    """Off-tile shapes: jnp computes the reference.  Interpret (like
+    compiled Pallas) never falls back to it: a 10-wide core repeated 3
+    times, and 7 rows against K = 33 / N = 9, run the kernel with
+    whole-axis blocks and rows padded to the 8-row tile."""
     x = jnp.asarray(rng.standard_normal((10, 30)).astype(np.float32))
     core = jnp.asarray(rng.standard_normal((10, 10)).astype(np.float32))
-    got = morph_rows(x, core, 3)
-    want = ref.block_diag_matmul_ref(x, core, 3)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
-
     t = jnp.asarray(rng.standard_normal((7, 33)).astype(np.float32))
     c = jnp.asarray(rng.standard_normal((33, 9)).astype(np.float32))
-    np.testing.assert_allclose(
-        np.asarray(aug_conv_forward(t, c)), np.asarray(ref.aug_gemm_ref(t, c)),
-        atol=1e-5,
-    )
+    cases = [
+        (partial(morph_rows, kappa=3), (x, core),
+         ref.block_diag_matmul_ref(x, core, 3)),
+        (aug_conv_forward, (t, c), ref.aug_gemm_ref(t, c)),
+    ]
+    for fn, args, want in cases:
+        for be in ("jnp", "interpret"):
+            f = partial(fn, backend=be)
+            jaxpr = str(jax.make_jaxpr(f)(*args))
+            assert ("pallas_call" in jaxpr) == (be == "interpret"), be
+            got = f(*args)
+            assert got.shape == want.shape
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(want), atol=1e-4
+            )
 
 
 @pytest.mark.parametrize("T,D,chunk", [(64, 16, 16), (128, 32, 32), (96, 64, 32)])
@@ -86,7 +97,7 @@ def test_wkv6_kernel_sweep(rng, T, D, chunk):
     flat = lambda x: x.reshape(BH, *x.shape[2:])
     u_b = jnp.broadcast_to(u[None], (B, H, D)).reshape(BH, D)
     out, sf = wkv6_chunked(flat(r), flat(k), flat(v), flat(logw), u_b, flat(s0),
-                           chunk=chunk)
+                           chunk=chunk, interpret=True)
     np.testing.assert_allclose(
         np.asarray(out.reshape(B, H, T, D)), np.asarray(ref_out), atol=2e-3
     )
@@ -112,7 +123,8 @@ def test_wkv6_model_path_matches_kernel(rng):
     out_x, s_x = _wkv_chunked(r, k, v, logw, u, s0, chunk=64, subchunk=16)
     u_b = jnp.broadcast_to(u[None], (B, H, D)).reshape(B * H, D)
     fl = lambda x: x.reshape(B * H, *x.shape[2:])
-    out_k, s_k = wkv6_chunked(fl(r), fl(k), fl(v), fl(logw), u_b, fl(s0), chunk=32)
+    out_k, s_k = wkv6_chunked(fl(r), fl(k), fl(v), fl(logw), u_b, fl(s0),
+                              chunk=32, interpret=True)
     np.testing.assert_allclose(
         np.asarray(out_x), np.asarray(out_k.reshape(B, H, T, D)), atol=2e-3
     )

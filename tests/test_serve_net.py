@@ -329,6 +329,26 @@ def test_drain_rejects_new_requests_typed(rng):
     front.close()
 
 
+def test_close_front_exit_code_fails_dead_engine(rng):
+    """The server's exit code: 0 for a clean drain, 1 for lost rids, and 1
+    for a flusher that died, even though its failed requests left nothing
+    in flight for the drain to lose."""
+    from repro.launch.server import close_front
+    from repro.runtime.async_engine import EngineDeadError
+
+    assert close_front(_front(rng), lost=0) == 0
+    assert close_front(_front(rng), lost=2) == 1
+    dead = _front(rng, injector=FailureInjector(at_phases={"device"}),
+                  max_restarts=0)
+    fut = dead.submit(DeliveryRequest(
+        "tenant-0", np.zeros((1, GEOM.alpha, GEOM.m, GEOM.m), np.float32)
+    ))
+    with pytest.raises(EngineDeadError):
+        fut.result(timeout=60)
+    assert dead.failure is not None
+    assert close_front(dead, lost=0) == 1
+
+
 # ---------------------------------------------------------------------------
 # slow lane: the real process lifecycle
 # ---------------------------------------------------------------------------
