@@ -1,0 +1,5 @@
+"""Peak device memory in use (``peak_bytes_in_use``) after the window."""
+
+
+def read(run):
+    return run.memory_peak_bytes / 2 ** 30 if run.memory_peak_bytes else None
