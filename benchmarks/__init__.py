@@ -1,1 +1,1 @@
-"""Benchmark harness: one module per paper table/figure + roofline (see run.py)."""
+"""Benchmark harness: one module per paper table/figure (see run.py)."""

@@ -1,4 +1,4 @@
-"""Benchmark entrypoint: one section per paper table/figure + roofline.
+"""Benchmark entrypoint: one section per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows (one per measurement).
 
@@ -18,7 +18,6 @@ SECTIONS = [
     "augconv_equivalence",   # paper §4.4 experiment (CPU-scaled)
     "kernel_bench",          # Pallas kernel structure/μbench
     "engine_throughput",     # delivery engine: batched multi-tenant serving
-    "roofline",              # deliverable (g), reads dry-run artifacts
 ]
 
 

@@ -66,11 +66,14 @@ def grouped_aug_gemm(
     bn: int = 128,
     bk: int = 512,
     interpret: bool,
+    name: str | None = None,
 ) -> jax.Array:
     """Per-group Aug-Conv forward ``t[g] @ c_acs[gidx[g]]``, secrets in place.
 
     The grouped twin of ``aug_gemm.aug_gemm`` — this is the GEMM whose
     ``(G, K, N)`` weight gather dominated the non-identity delivery path.
+    ``name`` names the kernel, and with it the operation in a device trace,
+    whatever jitted wrapper calls it.
     """
     G, B, K = t.shape
     N = c_acs.shape[-1]
@@ -101,6 +104,7 @@ def grouped_aug_gemm(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, B, N), t.dtype),
         interpret=interpret,
+        name=name,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "parallel", "parallel", "arbitrary")
         ),

@@ -217,7 +217,7 @@ def _morph_rows_grouped(x, gidx, cores, kappa, backend):
     if backend != "jnp":
         G, q = x.shape[0], cores.shape[-1]
         return _grouped_gemm(
-            x.reshape(G, -1, q), gidx, cores, backend
+            x.reshape(G, -1, q), gidx, cores, backend, "mole_morph_grouped"
         ).reshape(x.shape)
     return _with_arange_fast_case(
         gidx, cores.shape[0],
@@ -244,7 +244,7 @@ def aug_conv_forward_grouped(
 def _aug_conv_forward_grouped(t, gidx, c_acs, backend):
     gidx = _safe_gidx(gidx, c_acs.shape[0])
     if backend != "jnp":
-        return _grouped_gemm(t, gidx, c_acs, backend)
+        return _grouped_gemm(t, gidx, c_acs, backend, "mole_aug_conv_grouped")
     return _with_arange_fast_case(
         gidx, c_acs.shape[0],
         lambda t_, g_: ref.aug_gemm_batched_ref(t_, c_acs),
@@ -253,13 +253,14 @@ def _aug_conv_forward_grouped(t, gidx, c_acs, backend):
     )
 
 
-def _grouped_gemm(t, gidx, ws, backend):
+def _grouped_gemm(t, gidx, ws, backend, name):
     """``t[g] (B, K) @ ws[gidx[g]] (K, N)`` through the grouped Pallas
-    kernel, the stacked weights read in place."""
+    kernel named ``name``, the stacked weights read in place."""
     bn, bk = _gemm_tiles(*ws.shape[1:])
     t, B, bm = _pad_rows(t, 1)
     return grouped_aug_gemm(
         t, gidx, ws, bm=bm, bn=bn, bk=bk, interpret=pallas_interpret(backend),
+        name=name,
     )[:, :B]
 
 

@@ -305,7 +305,7 @@ def run_analysis_cell(arch: str, shape_name: str, multi_pod: bool = False) -> di
 
     Returns {flops, bytes_accessed, collective bytes by kind} for the FULL
     model at this cell, all per-device (cost_analysis is per-device under
-    SPMD).  Used by benchmarks/roofline.py.
+    SPMD).
     """
     cfg = get_config(arch)
     shape = SHAPES[shape_name]

@@ -64,6 +64,7 @@ from repro.runtime import wire
 from repro.runtime.async_engine import (
     AdmissionError, AsyncDeliveryEngine, EngineDeadError,
 )
+from repro.runtime.tracing import span
 from repro.runtime.wire import ProtocolError
 
 __all__ = [
@@ -294,7 +295,8 @@ class DeliveryServer:
                     # (or EOF) and re-fetch from the result cache.
                     conn.writer.write(frame[: max(1, len(frame) // 2)])
                     raise ConnectionResetError("chaos: truncated write")
-                conn.writer.write(frame)
+                with span("mole.server.write"):
+                    conn.writer.write(frame)
                 await asyncio.wait_for(conn.writer.drain(), self.write_timeout)
         except (asyncio.TimeoutError, ConnectionError, OSError):
             pass
@@ -355,7 +357,8 @@ class DeliveryServer:
             waiters.add(conn)
             return
         try:
-            _, age_ms, req = wire.decode_request(header, payload)
+            with span("mole.server.decode"):
+                _, age_ms, req = wire.decode_request(header, payload)
         except ProtocolError:
             raise                       # stream-level: close the connection
         except (ValueError, TypeError) as e:
@@ -449,7 +452,8 @@ class DeliveryServer:
         exc = fut.exception()
         if exc is None:
             try:
-                frame = wire.encode_result(rid, fut.result())
+                with span("mole.server.encode"):
+                    frame = wire.encode_result(rid, fut.result())
             except ProtocolError as e:  # pragma: no cover - non-wire dtype
                 frame, code = wire.encode_reject(rid, "FAILED", str(e)), "FAILED"
         elif isinstance(exc, AdmissionError):

@@ -69,6 +69,7 @@ from . import api
 from .api import DeliveryRequest
 from .engine import MoLeDeliveryEngine
 from .resilience import EngineSnapshot, SimulatedFailure
+from .tracing import span
 
 _log = logging.getLogger(__name__)
 
@@ -618,15 +619,16 @@ class AsyncDeliveryEngine:
             error: BaseException | None = None
             work = None
             with self._cv:
-                while not self._should_flush(time.monotonic()):
-                    if self._closed and not self._futures:
-                        return
-                    deadline = self._oldest_deadline()
-                    timeout = (
-                        None if deadline is None
-                        else max(0.0, deadline - time.monotonic())
-                    )
-                    self._cv.wait(timeout=timeout)
+                with span("mole.flusher.idle"):
+                    while not self._should_flush(time.monotonic()):
+                        if self._closed and not self._futures:
+                            return
+                        deadline = self._oldest_deadline()
+                        timeout = (
+                            None if deadline is None
+                            else max(0.0, deadline - time.monotonic())
+                        )
+                        self._cv.wait(timeout=timeout)
                 self._force_flush = False
                 # Phase 1 under the lock: coalesce the queues into private
                 # work items.  Afterwards the queues are empty — the second
@@ -712,12 +714,13 @@ class AsyncDeliveryEngine:
             # set_running_or_notify_cancel() guards against futures the
             # caller cancelled (e.g. after a result() timeout) — resolving
             # those would raise InvalidStateError and kill this thread.
-            for fut, feats in resolved:
-                if fut.set_running_or_notify_cancel():
-                    fut.set_result(feats)
-            for fut, err in failed:
-                if fut.set_running_or_notify_cancel():
-                    fut.set_exception(err)
+            with span("mole.flush.resolve"):
+                for fut, feats in resolved:
+                    if fut.set_running_or_notify_cancel():
+                        fut.set_result(feats)
+                for fut, err in failed:
+                    if fut.set_running_or_notify_cancel():
+                        fut.set_exception(err)
             # Notify only after the futures are resolved, so a drain()er
             # waking on an empty in-flight table can rely on .result()
             # being immediate.

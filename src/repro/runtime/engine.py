@@ -57,6 +57,7 @@ lock, a background deadline flusher, and admission control on top.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import json
 import logging
@@ -85,6 +86,7 @@ from . import api
 from .api import DeliveryRequest, DeliveryResult
 from .prefetch import ArrivalPredictor
 from .resilience import EngineSnapshot, StragglerMonitor
+from .tracing import span
 
 __all__ = ["EngineStats", "MoLeDeliveryEngine", "delivery_trace_count"]
 
@@ -110,8 +112,19 @@ def _fmt_ms(x: float) -> str:
     return v if v == "n/a" else v + "ms"
 
 
-# Flush phases timed by the engine; EngineStats keeps one reservoir each.
+# Flush phases timed by the engine; EngineStats keeps one reservoir each,
+# and each runs inside the host span mole.flush.<phase> (runtime/tracing.py).
 FLUSH_PHASES = ("coalesce", "device", "publish")
+_PHASE_SPANS = {p: f"mole.flush.{p}" for p in FLUSH_PHASES}
+
+
+@dataclasses.dataclass
+class PhaseTiming:
+    """One flush phase timed by :meth:`EngineStats.phase`: ``ms`` is set
+    when the phase ends; ``keep = False`` leaves it out of the reservoir."""
+
+    ms: float | None = None
+    keep: bool = True
 
 
 @dataclasses.dataclass
@@ -181,10 +194,12 @@ class EngineStats:
     latency_window: int = 4096
     _latencies_ms: collections.deque = dataclasses.field(default=None)
     _latencies_by_priority: dict = dataclasses.field(default=None)
-    # Per-flush phase durations (FLUSH_PHASES) + per-submit lock waits, same
-    # sliding-window reservoirs.
+    # Per-flush phase durations (FLUSH_PHASES) + per-submit lock waits +
+    # per-request queue waits (enqueue to the coalesce that takes its first
+    # rows), same sliding-window reservoirs.
     _phases_ms: dict = dataclasses.field(default=None)
     _submit_wait_ms: collections.deque = dataclasses.field(default=None)
+    _queue_wait_ms: collections.deque = dataclasses.field(default=None)
     # WFQ virtual-time lag (max - min across backlogged tenants) sampled at
     # every begin_flush: persistent lag means some tenant is being served far
     # ahead of another relative to its weighted share.
@@ -204,6 +219,8 @@ class EngineStats:
             self._submit_wait_ms = collections.deque(
                 maxlen=self.latency_window
             )
+        if self._queue_wait_ms is None:
+            self._queue_wait_ms = collections.deque(maxlen=self.latency_window)
         if self._wfq_lag is None:
             self._wfq_lag = collections.deque(maxlen=self.latency_window)
 
@@ -249,6 +266,19 @@ class EngineStats:
     def record_phase_ms(self, phase: str, ms: float) -> None:
         self._phases_ms[phase].append(float(ms))
 
+    @contextlib.contextmanager
+    def phase(self, name: str):
+        """Time one flush phase into its reservoir, inside the host span
+        ``mole.flush.<name>``.  Yields a :class:`PhaseTiming`; a phase that
+        raises records nothing."""
+        timing = PhaseTiming()
+        with span(_PHASE_SPANS[name]):
+            t0 = time.monotonic()
+            yield timing
+            timing.ms = (time.monotonic() - t0) * 1e3
+        if timing.keep:
+            self.record_phase_ms(name, timing.ms)
+
     def phase_quantile_ms(self, phase: str, q: float) -> float:
         """Per-flush duration quantile of one phase ('coalesce' | 'device' |
         'publish') over the recent window (nan when never flushed)."""
@@ -264,6 +294,15 @@ class EngineStats:
 
     def submit_wait_quantile_ms(self, q: float) -> float:
         return _window_quantile(self._submit_wait_ms, q)
+
+    # -- queue waits ----------------------------------------------------------
+    def record_queue_wait_ms(self, ms: float) -> None:
+        """One request's wait from enqueue to the coalesce that took its
+        first rows."""
+        self._queue_wait_ms.append(float(ms))
+
+    def queue_wait_quantile_ms(self, q: float) -> float:
+        return _window_quantile(self._queue_wait_ms, q)
 
     # -- WFQ accounting -------------------------------------------------------
     def record_wfq_lag(self, lag: float) -> None:
@@ -299,6 +338,10 @@ class EngineStats:
             f"submit wait: p50={_fmt_ms(self.submit_wait_quantile_ms(0.5))} "
             f"p95={_fmt_ms(self.submit_wait_quantile_ms(0.95))} "
             f"stalls(>{self.stall_threshold_ms:g}ms)={self.submit_stalls}"
+        )
+        lines.append(
+            f"queue wait: p50={_fmt_ms(self.queue_wait_quantile_ms(0.5))} "
+            f"p95={_fmt_ms(self.queue_wait_quantile_ms(0.95))}"
         )
         admission = (
             f"admission: rejected={self.rejected} blocked={self.blocked}"
@@ -466,6 +509,7 @@ class _ReqInfo:
     request: DeliveryRequest        # normalized descriptor
     submitted_at: float             # time.monotonic() at enqueue
     queue_depth_at_submit: int      # engine-wide pending rows before enqueue
+    coalesced_at: float | None = None   # set when its first rows coalesce
     completed_at: float | None = None   # set when a flush publishes the last row
 
 
@@ -922,22 +966,21 @@ class MoLeDeliveryEngine:
         self.stats.rows_padded += mb.n_padded_rows
         self.stats.bucket_shapes.add(mb.x.shape[:2])
         self.stats.padding_clamp_count += mb.n_clamped_padding
+        # Queue wait, once per request: at the coalesce that takes its first
+        # rows (a replayed request keeps its _ReqInfo, so it is not counted
+        # again).
+        now = time.monotonic()
+        for s in mb.slices:
+            info = self._req_info.get(s.request_id)
+            if info is not None and info.coalesced_at is None:
+                info.coalesced_at = now
+                self.stats.record_queue_wait_ms(
+                    (now - info.submitted_at) * 1e3
+                )
 
-    def begin_flush(self) -> _FlushWork | None:
-        """Phase 1 (cheap, engine-state-mutating): coalesce pending rows
-        into microbatch work items and snapshot the device plans.  The async
-        front door runs this under its lock; the coalesced rows leave the
-        queues, which immediately accept new submissions — the double-buffer
-        that lets submitters progress mid-flush.  At most
-        ``max_flush_microbatches`` items are taken per call so one round's
-        working set stays bounded however deep the backlog; the caller loops
-        until None, which is returned when nothing is pending.
-        """
-        vision_live = self.registry is not None and len(self.registry) > 0
-        lm_live = self.lm_registry is not None and len(self.lm_registry) > 0
-        if not vision_live and not lm_live:
-            return None  # nothing registered yet -> nothing can be pending
-        t0 = time.monotonic()
+    def _coalesce(self, vision_live: bool, lm_live: bool) -> _FlushWork | None:
+        """Coalesce the live lanes' pending rows into work items (None when
+        nothing is pending)."""
         work = _FlushWork(items=[])
         cap = self.max_flush_microbatches
         lanes: list[tuple[str, object, object, Callable[[], _Plan]]] = []
@@ -1003,14 +1046,45 @@ class MoLeDeliveryEngine:
                 "flush (total %d); see EngineStats.padding_clamp_count",
                 clamped, self.stats.padding_clamp_count,
             )
-        self.stats.flushes += 1
-        self.stats.record_phase_ms("coalesce", (time.monotonic() - t0) * 1e3)
+        return work
+
+    def begin_flush(self) -> _FlushWork | None:
+        """Phase 1 (cheap, engine-state-mutating): coalesce pending rows
+        into microbatch work items and snapshot the device plans.  The async
+        front door runs this under its lock; the coalesced rows leave the
+        queues, which immediately accept new submissions — the double-buffer
+        that lets submitters progress mid-flush.  At most
+        ``max_flush_microbatches`` items are taken per call so one round's
+        working set stays bounded however deep the backlog; the caller loops
+        until None, which is returned when nothing is pending.
+        """
+        vision_live = self.registry is not None and len(self.registry) > 0
+        lm_live = self.lm_registry is not None and len(self.lm_registry) > 0
+        if not vision_live and not lm_live:
+            return None  # nothing registered yet -> nothing can be pending
+        with self.stats.phase("coalesce") as timing:
+            work = self._coalesce(vision_live, lm_live)
+            if work is None:
+                timing.keep = False   # nothing pending: not a flush
+                return None
+            self.stats.flushes += 1
         # The nastiest crash point: the coalesced rows have already left the
         # queues, so a failure here strands them unless recovery replays
         # from _req_info (requeue_inflight / restore).
         if self.injector is not None:
             self.injector.maybe_fail_phase("coalesce")
         return work
+
+    def _dispatch(self, item: _WorkItem):
+        """Start one work item's jitted step; returns its device output."""
+        mb = item.mb
+        if item.lane == "vision":
+            return self._execute(mb.x, mb.group_tenant, item.plan)
+        if item.lane == "tokens":
+            return self._execute_tokens(
+                mb.x, mb.group_tenant, item.want_embed, item.plan
+            )
+        return self._execute_features(mb.x, mb.group_tenant, item.plan)
 
     # analysis: forbids-lock(_cv)
     def execute_flush(self, work: _FlushWork) -> None:
@@ -1023,33 +1097,26 @@ class MoLeDeliveryEngine:
         """
         if self.injector is not None:
             self.injector.maybe_fail_phase("device")
-        t0 = time.monotonic()
-        # Dispatch every step first (jax dispatch is async), then block: the
-        # device pipelines the microbatches instead of idling between them.
-        outs = []
-        for item in work.items:
-            mb = item.mb
-            if item.lane == "vision":
-                outs.append(self._execute(mb.x, mb.group_tenant, item.plan))
-            elif item.lane == "tokens":
-                outs.append(self._execute_tokens(
-                    mb.x, mb.group_tenant, item.want_embed, item.plan
-                ))
-            else:
-                outs.append(self._execute_features(
-                    mb.x, mb.group_tenant, item.plan
-                ))
-        for item, out in zip(work.items, outs):
-            if item.lane == "tokens":
-                morphed, feats = out
-                item.out = (
-                    np.asarray(morphed),
-                    None if feats is None else np.asarray(feats),
-                )
-            else:
-                item.out = np.asarray(out)
-        dt_ms = (time.monotonic() - t0) * 1e3
-        self.stats.record_phase_ms("device", dt_ms)
+        with self.stats.phase("device") as timing:
+            # Dispatch every step first (jax dispatch is async), then wait on
+            # and fetch each in turn: the device pipelines the microbatches
+            # instead of idling between them, and runs item i+1 while item
+            # i's output is copied to the host.
+            with span("mole.flush.dispatch"):
+                outs = [self._dispatch(item) for item in work.items]
+            for item, out in zip(work.items, outs):
+                with span("mole.flush.wait"):
+                    jax.block_until_ready(out)
+                with span("mole.flush.fetch"):
+                    if item.lane == "tokens":
+                        morphed, feats = out
+                        item.out = (
+                            np.asarray(morphed),
+                            None if feats is None else np.asarray(feats),
+                        )
+                    else:
+                        item.out = np.asarray(out)
+        dt_ms = timing.ms
         # Straggler watch: a device phase far above the running EMA flags
         # this flush as degraded (hung interconnect, preempted accelerator).
         if self.straggler.record(self.stats.flushes, dt_ms / 1e3):
@@ -1067,16 +1134,15 @@ class MoLeDeliveryEngine:
         # round, so recovery never sees a half-published flush.
         if self.injector is not None:
             self.injector.maybe_fail_phase("publish")
-        t0 = time.monotonic()
         done: dict[int, np.ndarray] = {}
-        for item in work.items:
-            if item.lane == "vision":
-                self._publish_rows(item, done, self._finish_vision)
-            elif item.lane == "tokens":
-                self._publish_tokens(item, done)
-            else:
-                self._publish_rows(item, done, self._finish_features)
-        self.stats.record_phase_ms("publish", (time.monotonic() - t0) * 1e3)
+        with self.stats.phase("publish"):
+            for item in work.items:
+                if item.lane == "vision":
+                    self._publish_rows(item, done, self._finish_vision)
+                elif item.lane == "tokens":
+                    self._publish_tokens(item, done)
+                else:
+                    self._publish_rows(item, done, self._finish_features)
         return done
 
     def _mark_done(self, rid: int) -> None:

@@ -87,6 +87,17 @@ def test_delivery_step_compiles_at_narrow_cores(one_chip, geom, kappa):
     assert _custom_calls(_compile_step(one_chip, geom, kappa, 3)) == 2
 
 
+def test_grouped_kernels_keep_their_names(one_chip):
+    """The morph and the Aug-Conv product run one kernel body; each call
+    names its kernel, and the compiled step's instructions (the operations
+    a device trace shows) carry those names, not the jitted wrappers'."""
+    text = _compile_step(one_chip, PAPER, 1, 8).as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2
+    assert any("%mole_morph_grouped" in ln for ln in calls)
+    assert any("%mole_aug_conv_grouped" in ln for ln in calls)
+
+
 def test_lm_head_compiles_at_decode_width(one_chip):
     """The batched-decode Aug-head GEMM at d=4096, V=102400 in bf16."""
     d, vocab, rows = 4096, 102400, 8
