@@ -64,8 +64,8 @@ LOG_METHODS = frozenset({
 })
 
 WIRE_SINKS = frozenset({
-    "encode_frame", "encode_request", "encode_result", "encode_reject",
-    "encode_bye",
+    "encode_frame", "frame_head", "encode_request", "encode_result",
+    "encode_result_parts", "encode_reject", "encode_bye",
 })
 
 # Functions whose return value is serialized out of process: returning

@@ -28,6 +28,10 @@ collapse or silently dropping work:
     not resubmit) and caches completed frames (a retry after a lost response
     is answered from cache), so a request is delivered by the engine at most
     once however many times the fleet re-sends it.
+  * **Results by view** — a result frame is its head and header plus a
+    read-only view of the result array (``wire.encode_result_parts``),
+    handed to the socket with ``writelines``: the payload is never copied
+    on the event loop, and the cache keeps the array, not a copy of it.
   * **Graceful drain** — SIGTERM stops the accept loop, lets the engine
     flush every admitted request, writes all pending responses, notifies
     clients (``BYE``), persists an :class:`EngineSnapshot` when
@@ -41,10 +45,10 @@ collapse or silently dropping work:
     (``repro.launch.client``) must still resolve every rid exactly once.
 
 Counters land in ``EngineStats`` (``shed_requests``, ``expired_requests``,
-``reconnects``, ``duplicate_hits``), next to a per-tenant security-budget
-line computed from ``repro.core.security`` at registration time — the
-operator sees the privacy budget of the served tenants beside their latency
-budget.
+``reconnects``, ``duplicate_hits``, ``result_frames_by_view``), next to a
+per-tenant security-budget line computed from ``repro.core.security`` at
+registration time — the operator sees the privacy budget of the served
+tenants beside their latency budget.
 """
 from __future__ import annotations
 
@@ -78,6 +82,10 @@ _log = logging.getLogger(__name__)
 # bytes cannot change.  OVERLOADED / DRAINING are transient by definition —
 # caching them would turn a momentary shed into a permanent one.
 _CACHEABLE_REJECTS = ("EXPIRED", "INVALID", "FAILED")
+
+# One response frame: ``bytes`` (rejects, BYE) or, for a result, the buffers
+# of ``wire.encode_result_parts`` to be written in order.
+_Frame = bytes | tuple[bytes, memoryview]
 
 
 class _Conn:
@@ -155,7 +163,7 @@ class DeliveryServer:
         self._loop: asyncio.AbstractEventLoop | None = None
         self._conns: dict[_Conn, asyncio.Task] = {}       # conn -> writer task
         self._inflight: dict[str, set[_Conn]] = {}        # wire rid -> waiters
-        self._completed: collections.OrderedDict[str, bytes] = (
+        self._completed: collections.OrderedDict[str, _Frame] = (
             collections.OrderedDict()
         )
         self._draining = False
@@ -293,10 +301,16 @@ class DeliveryServer:
                     # Chaos: truncate the frame mid-write and reset — the
                     # client's reader must fail with a typed ProtocolError
                     # (or EOF) and re-fetch from the result cache.
+                    if isinstance(frame, tuple):
+                        frame = b"".join(frame)
                     conn.writer.write(frame[: max(1, len(frame) // 2)])
                     raise ConnectionResetError("chaos: truncated write")
                 with span("mole.server.write"):
-                    conn.writer.write(frame)
+                    if isinstance(frame, tuple):
+                        conn.writer.writelines(frame)
+                        self.stats.result_frames_by_view += 1
+                    else:
+                        conn.writer.write(frame)
                 await asyncio.wait_for(conn.writer.drain(), self.write_timeout)
         except (asyncio.TimeoutError, ConnectionError, OSError):
             pass
@@ -310,7 +324,7 @@ class DeliveryServer:
                     conn.writer.close()
 
     # -- request path ---------------------------------------------------------
-    def _send(self, conn: _Conn, frame: bytes) -> None:
+    def _send(self, conn: _Conn, frame: _Frame) -> None:
         if not conn.alive:
             return
         try:
@@ -326,7 +340,7 @@ class DeliveryServer:
             self._remember(rid, frame)
         self._send(conn, frame)
 
-    def _remember(self, rid: str, frame: bytes) -> None:
+    def _remember(self, rid: str, frame: _Frame) -> None:
         self._completed[rid] = frame
         self._completed.move_to_end(rid)
         while len(self._completed) > self.result_cache:
@@ -453,7 +467,7 @@ class DeliveryServer:
         if exc is None:
             try:
                 with span("mole.server.encode"):
-                    frame = wire.encode_result(rid, fut.result())
+                    frame = wire.encode_result_parts(rid, fut.result())
             except ProtocolError as e:  # pragma: no cover - non-wire dtype
                 frame, code = wire.encode_reject(rid, "FAILED", str(e)), "FAILED"
         elif isinstance(exc, AdmissionError):

@@ -156,13 +156,16 @@ class EngineStats:
     # per-tenant admission quota), requests already past their deadline_ms
     # on arrival (EXPIRED), front-door deliver(timeout=) expiries that
     # cancelled their request, connections dropped/reset mid-stream (each
-    # one a client reconnect), and retries answered straight from the
-    # exactly-once result cache.
+    # one a client reconnect), retries answered straight from the
+    # exactly-once result cache, and result frames handed to a socket as
+    # head and header plus a view of the result array (one per ok response
+    # written).
     shed_requests: int = 0
     expired_requests: int = 0
     timed_out_requests: int = 0
     reconnects: int = 0
     duplicate_hits: int = 0
+    result_frames_by_view: int = 0
     # Per-tenant security budget on the served path: tenant -> log2 of the
     # brute-force attack-success upper bound for the secrets serving that
     # tenant (core.security).  Filled by the network server at registration
@@ -380,6 +383,7 @@ class EngineStats:
         served = (
             self.shed_requests + self.expired_requests
             + self.timed_out_requests + self.reconnects + self.duplicate_hits
+            + self.result_frames_by_view
         )
         if served:
             lines.append(
@@ -387,7 +391,8 @@ class EngineStats:
                 f"expired={self.expired_requests} "
                 f"timed_out={self.timed_out_requests} "
                 f"reconnects={self.reconnects} "
-                f"duplicate_hits={self.duplicate_hits}"
+                f"duplicate_hits={self.duplicate_hits} "
+                f"result_frames_by_view={self.result_frames_by_view}"
             )
         if self.security_budget_log2:
             worst = max(self.security_budget_log2.items(), key=lambda kv: kv[1])

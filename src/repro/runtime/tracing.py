@@ -26,7 +26,7 @@ SPANS = (
     "mole.flush.resolve",   # resolving the round's futures and callbacks
     # The network front door (launch/server.py), on the event-loop thread.
     "mole.server.decode",   # wire.decode_request of one request frame
-    "mole.server.encode",   # wire.encode_result of one result frame
+    "mole.server.encode",   # wire.encode_result_parts: head, header, view
     "mole.server.write",    # handing one response frame to the socket writer
 )
 

@@ -53,9 +53,9 @@ __all__ = [
     "ProtocolError",
     "KIND_REQ", "KIND_RES", "KIND_REJ", "KIND_BYE",
     "REJECT_CODES", "DEFAULT_MAX_FRAME",
-    "encode_frame", "read_frame",
+    "encode_frame", "frame_head", "read_frame",
     "encode_request", "decode_request",
-    "encode_result", "decode_result", "WireResult",
+    "encode_result", "encode_result_parts", "decode_result", "WireResult",
     "encode_reject", "decode_reject", "WireReject",
     "encode_bye",
 ]
@@ -110,17 +110,25 @@ _WIRE_DTYPES = (
 # framing
 # ---------------------------------------------------------------------------
 
-def encode_frame(kind: int, header: Mapping[str, Any],
-                 payload: bytes = b"") -> bytes:
-    """Serialize one frame.  Raises :class:`ProtocolError` on a non-JSON-able
-    header or an unknown kind (catching producer bugs on the producer)."""
+def frame_head(kind: int, header: Mapping[str, Any],
+               payload_len: int) -> bytes:
+    """Everything of one frame before its payload: the fixed head and the
+    JSON header, for a payload of ``payload_len`` bytes sent after it.
+    Raises :class:`ProtocolError` on a non-JSON-able header or an unknown
+    kind (catching producer bugs on the producer)."""
     if kind not in _KINDS:
         raise ProtocolError(f"unknown frame kind {kind!r}")
     try:
         hdr = json.dumps(dict(header), separators=(",", ":")).encode("utf-8")
     except (TypeError, ValueError) as e:
         raise ProtocolError(f"header is not JSON-able: {e}") from e
-    return _HEAD.pack(MAGIC, kind, len(hdr), len(payload)) + hdr + payload
+    return _HEAD.pack(MAGIC, kind, len(hdr), payload_len) + hdr
+
+
+def encode_frame(kind: int, header: Mapping[str, Any],
+                 payload: bytes = b"") -> bytes:
+    """Serialize one frame (see :func:`frame_head` for what it raises)."""
+    return frame_head(kind, header, len(payload)) + payload
 
 
 def _parse_head(head: bytes, max_frame_bytes: int) -> tuple[int, int, int]:
@@ -205,14 +213,23 @@ async def read_frame(
 # ndarray payloads
 # ---------------------------------------------------------------------------
 
-def _encode_array(arr: np.ndarray) -> tuple[dict, bytes]:
+def _array_view(arr: np.ndarray) -> tuple[dict, memoryview]:
+    """The array's wire header and a read-only byte view of its data: no
+    copy unless ``arr`` is not C-contiguous.  (A uint8 view, not
+    ``memoryview.cast``, which refuses zero-size arrays.)"""
     arr = np.ascontiguousarray(arr)
     if arr.dtype.name not in _WIRE_DTYPES:
         raise ProtocolError(
             f"dtype {arr.dtype.name!r} is not wire-transportable "
             f"(allowed: {_WIRE_DTYPES})"
         )
-    return {"dtype": arr.dtype.name, "shape": list(arr.shape)}, arr.tobytes()
+    view = memoryview(arr.reshape(-1).view(np.uint8)).toreadonly()
+    return {"dtype": arr.dtype.name, "shape": list(arr.shape)}, view
+
+
+def _encode_array(arr: np.ndarray) -> tuple[dict, bytes]:
+    meta, view = _array_view(arr)
+    return meta, view.tobytes()
 
 
 def _decode_array(header: Mapping[str, Any], payload: bytes) -> np.ndarray:
@@ -321,8 +338,13 @@ class WireResult:
     metadata: Mapping[str, Any] = dataclasses.field(default_factory=dict)
 
 
-def encode_result(rid: str, result: DeliveryResult) -> bytes:
-    meta, body = _encode_array(np.asarray(result.payload))
+def encode_result_parts(rid: str,
+                        result: DeliveryResult) -> tuple[bytes, memoryview]:
+    """One ``KIND_RES`` frame as two buffers: its head and header, and a
+    read-only view of the result array as its payload.  Writing both in
+    order (``writelines``) puts :func:`encode_result`'s bytes on the wire
+    without copying the payload; the view keeps the array alive."""
+    meta, body = _array_view(np.asarray(result.payload))
     header = {
         "rid": str(rid),
         "engine_rid": int(result.request_id),
@@ -332,7 +354,11 @@ def encode_result(rid: str, result: DeliveryResult) -> bytes:
         "metadata": dict(result.metadata),
         **meta,
     }
-    return encode_frame(KIND_RES, header, body)
+    return frame_head(KIND_RES, header, body.nbytes), body
+
+
+def encode_result(rid: str, result: DeliveryResult) -> bytes:
+    return b"".join(encode_result_parts(rid, result))
 
 
 def decode_result(header: Mapping[str, Any], payload: bytes) -> WireResult:

@@ -50,6 +50,7 @@ FIXTURE_EXPECTATIONS = [
     ("secret_in_exception.py",
      {("exception-leak", 8), ("assert-leak", 13)}),
     ("wire_header_leak.py", {("wire-leak", 8), ("wire-leak", 12)}),
+    ("wire_result_parts_leak.py", {("wire-leak", 9), ("wire-leak", 13)}),
     ("declassified_snapshot.py", {("serialized-secret", 14)}),
     ("lock_violation.py",
      {("held-forbidden", 17), ("held-forbidden", 21),
@@ -67,6 +68,25 @@ FIXTURE_EXPECTATIONS = [
 def test_fixture_findings_at_exact_locations(fixture, expected):
     found = _locset(_findings(FIXTURES / fixture))
     assert found == expected
+
+
+def test_result_parts_sink_flags_a_tainted_argument():
+    """``encode_result_parts`` writes its payload to the socket as a view:
+    a secret passed to it is a wire leak, named after that sink; the same
+    call on a plain result is not."""
+    (f, _) = sorted(_findings(FIXTURES / "wire_result_parts_leak.py"),
+                    key=lambda f: f.line)
+    assert (f.rule, f.line) == ("wire-leak", 9)
+    assert "encode_result_parts()" in f.message
+    assert "encode_result_parts" in taint.WIRE_SINKS
+    active, _, _ = _run_source(
+        "from repro.runtime import wire\n"
+        "def send(seed, rid):\n"
+        "    return wire.encode_result_parts(rid, seed)\n"
+        "def fine(rid, res):\n"
+        "    return wire.encode_result_parts(rid, res)\n"
+    )
+    assert _locset(active) == {("wire-leak", 3)}
 
 
 def test_declassified_fixture_is_suppressed_with_reason():

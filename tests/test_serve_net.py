@@ -21,6 +21,7 @@ from repro.runtime import (
 )
 from repro.runtime import wire
 from repro.runtime.api import DeliveryRequest
+from repro.runtime.wire import ProtocolError
 from repro.launch.client import ClientFleet, FleetConfig
 from repro.launch.server import DeliveryServer
 
@@ -239,6 +240,125 @@ def test_duplicate_rid_served_from_cache(rng):
     assert r1.engine_rid == r2.engine_rid      # one engine delivery, not two
     np.testing.assert_array_equal(r1.payload, r2.payload)
     assert front.engine.stats.duplicate_hits == 1
+    front.close()
+
+
+async def _read_raw_frame(reader) -> bytes:
+    """One whole frame as the bytes that crossed the socket."""
+    head = await asyncio.wait_for(reader.readexactly(wire._HEAD.size), 30)
+    _, _, hlen, plen = wire._HEAD.unpack(head)
+    return head + await asyncio.wait_for(reader.readexactly(hlen + plen), 30)
+
+
+def _vision_request(tenant="tenant-0", batch=2, seed=0):
+    x = np.random.default_rng(seed).standard_normal(
+        (batch, GEOM.alpha, GEOM.m, GEOM.m)).astype(np.float32)
+    return DeliveryRequest(tenant, x)
+
+
+def test_result_sent_as_parts_arrives_byte_identical(rng):
+    """The head and the view the server writes with writelines reach a
+    real client over loopback as the bytes of the joined frame."""
+    front = _front(rng)
+    req = _vision_request("tenant-1")
+
+    async def body(server):
+        reader, writer = await asyncio.open_connection("127.0.0.1",
+                                                       server.port)
+        writer.write(wire.encode_request(req, "parts-1"))
+        await writer.drain()
+        raw = await _read_raw_frame(reader)
+        writer.close()
+        return raw, server._completed["parts-1"]
+
+    raw, cached = _run_served(front, body)
+    assert isinstance(cached, tuple) and isinstance(cached[1], memoryview)
+    assert raw == b"".join(cached)
+    res = wire.decode_result(*wire.decode_frame(raw)[1:])
+    assert res.payload.shape == (2, GEOM.beta, GEOM.m, GEOM.m)
+    assert front.engine.stats.result_frames_by_view == 1
+    front.close()
+
+
+def test_retry_from_the_result_cache_resends_the_same_bytes(rng):
+    front = _front(rng)
+    frame = wire.encode_request(_vision_request("tenant-2"), "again-1")
+
+    async def body(server):
+        reader, writer = await asyncio.open_connection("127.0.0.1",
+                                                       server.port)
+        raws = []
+        for _ in range(2):                # the retry re-sends the same rid
+            writer.write(frame)
+            await writer.drain()
+            raws.append(await _read_raw_frame(reader))
+        writer.close()
+        return raws
+
+    first, second = _run_served(front, body)
+    assert first == second
+    assert wire.decode_frame(first)[0] == wire.KIND_RES
+    assert front.engine.stats.duplicate_hits == 1
+    assert front.engine.stats.result_frames_by_view == 2
+    front.close()
+
+
+def test_chaos_truncated_result_fails_typed_then_refetches(rng):
+    """The chaos write cuts a result frame sent as parts: the client's
+    reader fails with a typed ProtocolError, and a retry on a fresh
+    connection is answered from the cache with the whole frame."""
+    inj = FailureInjector(network_phases={"write"}, network_rate=1.0, seed=0)
+    front = _front(rng)
+    frame = wire.encode_request(_vision_request("tenant-0"), "cut-1")
+
+    async def exchange(port, read):
+        reader, writer = await asyncio.open_connection("127.0.0.1", port)
+        writer.write(frame)
+        await writer.drain()
+        try:
+            return await asyncio.wait_for(read(reader), 30)
+        finally:
+            writer.close()
+
+    async def body(server):
+        cut = await exchange(server.port, lambda r: r.read())   # to EOF
+        inj.network_phases = set()        # the fault has fired: stand down
+        again = await exchange(server.port, wire.read_frame)
+        seen = asyncio.StreamReader()     # what the client's reader got
+        seen.feed_data(cut)
+        seen.feed_eof()
+        with pytest.raises(ProtocolError, match="truncated frame"):
+            await wire.read_frame(seen)
+        return cut, again, b"".join(server._completed["cut-1"])
+
+    cut, (kind, header, payload), whole = _run_served(front, body,
+                                                      injector=inj)
+    assert cut == whole[: len(whole) // 2]      # half of the joined frame
+    assert kind == wire.KIND_RES
+    assert wire.decode_frame(whole)[1:] == (header, payload)
+    assert inj.network_hits == {"write": 1}
+    assert front.engine.stats.duplicate_hits == 1
+    assert front.engine.stats.result_frames_by_view == 1
+    front.close()
+
+
+def test_result_frames_by_view_counts_the_ok_frames(rng):
+    """Without hedges or retries, one result frame by view is written for
+    each ok outcome; rejects are plain frames and are not counted."""
+    front = _front(rng, max_inflight_rows=4096)
+
+    async def body(server):
+        cfg = _fleet_cfg(server.port, requests=24, batch=4,
+                         trace="burst:24@1", max_attempts=1,
+                         attempt_timeout_ms=60_000.0)
+        return await ClientFleet(cfg).run()
+
+    report = _run_served(front, body, max_pending_rows=8)
+    report.assert_exactly_once()
+    counts = report.counts()
+    assert counts.get("ok", 0) > 0
+    assert counts.get("rejected:OVERLOADED", 0) > 0
+    assert front.engine.stats.result_frames_by_view == counts["ok"]
     front.close()
 
 

@@ -79,6 +79,80 @@ def test_result_roundtrip():
     np.testing.assert_array_equal(out.payload, res.payload)
 
 
+def _result(payload, request_id=7, lane="rows"):
+    return DeliveryResult(
+        request_id=request_id, tenant_id="tenant-0", lane=lane,
+        deliver="tokens", priority=0, payload=payload,
+        submitted_at=1.0, completed_at=1.0125, queue_depth_at_submit=2,
+        metadata={"n": 1},
+    )
+
+
+def _copied_frame(rid, res):
+    """A result frame as a payload copy: head, JSON header, ``tobytes()``."""
+    arr = np.ascontiguousarray(res.payload)
+    header = {
+        "rid": rid, "engine_rid": res.request_id, "tenant": res.tenant_id,
+        "lane": res.lane, "latency_ms": float(res.latency_ms),
+        "metadata": dict(res.metadata),
+        "dtype": arr.dtype.name, "shape": list(arr.shape),
+    }
+    return wire.encode_frame(wire.KIND_RES, header, arr.tobytes())
+
+
+@pytest.mark.parametrize("payload,lane", [
+    (np.arange(2 * 16 * 8 * 8, dtype=np.float32).reshape(2, 16, 8, 8) / 7,
+     "rows"),
+    (np.arange(3 * 5, dtype=np.int32).reshape(3, 5) - 4, "tokens"),
+    (np.zeros((0, 16, 8, 8), np.float32), "rows"),
+], ids=["float32-vision", "int32-tokens", "empty"])
+def test_result_parts_join_to_the_copied_frame(payload, lane):
+    """Head plus view, joined, are the bytes of the frame built by copying
+    the payload, and read back as the same result."""
+    res = _result(payload, lane=lane)
+    parts = wire.encode_result_parts("r-1", res)
+    frame = b"".join(parts)
+    assert frame == _copied_frame("r-1", res)
+    assert wire.encode_result("r-1", res) == frame
+    out = wire.decode_result(*_read(frame)[1:])
+    assert out.rid == "r-1" and out.engine_rid == 7 and out.lane == lane
+    assert out.payload.dtype == payload.dtype
+    np.testing.assert_array_equal(out.payload, payload)
+
+
+def test_result_parts_payload_is_a_readonly_view_of_the_result():
+    payload = np.random.default_rng(0).standard_normal(
+        (4, 16, 8, 8)).astype(np.float32)
+    head, body = wire.encode_result_parts("r-2", _result(payload))
+    assert isinstance(head, bytes) and isinstance(body, memoryview)
+    assert body.readonly and body.nbytes == payload.nbytes
+    assert np.shares_memory(np.asarray(body), payload)
+    with pytest.raises(TypeError):
+        body[0] = 1
+
+
+def test_result_parts_refuse_a_non_wire_dtype():
+    with pytest.raises(ProtocolError, match="not wire-transportable"):
+        wire.encode_result_parts("r-3", _result(np.ones(3, np.complex64)))
+    with pytest.raises(ProtocolError, match="not wire-transportable"):
+        wire.encode_result_parts("r-3", _result(np.array([object()])))
+
+
+def test_result_parts_of_a_non_contiguous_payload():
+    """A strided result is the one case that copies: its bytes are the
+    C-order bytes of the array, not of the buffer it views."""
+    base = np.arange(4 * 6 * 5, dtype=np.float32).reshape(4, 6, 5)
+    payload = base[:, ::2].transpose(0, 2, 1)
+    assert not payload.flags.c_contiguous
+    res = _result(payload)
+    head, body = wire.encode_result_parts("r-4", res)
+    assert not np.shares_memory(np.asarray(body), base)
+    assert head + bytes(body) == _copied_frame("r-4", res)
+    out = wire.decode_result(*_read(head + bytes(body))[1:])
+    assert out.payload.shape == payload.shape
+    np.testing.assert_array_equal(out.payload, payload)
+
+
 def test_reject_roundtrip_all_codes():
     for code in wire.REJECT_CODES:
         kind, header, payload = _read(wire.encode_reject("x-1", code, "why"))
