@@ -24,11 +24,11 @@ ROOT = Path(__file__).resolve().parents[1]
 
 def control_sample(cell, seed: int) -> dict:
     """Tenants and images of a sample the size of a run's."""
-    from bench import loadgen
     from bench.harness import loadgen_spec
+    from bench.lanes import rows_load
 
     spec = loadgen_spec(cell, 10.0)
-    pool = loadgen.image_pool(spec, seed)
+    pool = rows_load.image_pool(spec, seed)
     k, per = int(spec["check_per_tenant"]), int(spec["images_per_request"])
     tenants = np.repeat(np.arange(spec["tenants"]), k)
     rng = np.random.default_rng([seed, 4])

@@ -3,8 +3,13 @@
 Everything that belongs to a configuration, a traffic mix or a metric is a
 file the harness finds by name, from ``BENCHMARK.json`` at the root:
 
-* ``bench/configs/<config>.json`` (the workload's ``file``): geometry,
-  tenants, slot capacity, extra front-door flags, the limits of the check;
+* ``bench/configs/<config>.json`` (the workload's ``file``): its lane
+  (``"lane"``, ``rows`` when absent), and what the lane reads of it:
+  geometry, tenants, slot capacity, extra front-door flags, the limits of
+  the check;
+* ``bench/lanes/<lane>.py`` and ``<lane>_load.py``: everything that depends
+  on the lane the configuration serves, on the harness's side and on the
+  load generator's (``bench/lanes/rows.py`` lists what a lane gives);
 * ``bench/traffic/<traffic>.json``: the loop, arrivals, request sizes,
   popularity, depth, rate, and how many requests the check samples;
 * ``bench/metrics/<metric>.py``: a ``read(run)`` that returns the metric's
@@ -14,12 +19,13 @@ file the harness finds by name, from ``BENCHMARK.json`` at the root:
   the first dot (``flush_device_ms.py``).
 
 A run builds the system through the program's own entry points
-(``launch/serve.parse_args`` -> ``launch/server.build_front`` ->
-``make_server``), serves it on a loopback port, warms every microbatch
-shape the cell's traffic can reach, and lets ``bench/loadgen.py`` -- a
-child process that never imports JAX -- drive it for ``--seconds``.  Once
-the window has closed and the program's state is freed, a seeded sample of
-what was served is held to the float64 reference (``bench/reference.py``).
+(``launch/serve.parse_args`` with the lane's flags ->
+``launch/server.build_front`` -> ``make_server``), serves it on a loopback
+port, warms every shape the cell's traffic can reach, and lets
+``bench/loadgen.py`` -- a child process that never imports JAX -- drive it
+for ``--seconds``.  Once the window has closed and the program's state is
+freed, a seeded sample of what was served is held to the lane's plain
+reference (for ``rows``, the float64 convolution of ``bench/reference.py``).
 """
 from __future__ import annotations
 
@@ -35,14 +41,13 @@ import tempfile
 import threading
 import time
 from pathlib import Path
-from types import SimpleNamespace
+from types import ModuleType, SimpleNamespace
 
 import numpy as np
 
-from bench import reference, trace as tr, work
+from bench import trace as tr, work
 
 ROOT = Path(__file__).resolve().parents[1]
-LOADGEN = Path(__file__).resolve().parent / "loadgen.py"
 
 
 def log(msg: str) -> None:
@@ -58,6 +63,12 @@ class Cell:
     traffic: dict
     end_to_end: list
     per_layer: list
+    root: Path
+    lane: ModuleType            # bench/lanes/<lane>.py
+
+
+def lane_name(config: dict) -> str:
+    return config.get("lane", "rows")
 
 
 def _reports(metric: dict, cell: str) -> bool:
@@ -72,46 +83,40 @@ def load_cell(root: Path, name: str) -> Cell:
                        f"(have {sorted(cells)})")
     w = cells[name]
     cfg_entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    config = json.loads((root / cfg_entry["file"]).read_text())
+    lane = lane_name(config)
     return Cell(
         name=name,
         chips=int(w["chips"]),
-        config=json.loads((root / cfg_entry["file"]).read_text()),
+        config=config,
         traffic=json.loads(
             (root / "bench" / "traffic" / f"{w['traffic']}.json").read_text()
         ),
         end_to_end=[m for m in bench["end_to_end"] if _reports(m, name)],
         per_layer=[m for m in bench["per_layer"] if _reports(m, name)],
+        root=root,
+        lane=_load(root / "bench" / "lanes" / f"{lane}.py", f"bench_lane_{lane}"),
     )
+
+
+def _load(path: Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reader(root: Path, metric: str):
     path = root / "bench" / "metrics" / f"{metric}.py"
     if not path.exists():
         path = path.with_name(f"{metric.split('.')[0]}.py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
-
-
-def serve_flags(config: dict, seed: int) -> list[str]:
-    g = config["geometry"]
-    if (g["p"], g["stride"], g["pad"]) != (3, 1, 1):
-        raise ValueError("the front door serves p=3, stride 1, pad 1 only")
-    return [
-        "--mode", "serve", "--port", "0", "--seed", str(seed),
-        "--channels", str(g["alpha"]), "--out-channels", str(g["beta"]),
-        "--image-size", str(g["m"]), "--kappa", str(g["kappa"]),
-        "--tenants", str(config["tenants"]),
-        "--capacity", str(config["capacity"]),
-        *config.get("front_door_flags", []),
-    ]
+    return _load(path, f"bench_metric_{metric}").read
 
 
 def loadgen_spec(cell: Cell, seconds: float) -> dict:
-    g, t = cell.config["geometry"], dict(cell.traffic)
+    t = dict(cell.traffic)
     t.update(seconds=seconds, tenants=cell.config["tenants"],
-             channels=g["alpha"], image_size=g["m"])
+             lane=lane_name(cell.config), **cell.lane.loadgen_fields(cell.config))
     if t.get("jobs") == "one_per_tenant":
         t["jobs"] = cell.config["tenants"]
     return t
@@ -204,40 +209,12 @@ class Pauses:
         )
 
 
-def reachable_shapes(engine, cell: Cell) -> list[tuple[int, int]]:
-    """Every (G, B) microbatch bucket the cell's traffic can produce.
-
-    G: any number of groups up to the slot capacity, bucketed.  B: requests
-    whose image count is a multiple of the engine's chunk arrive as whole
-    chunks, so only the chunk size; otherwise any chunk length, bucketed.
-    """
-    from repro.runtime.queue import bucketize
-
-    q = engine.queue
-    groups = min(q.group_buckets[-1], engine.registry.capacity)
-    gs = sorted({bucketize(n, q.group_buckets) for n in range(1, groups + 1)})
-    per = int(cell.traffic["images_per_request"])
-    bs = ([engine.max_rows] if per % engine.max_rows == 0
-          else sorted({bucketize(n, q.row_buckets)
-                       for n in range(1, engine.max_rows + 1)}))
-    return [(g, b) for g in gs for b in bs]
-
-
-def warm(engine, shapes) -> None:
-    """Run the engine's own device step once at every shape."""
-    plan = engine._refresh_plan()
-    f_in, cap = engine.registry.geom.in_features, engine.registry.capacity
-    for g, b in shapes:
-        x = np.zeros((g, b, f_in), np.float32)
-        gidx = (np.arange(g) % cap).astype(np.int32)
-        np.asarray(engine._execute(x, gidx, plan))
-
-
 # -- spans around the program's layers (traced runs only) -------------------------
-def instrument(front, server, steps: list) -> None:
+def instrument(front, server, steps: list, lane: ModuleType) -> None:
     """Open a ``bench.*`` host span around each call into the flush phases
-    and the server's request and completion paths, and log each vision
-    microbatch's work as the device phase dispatches it."""
+    and the server's request and completion paths, and log the work of each
+    step the device phase dispatches (the lane's ``note``), behind the time
+    it was dispatched."""
     from jax.profiler import TraceAnnotation
 
     def wrap(obj, name: str, span: str, note=None):
@@ -253,13 +230,7 @@ def instrument(front, server, steps: list) -> None:
 
     def note(flush_work):
         now = time.perf_counter()
-        for item in flush_work.items:
-            if item.lane != "vision":
-                continue
-            mb = item.mb
-            slots = {int(mb.group_tenant[s.group]) for s in mb.slices}
-            steps.append((now, sum(s.n_rows for s in mb.slices), len(slots),
-                          *mb.x.shape[:2]))
+        steps.extend((now, *step) for step in lane.note(flush_work))
 
     eng = front.engine
     wrap(eng, "begin_flush", "bench.flush.coalesce")
@@ -279,19 +250,20 @@ def snapshot_stats(stats) -> dict:
         "flush_failures": stats.flush_failures,
         "phase_ms": {p: list(stats._phases_ms[p]) for p in FLUSH_PHASES},
         "submit_wait_ms": list(stats._submit_wait_ms),
+        "queue_wait_ms": list(stats._queue_wait_ms),
     }
 
 
 # -- the window ---------------------------------------------------------------------
-async def _serve_window(front, args, spec_path: Path, out_dir: Path, seed: int,
-                        seconds: float, hooks) -> int:
+async def _serve_window(front, args, loadgen: Path, spec_path: Path,
+                        out_dir: Path, seed: int, seconds: float, hooks) -> int:
     from repro.launch.server import make_server
 
     server = make_server(front, args)
     await server.start()
     hooks.server(server)
     proc = await asyncio.create_subprocess_exec(
-        sys.executable, str(LOADGEN), "--port", str(server.port),
+        sys.executable, str(loadgen), "--port", str(server.port),
         "--spec", str(spec_path), "--seed", str(seed), "--out", str(out_dir),
         stdin=asyncio.subprocess.PIPE, stdout=asyncio.subprocess.PIPE,
     )
@@ -324,6 +296,7 @@ def _requests(out_dir: Path) -> SimpleNamespace:
     doc = json.loads((out_dir / "requests.json").read_text())
     rows = doc["rows"]
     col = {c: i for i, c in enumerate(doc["columns"])}
+    own = ("due", "sent", "done", "outcome", "images", "tenant")
 
     def arr(name, dtype=np.float64):
         return np.array([np.nan if r[col[name]] is None else r[col[name]]
@@ -336,6 +309,7 @@ def _requests(out_dir: Path) -> SimpleNamespace:
         ok=np.array([o == "ok" for o in outcome], bool),
         lost=np.array([o in ("timeout", "dropped") for o in outcome], bool),
         outcome=outcome, closed_at=doc["closed_at"],
+        extra={c: [r[i] for r in rows] for c, i in col.items() if c not in own},
     )
 
 
@@ -352,8 +326,9 @@ def _quantile_ms(xs, q: float) -> float:
 
 
 class System:
-    """The cell's front door, built through the program's entry points and
-    warmed at every shape its traffic can reach; serves one or more windows.
+    """The cell's front door, built through the program's entry points with
+    its lane's flags and warmed at every shape its traffic can reach; serves
+    one or more windows.
     """
 
     def __init__(self, cell: Cell, seed: int):
@@ -366,11 +341,11 @@ class System:
         self.compiles = Compiles()
         jax.monitoring.register_event_duration_secs_listener(self.compiles)
         self.t_import = time.perf_counter()
-        self.args = parse_args(serve_flags(cell.config, seed))
+        self.args = parse_args(cell.lane.serve_flags(cell.config, seed))
         self.front = build_front(self.args)
         self.t_built = time.perf_counter()
-        self.shapes = reachable_shapes(self.front.engine, cell)
-        warm(self.front.engine, self.shapes)
+        self.shapes = cell.lane.reachable_shapes(self.front.engine, cell)
+        cell.lane.warm(self.front.engine, self.shapes)
         self.t_warm = time.perf_counter()
 
     def close(self) -> int:
@@ -402,7 +377,7 @@ class System:
         class Hooks:
             def server(self, server):
                 if traced:
-                    instrument(front, server, w.steps)
+                    instrument(front, server, w.steps, cell.lane)
 
             def go(self):
                 with front._cv:
@@ -432,7 +407,8 @@ class System:
                     )
 
         w.lost_server = asyncio.run(_serve_window(
-            front, self.args, out / "spec.json", out, seed, seconds, Hooks()
+            front, self.args, cell.root / "bench" / "loadgen.py",
+            out / "spec.json", out, seed, seconds, Hooks()
         ))
         w.req = _requests(out)
         with np.load(out / "sample.npz") as z:
@@ -452,12 +428,9 @@ class System:
         log(w.pauses)
         return w
 
-    def permutations(self) -> dict[int, np.ndarray]:
-        """Each tenant's secret channel permutation, from its provider's
-        record (the front door registers tenant ``i`` as ``tenant-i``)."""
-        reg = self.front.engine.registry
-        return {i: np.array(reg.session(f"tenant-{i}").provider._perm)
-                for i in range(self.cell.config["tenants"])}
+    def secrets(self):
+        """What the check reads of the tenants' secrets (the lane's)."""
+        return self.cell.lane.secrets(self.front, self.cell.config)
 
 
 def find_devices(cell: Cell, require_tpu: bool):
@@ -496,13 +469,12 @@ def run(root: Path, workload: str, seed: int, seconds: float, traced: bool,
     devices = find_devices(cell, require_tpu)
     if devices is None:
         return None
-    geom = dict(cell.config["geometry"])
     tmp = Path(tempfile.mkdtemp(prefix="bench-"))
     try:
         system = System(cell, seed)
         try:
             w = system.window(tmp, seconds, seed, traced)
-            perms = system.permutations()
+            secrets = system.secrets()
         finally:
             rc_front = system.close()
         setup_s = w.go - started
@@ -524,20 +496,9 @@ def run(root: Path, workload: str, seed: int, seconds: float, traced: bool,
 
         # The check, on the host, once the program's state is gone.
         lost = int(w.req.lost.sum()) + int(w.lost_server) + int(rc_front != 0)
-        kernels = reference.developer_kernels(geom, cell.config["tenants"], seed)
-        cmp = reference.compare(w.sample, geom, kernels, perms)
-        tenants = cell.config["tenants"]
-        sample = tenants * w.spec["check_per_tenant"] * w.spec["images_per_request"]
         # (name, value, limit, the value has to be at least the limit)
-        checks = [
-            ("max_abs_err", cmp["max_abs_err"],
-             cell.config["limits"]["max_abs_err"], False),
-            ("unpermuted_tenants", reference.unpermuted(perms, geom["beta"]), 0,
-             False),
-            ("lost", lost, 0, False),
-            ("compared_tenants", cmp["compared_tenants"], tenants, True),
-            ("compared_images", cmp["compared_images"], sample, True),
-        ]
+        checks = [*cell.lane.checks(cell.config, w.spec, w.sample, secrets, seed),
+                  ("lost", lost, 0, False)]
         correct = all(v >= lim if least else v <= lim
                       for _, v, lim, least in checks)
 
@@ -545,7 +506,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, traced: bool,
         record = SimpleNamespace(
             cell=cell, seconds=seconds, grace_s=float(w.spec.get("grace_s", 60.0)),
             req=w.req, setup_s=setup_s, memory_peak_bytes=memory_peak,
-            stats=w.stats, geom=geom, trace=None,
+            stats=w.stats, trace=None, **cell.lane.record_fields(cell.config),
         )
         device = {"platform": dev.platform, "kind": dev.device_kind,
                   "count": len(devices), "memory_peak_bytes": int(memory_peak)}
@@ -575,7 +536,7 @@ def run(root: Path, workload: str, seed: int, seconds: float, traced: bool,
 
 def reduce_trace(root: Path, w: SimpleNamespace, dev) -> dict:
     """The traced window for the metric readers: the plain trace and the
-    window's bounds, busy and window seconds, the work of each delivery step
+    window's bounds, busy and window seconds, the work of each step
     dispatched in the window, the chip's peaks, and the breakdown."""
     files = sorted(w.trace_dir.rglob("*.xplane.pb"))
     if not files:

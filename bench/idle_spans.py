@@ -47,11 +47,11 @@ def main(argv=None) -> int:
     stats = []
     snapshot = harness.snapshot_stats
 
-    def with_queue_waits(engine_stats):
-        stats.append(list(engine_stats._queue_wait_ms))
-        return snapshot(engine_stats)
+    def keep_stats(engine_stats):
+        stats.append(snapshot(engine_stats))
+        return stats[-1]
 
-    harness.snapshot_stats = with_queue_waits
+    harness.snapshot_stats = keep_stats
     result = harness.run(ROOT, args.workload, args.seed, args.seconds, True,
                          started=STARTED)
     if result is None or not seen:
@@ -68,7 +68,7 @@ def main(argv=None) -> int:
     total = (win[1] - win[0]) / 1e9 - (tr.busy_s(trace, win) or 0.0)
     harness.log(f"idle under no program span: {idle['no program span']:.4f} "
                 f"s of {total:.4f} s idle")
-    waits = stats[-1]
+    waits = stats[-1]["queue_wait_ms"]
     harness.log(f"queue wait: {len(waits)} requests, p50 "
                 f"{quantile(waits, 0.50)} ms, p99 {quantile(waits, 0.99)} ms")
     print(json.dumps(result), flush=True)

@@ -17,22 +17,27 @@ traffic mix from ``--seed``:
   moment a response lands.
 
 Tenants are drawn from a Zipf law (``popularity.theta``) in the open loop;
-in the closed loop job ``j`` is tenant ``j``.  Images come from a pool drawn
-from the seed, so the same seed sends the same bytes.
+in the closed loop job ``j`` is tenant ``j``.  What a request carries is the
+spec's lane's (``bench/lanes/<lane>_load.py``, loaded by path; ``rows``
+when the spec names none): each request draws an offset into the lane's
+payloads from the seed, so the same seed sends the same bytes.
 
 Protocol with the parent, one line each on stdout: ``ready`` once every
 connection is open; the window opens when ``go`` arrives on stdin; ``closed``
 when it closes (no request is issued after that); ``done`` once every
 request issued has an outcome (or the grace period ran out) and the results
 are written to ``--out``: ``requests.json`` (one row per request: due, sent
-and done seconds from the window's opening, outcome, images, tenant) and
-``sample.npz`` (for each tenant, a seeded uniform sample of its requests
-served ``ok``: their tenant, the images sent and the features served).
+and done seconds from the window's opening, outcome, images, tenant, then
+the lane's own columns) and ``sample.npz`` (for each tenant, a seeded
+uniform sample of its requests served ``ok``: their index and tenant, and
+what the lane keeps of each, for the rows lane the images sent and the
+features served).
 """
 from __future__ import annotations
 
 import argparse
 import asyncio
+import importlib.util
 import itertools
 import json
 import struct
@@ -53,14 +58,23 @@ MAX_FRAME = 256 * 1024 * 1024
 OK, REJECTED, TIMEOUT, DROPPED = "ok", "rejected", "timeout", "dropped"
 
 
-def encode_request(rid: str, tenant: str, images: np.ndarray) -> bytes:
-    images = np.ascontiguousarray(images, np.float32)
-    header = json.dumps({
-        "rid": rid, "tenant": tenant, "lane": "rows", "deliver": "tokens",
-        "priority": 0, "deadline_ms": None, "age_ms": 0.0, "metadata": {},
-        "dtype": "float32", "shape": list(images.shape),
-    }, separators=(",", ":")).encode()
-    body = images.tobytes()
+LANES = Path(__file__).resolve().parent / "lanes"
+
+
+def load_lane(name: str):
+    """The load side of lane ``name``: ``lanes/<name>_load.py`` beside this
+    file."""
+    path = LANES / f"{name}_load.py"
+    spec = importlib.util.spec_from_file_location(f"bench_lane_{name}_load", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def encode_request(rid: str, tenant: str, fields: dict, body: bytes) -> bytes:
+    """A request frame: ``rid``, ``tenant`` and a lane's header fields."""
+    header = json.dumps({"rid": rid, "tenant": tenant, **fields},
+                        separators=(",", ":")).encode()
     return HEAD.pack(MAGIC, KIND_REQ, len(header), len(body)) + header + body
 
 
@@ -85,21 +99,16 @@ async def read_frame(reader: asyncio.StreamReader):
     return kind, header, payload
 
 
-def decode_array(header: dict, payload: bytes) -> np.ndarray:
-    return np.frombuffer(payload, np.dtype(header["dtype"])).reshape(
-        header["shape"]
-    ).copy()
-
-
 # -- the traffic, drawn from the seed ------------------------------------------
 def zipf_weights(n: int, theta: float) -> np.ndarray:
     w = 1.0 / np.arange(1, n + 1, dtype=np.float64) ** theta
     return w / w.sum()
 
 
-def open_schedule(spec: dict, seed: int) -> dict:
-    """Due times (seconds from the window's opening), tenants and image
-    offsets of every request of an open-loop window, drawn from ``seed``."""
+def open_schedule(spec: dict, seed: int, choices: int) -> dict:
+    """Due times (seconds from the window's opening), tenants and payload
+    offsets (in ``[0, choices)``) of every request of an open-loop window,
+    drawn from ``seed``."""
     rng = np.random.default_rng([seed, 1])
     rate, seconds = float(spec["rate_per_s"]), float(spec["seconds"])
     n = int(rate * seconds * 1.5) + 64
@@ -112,26 +121,19 @@ def open_schedule(spec: dict, seed: int) -> dict:
         spec["tenants"], size=due.size, p=zipf_weights(spec["tenants"],
                                                        float(pop["theta"]))
     )
-    offsets = rng.integers(
-        0, spec["pool_images"] - spec["images_per_request"] + 1, size=due.size
-    )
+    offsets = rng.integers(0, choices, size=due.size)
     return {"due": due, "tenant": tenants, "offset": offsets}
-
-
-def image_pool(spec: dict, seed: int) -> np.ndarray:
-    rng = np.random.default_rng([seed, 0])
-    c, m = spec["channels"], spec["image_size"]
-    return rng.standard_normal((spec["pool_images"], c, m, m), np.float32)
 
 
 class _Request:
     __slots__ = ("idx", "tenant", "offset", "due", "sent", "done", "outcome",
-                 "ev")
+                 "extra", "ev")
 
     def __init__(self, idx: int, tenant: int, offset: int, due: float):
         self.idx, self.tenant, self.offset, self.due = idx, tenant, offset, due
         self.sent = self.done = None
         self.outcome = None
+        self.extra = None               # the lane's columns, on a response
         self.ev = asyncio.Event()
 
     def resolve(self, outcome: str, at: float | None) -> None:
@@ -146,8 +148,7 @@ class LoadGen:
     def __init__(self, spec: dict, seed: int, host: str, port: int):
         self.spec, self.seed = spec, seed
         self.host, self.port = host, port
-        self.images = int(spec["images_per_request"])
-        self.pool = image_pool(spec, seed)
+        self.lane = load_lane(spec.get("lane", "rows")).Load(spec, seed)
         self.reqs: dict[str, _Request] = {}
         self.t0 = 0.0
         self.closed_at = None
@@ -194,6 +195,7 @@ class LoadGen:
                     continue
                 self._open[conn].discard(r)
                 if kind == KIND_RES:
+                    r.extra = self.lane.response(header, payload)
                     self._keep(r, header, payload)
                     r.resolve(OK, now)
                 else:
@@ -209,22 +211,20 @@ class LoadGen:
         seen, kept = self._res.get(r.tenant, (0, []))
         self._res[r.tenant] = (seen + 1, kept)
         if len(kept) < k:
-            kept.append((r, decode_array(header, payload)))
+            kept.append((r, self.lane.keep(header, payload)))
             return
         j = int(self._res_rng.integers(0, seen + 1))
         if j < k:
-            kept[j] = (r, decode_array(header, payload))
+            kept[j] = (r, self.lane.keep(header, payload))
 
     @property
-    def sample(self) -> list[tuple[_Request, np.ndarray]]:
+    def sample(self) -> list[tuple[_Request, object]]:
         return sorted((s for _, kept in self._res.values() for s in kept),
                       key=lambda s: s[0].idx)
 
     async def _send(self, conn: int, r: _Request) -> None:
-        frame = encode_request(
-            str(r.idx), f"tenant-{r.tenant}",
-            self.pool[r.offset : r.offset + self.images],
-        )
+        frame = encode_request(str(r.idx), f"tenant-{r.tenant}",
+                               *self.lane.request(r.offset))
         r.sent = time.monotonic() - self.t0
         writer = self._conns[conn]
         if writer is None:
@@ -239,7 +239,7 @@ class LoadGen:
 
     # -- the two loops -------------------------------------------------------
     async def run_open(self) -> None:
-        sch = open_schedule(self.spec, self.seed)
+        sch = open_schedule(self.spec, self.seed, self.lane.choices)
         n_conn = len(self._conns)
         for i, (due, tenant, off) in enumerate(
             zip(sch["due"], sch["tenant"], sch["offset"])
@@ -257,7 +257,7 @@ class LoadGen:
     async def run_closed(self) -> None:
         seconds = float(self.spec["seconds"])
         counter = itertools.count()
-        hi = self.spec["pool_images"] - self.images + 1
+        hi = self.lane.choices
 
         async def job(j: int) -> None:
             inflight: list[_Request] = []
@@ -311,14 +311,17 @@ class LoadGen:
         await asyncio.gather(*self._readers, return_exceptions=True)
 
     def write(self, out: Path) -> None:
+        none = (None,) * len(self.lane.columns)
         rows = [
-            [r.due, r.sent, r.done, r.outcome, self.images, r.tenant]
+            [r.due, r.sent, r.done, r.outcome, self.lane.images, r.tenant,
+             *(none if r.extra is None else r.extra)]
             for r in sorted(self.reqs.values(), key=lambda r: r.idx)
         ]
         (out / "requests.json").write_text(json.dumps({
             "seconds": self.spec["seconds"],
             "closed_at": self.closed_at,
-            "columns": ["due", "sent", "done", "outcome", "images", "tenant"],
+            "columns": ["due", "sent", "done", "outcome", "images", "tenant",
+                        *self.lane.columns],
             "rows": rows,
         }))
         sample = self.sample
@@ -326,11 +329,7 @@ class LoadGen:
             out / "sample.npz",
             idx=np.array([r.idx for r, _ in sample], np.int64),
             tenant=np.array([r.tenant for r, _ in sample], np.int64),
-            images=np.stack([
-                self.pool[r.offset : r.offset + self.images] for r, _ in sample
-            ]) if sample else np.zeros((0,), np.float32),
-            served=np.stack([p for _, p in sample])
-            if sample else np.zeros((0,), np.float32),
+            **self.lane.sample([(r.offset, kept) for r, kept in sample]),
         )
 
 
@@ -362,8 +361,8 @@ def main(argv=None) -> int:
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, required=True)
     ap.add_argument("--spec", required=True,
-                    help="JSON file: the traffic mix with the cell's "
-                         "geometry, tenants and window length filled in")
+                    help="JSON file: the traffic mix with the cell's lane, "
+                         "tenants, window length and lane fields filled in")
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--out", required=True, help="directory for the results")
     args = ap.parse_args(argv)

@@ -1,6 +1,6 @@
 """Median time the front door spends encoding one result frame: the
 program's ``mole.server.encode`` span (launch/server.py ``_complete``:
-``wire.encode_result``), in the traced window."""
+``wire.encode_result_parts``), in the traced window."""
 from bench.spans import median_ms
 
 

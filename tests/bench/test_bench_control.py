@@ -12,7 +12,8 @@ from bench import control, reference
 from bench.harness import ROOT, load_cell
 
 
-@pytest.mark.parametrize("workload", ["vgg16_cifar.infer", "resnet20_cifar.train"])
+@pytest.mark.parametrize(
+    "workload", ["vgg16_cifar.infer", "resnet20_cifar.train", "vgg16_cifar.train"])
 def test_control_reads_above_the_limit(workload):
     cell = load_cell(ROOT, workload)
     small = dataclasses.replace(

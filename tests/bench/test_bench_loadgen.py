@@ -1,5 +1,6 @@
-"""The load generator (bench/loadgen.py) and the latency arithmetic the
-metric readers apply to what it records."""
+"""The load generator (bench/loadgen.py), the rows lane's load side
+(bench/lanes/rows_load.py), and the latency arithmetic the metric readers
+apply to what it records."""
 import json
 import subprocess
 import sys
@@ -11,18 +12,21 @@ import pytest
 import bench_tiny
 from bench import loadgen
 from bench.harness import ROOT, load_reader
+from bench.lanes import rows_load
 from bench.readout import latencies_s, quantile
 
 SPEC = dict(
     json.loads((ROOT / "bench/traffic/infer.json").read_text()),
     seconds=4.0, tenants=4, channels=3, image_size=32,
 )
+CHOICES = rows_load.Load(SPEC, 0).choices
 
 
 def test_loadgen_imports_no_jax():
     out = subprocess.run(
         [sys.executable, "-c",
          "import sys; sys.path.insert(0, 'bench'); import loadgen; "
+         "loadgen.load_lane('rows'); "
          "print(sorted(m for m in sys.modules if m.split('.')[0] == 'jax'))"],
         cwd=bench_tiny.ROOT, capture_output=True, text=True, timeout=60,
     )
@@ -31,13 +35,14 @@ def test_loadgen_imports_no_jax():
 
 
 def test_open_schedule_is_drawn_from_the_seed():
-    a = loadgen.open_schedule(SPEC, 2 ** 33 + 5)
-    b = loadgen.open_schedule(SPEC, 2 ** 33 + 5)
-    c = loadgen.open_schedule(SPEC, 2 ** 33 + 6)
+    a = loadgen.open_schedule(SPEC, 2 ** 33 + 5, CHOICES)
+    b = loadgen.open_schedule(SPEC, 2 ** 33 + 5, CHOICES)
+    c = loadgen.open_schedule(SPEC, 2 ** 33 + 6, CHOICES)
     for k in a:
         np.testing.assert_array_equal(a[k], b[k])
     assert a["due"].size != c["due"].size or not np.array_equal(a["due"], c["due"])
     assert np.all(np.diff(a["due"]) >= 0) and a["due"].max() < SPEC["seconds"]
+    assert 0 <= a["offset"].min() and a["offset"].max() < CHOICES
     # Poisson at the file's rate: the count is within a few deviations.
     mean = SPEC["rate_per_s"] * SPEC["seconds"]
     assert abs(a["due"].size - mean) < 5 * np.sqrt(mean)
@@ -47,22 +52,23 @@ def test_zipf_popularity():
     w = loadgen.zipf_weights(4, 0.99)
     assert w.sum() == pytest.approx(1.0)
     assert w[0] / w[1] == pytest.approx(2 ** 0.99)
-    sch = loadgen.open_schedule(dict(SPEC, seconds=50.0), 3)
+    sch = loadgen.open_schedule(dict(SPEC, seconds=50.0), 3, CHOICES)
     share = np.bincount(sch["tenant"], minlength=4) / sch["tenant"].size
     np.testing.assert_allclose(share, w, atol=0.02)
 
 
 def test_image_pool_is_drawn_from_the_seed():
-    a = loadgen.image_pool(SPEC, 11)
+    a = rows_load.image_pool(SPEC, 11)
     assert a.shape == (SPEC["pool_images"], 3, 32, 32) and a.dtype == np.float32
-    np.testing.assert_array_equal(a, loadgen.image_pool(SPEC, 11))
+    np.testing.assert_array_equal(a, rows_load.image_pool(SPEC, 11))
 
 
 def test_request_frame_is_the_wire_format():
     from repro.runtime import wire
 
     images = np.arange(2 * 3 * 4 * 4, dtype=np.float32).reshape(2, 3, 4, 4)
-    frame = loadgen.encode_request("7", "tenant-1", images)
+    frame = loadgen.encode_request("7", "tenant-1",
+                                   *rows_load.request_fields(images))
     kind, header, payload = wire.decode_frame(frame)
     rid, age, req = wire.decode_request(header, payload)
     assert kind == wire.KIND_REQ and rid == "7" and age == 0.0
