@@ -3,8 +3,10 @@
 Tracks per-tenant secret material (morph cores, token/channel/output
 permutations, seeds, snapshot payloads) from source expressions to sinks
 where it would cross the provider trust boundary: log/print/warn calls,
-exception constructor text, assert messages, ``wire.encode_*`` frames,
-``DeliveryResult.metadata``, and snapshot serializers.
+exception constructor text, assert messages, ``wire.encode_*`` frames
+(the decode lane's request frames carry prompt ids and ``max_new_tokens``,
+its result frames ``scores`` in the header), ``DeliveryResult.metadata`` and
+``DeliveryResult.scores``, and snapshot serializers.
 
 The analysis is intraprocedural and flow-insensitive: two propagation
 sweeps over each function body compute the set of tainted local names,
@@ -327,6 +329,10 @@ class _FunctionTaint:
             if kw.arg == "metadata" and self.is_tainted(kw.value):
                 self._emit("metadata-leak", node,
                            "secret material passed as metadata")
+            elif kw.arg == "scores" and self.is_tainted(kw.value):
+                # Decode results send their scores in the frame header.
+                self._emit("scores-leak", node,
+                           "secret material passed as result scores")
 
     def _sink_raise(self, stmt: ast.Raise) -> None:
         exc = stmt.exc

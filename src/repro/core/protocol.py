@@ -166,6 +166,7 @@ class SlotRegistry:
         self._order: list[str] = []
         self._clock = 0
         self._last_used: dict[str, int] = {}
+        self._pinned: set[str] = set()                # never evicted
         self.version = 0
         self.evictions = 0
         self._slot_log: list[tuple[int, int]] = []    # (version, slot)
@@ -220,7 +221,13 @@ class SlotRegistry:
                 slot = self.capacity
                 self._slot_tenant.extend([None] * self.capacity)
             else:
-                victim = min(self._slot_of, key=self._last_used.__getitem__)
+                free = [t for t in self._slot_of if t not in self._pinned]
+                if not free:
+                    raise RuntimeError(
+                        f"every one of the {self.capacity} slots is pinned; "
+                        f"no slot can go to tenant {tenant_id!r}"
+                    )
+                victim = min(free, key=self._last_used.__getitem__)
                 slot = self.evict(victim)
         self._slot_tenant[slot] = tenant_id
         self._slot_of[tenant_id] = slot
@@ -241,6 +248,31 @@ class SlotRegistry:
         self.evictions += 1
         self._log_slot(slot)
         return slot
+
+    def pin(self, tenant_id: str) -> None:
+        """Keep a resident tenant's slot: LRU eviction passes it by until
+        :meth:`unpin` (the decode lane pins a tenant while it has rows)."""
+        if tenant_id not in self._slot_of:
+            raise KeyError(f"tenant {tenant_id!r} holds no slot")
+        self._pinned.add(tenant_id)
+
+    def unpin(self, tenant_id: str | None = None) -> None:
+        """Let LRU eviction take the tenant's slot again (None: every
+        tenant's)."""
+        if tenant_id is None:
+            self._pinned.clear()
+        else:
+            self._pinned.discard(tenant_id)
+
+    def can_seat(self, tenant_id: str, keep=()) -> bool:
+        """Whether :meth:`slot_for` can give the tenant a slot without
+        evicting a pinned tenant or one of ``keep`` (the tenants a
+        microbatch being built already holds slots for)."""
+        if (tenant_id in self._slot_of or self._auto_capacity
+                or None in self._slot_tenant):
+            return True
+        return any(t not in self._pinned and t not in keep
+                   for t in self._slot_of)
 
     def ensure_resident(self, tenant_id: str) -> int:
         """Give a registered tenant a slot (LRU-evicting if needed)."""

@@ -21,6 +21,13 @@ rows, and the morph on rows cut to the core width, ``(G, B * kappa, q)``
 (``ops.morph_rows_grouped``).  Its grid mirrors the unbatched kernel with a
 leading group dimension: (G, B/bm, N/bn, K/bk).
 
+``tiled_group_gemm`` is the ragged form: rows sorted by group and padded to
+whole ``bm``-row tiles, each tile multiplied by its group's matrix.  It
+serves the dropless MoE (tokens grouped by expert, the kernel named
+``mole_expert_gmm``) and the batched decode's Aug-head (rows grouped by
+tenant slot): a group's matrix is read once per tile, and groups no row
+chose are never read.
+
 The contraction axis stays innermost ("arbitrary"), accumulated in an fp32
 VMEM scratch; the group axis is "arbitrary" too because its block mapping
 depends on the prefetched scalars.
@@ -38,7 +45,7 @@ from .aug_gemm import mxu_dot
 
 __all__ = [
     "grouped_aug_gemm",
-    "grouped_row_gemm",
+    "tiled_group_gemm",
 ]
 
 
@@ -111,28 +118,73 @@ def grouped_aug_gemm(
     )(gidx, t, c_acs)
 
 
-def grouped_row_gemm(
-    h: jax.Array,        # (R, K) one decode row per group
-    gidx: jax.Array,     # (R,) int32 slot index per row
-    tables: jax.Array,   # (S, K, N) stacked per-slot matrices (e.g. LM heads)
-    *,
-    bn: int = 128,
-    bk: int = 512,
-    interpret: bool,
-) -> jax.Array:
-    """Decode-shaped grouped GEMM: ``h[r] @ tables[gidx[r]]`` -> (R, N).
+def _tiled_kernel(tg_ref, nu_ref, x_ref, w_ref, o_ref, acc_ref, *, n_kk: int):
+    del tg_ref
+    i, kk = pl.program_id(0), pl.program_id(2)
 
-    Batched cross-tenant decode is a ``(G, d)``-row grouped GEMM — G groups
-    of exactly one row each — so this is :func:`grouped_aug_gemm` at
-    ``B = bm = 1``: the scalar-prefetched index_map still DMAs each row's
-    slot matrix straight out of the stacked array, and the 1-row block is
-    padded up to the fp32 (8, 128) min tile by Mosaic.  The ~8x row-pad
-    waste is noise next to the gather it avoids (each slot table is
-    ``K x N``, the row is ``K``).
+    @pl.when(kk == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(i < nu_ref[0])
+    def _():
+        acc_ref[...] += mxu_dot(x_ref[...], w_ref[0])
+
+    @pl.when(kk == n_kk - 1)
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def tiled_group_gemm(
+    x: jax.Array,            # (M, K) rows sorted by group, M = tiles * bm
+    tile_group: jax.Array,   # (tiles,) int32 group of each row tile
+    n_used: jax.Array,       # (1,) int32 tiles holding rows
+    w: jax.Array,            # (G, K, N) stacked per-group matrices
+    *,
+    bm: int,
+    bn: int,
+    bk: int,
+    interpret: bool,
+    name: str,
+    out_dtype=None,
+) -> jax.Array:
+    """``x[tile] @ w[tile_group[tile]]`` for each ``bm``-row tile -> (M, N),
+    in ``out_dtype`` (default ``x.dtype``), accumulated in float32.
+
+    Both index vectors are scalar-prefetched: each tile DMAs its group's
+    weight blocks straight from the stack.  Tiles past ``n_used`` hold no
+    rows: they skip the product (their output is zeros), and their weight
+    index stays on one block, so they read no weights.  Callers give the
+    unused tiles the last used tile's group, so nothing new is fetched.
     """
-    R, K = h.shape
-    assert gidx.shape == (R,), (h.shape, gidx.shape)
-    out = grouped_aug_gemm(
-        h[:, None, :], gidx, tables, bm=1, bn=bn, bk=bk, interpret=interpret
+    M, K = x.shape
+    N = w.shape[-1]
+    tiles = tile_group.shape[0]
+    assert w.shape[1] == K and M == tiles * bm, (x.shape, w.shape, tiles, bm)
+    assert N % bn == 0 and K % bk == 0, (N, bn, K, bk)
+    n_kk = K // bk
+
+    def w_map(i, j, kk, tg, nu):
+        used = i < nu[0]
+        return (tg[i], jnp.where(used, kk, 0), jnp.where(used, j, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(tiles, N // bn, n_kk),
+        in_specs=[
+            pl.BlockSpec((bm, bk), lambda i, j, kk, tg, nu: (i, kk)),
+            pl.BlockSpec((1, bk, bn), w_map),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, tg, nu: (i, j)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
     )
-    return out[:, 0, :]
+    return pl.pallas_call(
+        functools.partial(_tiled_kernel, n_kk=n_kk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype or x.dtype),
+        interpret=interpret,
+        name=name,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
+        ),
+    )(tile_group, n_used, x, w)

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from . import ref
 from .aug_gemm import aug_gemm
 from .dispatch import pallas_interpret, resolve_backend
-from .grouped import grouped_aug_gemm, grouped_row_gemm
+from .grouped import grouped_aug_gemm, tiled_group_gemm
 
 __all__ = [
     "morph_rows",
@@ -45,6 +45,7 @@ __all__ = [
     "aug_embed_grouped",
     "aug_embed_rows_grouped",
     "lm_head_rows_grouped",
+    "expert_ffn",
 ]
 
 # Mosaic's block rule: a block's last axis is a multiple of the 128-lane
@@ -351,9 +352,8 @@ def aug_embed_rows_grouped(
     tokens (R,) int (one *morphed* token per decode row), gidx (R,),
     tables (S, V, d) -> (R, d).  A gather stays a gather: like
     :func:`token_morph_grouped`, every backend routes to the XLA
-    formulation (no MACs to win back on the MXU), with the identity
-    arrangement — the continuous-batching steady state where row ``r``
-    serves slot ``r`` — reading the stacked tables fully in place.
+    formulation (no MACs to win back on the MXU), one gather of the R rows
+    read in place from the stacked tables.
     """
     resolve_backend(backend)
     return _aug_embed_rows_grouped(tokens, gidx, tables)
@@ -362,45 +362,180 @@ def aug_embed_rows_grouped(
 @jax.jit
 def _aug_embed_rows_grouped(tokens, gidx, tables):
     gidx = _safe_gidx(gidx, tables.shape[0])
-    return _with_arange_fast_case(
-        gidx, tables.shape[0],
-        lambda t_, g_: ref.aug_embed_rows_batched_ref(t_, tables),
-        lambda t_, g_: ref.aug_embed_rows_grouped_ref(t_, g_, tables),
-        tokens, gidx,
-    )
+    return ref.aug_embed_rows_grouped_ref(tokens, gidx, tables)
 
 
 def lm_head_rows_grouped(
     h: jax.Array, gidx: jax.Array, heads: jax.Array,
-    backend: str | None = None,
+    backend: str | None = None, keep: jax.Array | None = None,
 ) -> jax.Array:
     """Slot-indexed per-row LM-head GEMM — the batched-decode logits step.
 
     h (R, d) final hidden states (one per decode row), gidx (R,), heads
     (S, d, V) fused per-slot Aug-heads -> (R, V) morphed-order logits.
     Decode *is* a (R, d)-row grouped GEMM against the stacked heads: Pallas
-    backends run :func:`repro.kernels.grouped.grouped_row_gemm` (scalar-
-    prefetched in-place reads, rows padded to the min tile); the jnp
+    backends sort the rows by slot and run
+    :func:`repro.kernels.grouped.tiled_group_gemm` (the kernel
+    ``mole_lm_head_grouped``), so each slot's head is read once per tile of
+    its rows, in place, and a slot no row uses is not read; the jnp
     backend mirrors ``models.stack.lm_head``'s dtype semantics exactly
     (contraction in ``h.dtype``) so batched decode emits bit-identical
     logits, with the identity arrangement contracting against the stack in
-    place as one batched einsum.
+    place as one batched einsum.  ``keep`` (R,) bool, if given, spares the
+    Pallas backends the other rows (their logits are zeros there), so a
+    slot only such rows use is not read.
     """
-    return _lm_head_rows_grouped(h, gidx, heads, resolve_backend(backend))
+    return _lm_head_rows_grouped(h, gidx, heads, resolve_backend(backend),
+                                 keep=keep)
 
 
 @partial(jax.jit, static_argnames=("backend",))
-def _lm_head_rows_grouped(h, gidx, heads, backend):
+def _lm_head_rows_grouped(h, gidx, heads, backend, keep=None):
     gidx = _safe_gidx(gidx, heads.shape[0])
     if backend != "jnp":
-        bn, bk = _gemm_tiles(*heads.shape[1:])
-        return grouped_row_gemm(
-            h, gidx, heads, bn=bn, bk=bk,
-            interpret=pallas_interpret(backend),
-        )
+        # Rows grouped by slot: each slot's head is read once per tile of
+        # its rows, not once per row.
+        bm = min(128, -(-h.shape[0] // _SUBLANE) * _SUBLANE)
+        out = _tiled_gemm(h, gidx, heads, bm, backend, "mole_lm_head_grouped",
+                          out_dtype=jnp.float32, keep=keep)
+        return out.astype(h.dtype)
     return _with_arange_fast_case(
         gidx, heads.shape[0],
         lambda h_, g_: ref.lm_head_rows_batched_ref(h_, heads),
         lambda h_, g_: ref.lm_head_rows_grouped_ref(h_, g_, heads),
         h, gidx,
     )
+
+
+# -- ragged groups: rows sorted by group, padded to whole tiles -------------------
+def _block_tiles(K: int, N: int, itemsize: int,
+                 budget: int = 2 * 2 ** 20) -> tuple[int, int]:
+    """(bn, bk) of a weight block: the largest ``bk x bn`` block within
+    ``budget`` bytes, each axis a multiple of 128 dividing it or the whole
+    axis; ties go to the longer contraction."""
+    def sizes(dim):
+        return [t for t in range(_LANE, dim + 1, _LANE) if dim % t == 0] or [dim]
+
+    fits = [(bn * bk, bk, bn) for bn in sizes(N) for bk in sizes(K)
+            if bn * bk * itemsize <= budget]
+    _, bk, bn = max(fits) if fits else (0, sizes(K)[0], sizes(N)[0])
+    return bn, bk
+
+
+def tile_count(rows: int, n_groups: int, bm: int) -> int:
+    """Tiles enough for ``rows`` rows in ``n_groups`` groups, each group
+    padded to whole ``bm``-row tiles, whatever the grouping:
+    ``G + (rows - G) // bm`` with ``G = min(n_groups, rows)`` bounds
+    ``sum_g ceil(n_g / bm)``."""
+    g = min(n_groups, rows)
+    return g + (rows - g) // bm
+
+
+def group_tiles(groups: jax.Array, n_groups: int, bm: int,
+                keep: jax.Array | None = None) -> dict:
+    """Tile layout of rows sorted by group, each group padded to whole
+    ``bm``-row tiles (:func:`tile_count` of them).
+
+    ``groups`` (P,) int: each row's group; ``keep`` (P,) bool, if given,
+    leaves the other rows out: they sort last and take no tile.  Returns
+    ``order`` (P,) the rows in group order, ``slot`` (P,) where each sorted
+    row sits in the padded buffer (a left-out row's lies past its end),
+    ``tile_group`` (tiles,) each tile's group (unused tiles repeat the last
+    used tile's) and ``n_used`` (1,).
+    """
+    P = groups.shape[0]
+    n_tiles = tile_count(P, n_groups, bm)
+    groups = groups.astype(jnp.int32)
+    if keep is not None:
+        groups = jnp.where(keep, groups, n_groups)
+    order = jnp.argsort(groups, stable=True)
+    sorted_g = groups[order]
+    counts = jnp.zeros((n_groups + 1,), jnp.int32).at[groups].add(1)
+    starts = jnp.cumsum(counts) - counts               # first sorted row
+    tiles_of = (counts[:n_groups] + bm - 1) // bm
+    tile_end = jnp.cumsum(tiles_of)
+    tile_start = jnp.append(tile_end - tiles_of, n_tiles)
+    rank = jnp.arange(P, dtype=jnp.int32) - starts[sorted_g]
+    slot = tile_start[sorted_g] * bm + rank
+    n_used = tile_end[-1]
+    i = jnp.arange(n_tiles, dtype=jnp.int32)
+    tg = jnp.searchsorted(tile_end, jnp.minimum(i, n_used - 1), side="right")
+    return {"order": order, "slot": slot, "tile_group": tg.astype(jnp.int32),
+            "n_used": n_used.reshape(1).astype(jnp.int32)}
+
+
+def _tiled_gemm(x, gidx, w, bm, backend, name, out_dtype=None, keep=None):
+    """Rows ``x`` (P, K) in groups ``gidx`` (P,), each times its group's
+    ``w[g]`` (K, N) -> (P, N) in the original row order; rows outside
+    ``keep`` are not computed (zeros)."""
+    P = x.shape[0]
+    tiles = group_tiles(gidx, w.shape[0], bm, keep)
+    buf = jnp.zeros((tile_count(P, w.shape[0], bm) * bm, x.shape[1]), x.dtype)
+    buf = buf.at[tiles["slot"]].set(x[tiles["order"]], mode="drop")
+    out = _tiled_products(buf, tiles, w, bm, backend, name, out_dtype)
+    back = jnp.zeros((x.shape[0],), jnp.int32).at[tiles["order"]].set(
+        tiles["slot"])
+    return out.at[back].get(mode="fill", fill_value=0)
+
+
+def _tiled_products(buf, tiles, w, bm, backend, name, out_dtype=None):
+    """``buf``'s ``bm``-row tiles times their groups' matrices: the Pallas
+    kernel, or on the jnp backend one einsum over the tiles' gathered
+    matrices."""
+    dt = out_dtype or buf.dtype
+    if backend == "jnp":
+        xt = buf.reshape(-1, bm, buf.shape[1])
+        out = jnp.einsum("tbk,tkn->tbn", xt, w[tiles["tile_group"]].astype(
+            buf.dtype), preferred_element_type=jnp.float32)
+        used = jnp.arange(xt.shape[0]) < tiles["n_used"][0]
+        out = jnp.where(used[:, None, None], out, 0.0)
+        return out.reshape(buf.shape[0], -1).astype(dt)
+    bn, bk = _block_tiles(w.shape[1], w.shape[2], w.dtype.itemsize)
+    return tiled_group_gemm(
+        buf, tiles["tile_group"], tiles["n_used"], w, bm=bm, bn=bn, bk=bk,
+        interpret=pallas_interpret(backend), name=name, out_dtype=dt,
+    )
+
+
+def expert_ffn(x: jax.Array, experts: jax.Array, weights: jax.Array,
+               wg: jax.Array, wu: jax.Array, wd: jax.Array, *, act: str,
+               keep: jax.Array | None = None,
+               backend: str | None = None) -> jax.Array:
+    """Dropless routed experts: token ``t`` goes through each of its experts
+    ``experts[t]`` (T, k), weighted by ``weights[t]`` (T, k), and the
+    weighted outputs sum -> (T, d).  Tokens outside ``keep`` (T,) bool, if
+    given, go to no expert (their output is zeros).
+
+    The (token, expert) pairs are sorted by expert and padded to whole row
+    tiles (:func:`group_tiles`); the gated FFN then runs as three grouped
+    GEMMs, the Pallas kernel ``mole_expert_gmm``, which reads each chosen
+    expert's matrices once per tile of its tokens and never reads an expert
+    no token chose.
+    """
+    return _expert_ffn(x, experts, weights, wg, wu, wd, act=act,
+                       backend=resolve_backend(backend), keep=keep)
+
+
+@partial(jax.jit, static_argnames=("act", "backend"))
+def _expert_ffn(x, experts, weights, wg, wu, wd, act, backend, keep=None):
+    from ..models.layers import act_fn
+
+    T, k = experts.shape
+    E = wg.shape[0]
+    P = T * k
+    per = -(-2 * P // E)
+    bm = min(128, max(_SUBLANE, 1 << (per - 1).bit_length()))
+    tiles = group_tiles(experts.reshape(-1), E, bm,
+                        None if keep is None else jnp.repeat(keep, k))
+    tok = tiles["order"] // k
+    buf = jnp.zeros((tile_count(P, E, bm) * bm, x.shape[1]), x.dtype)
+    buf = buf.at[tiles["slot"]].set(x[tok], mode="drop")
+    g = _tiled_products(buf, tiles, wg, bm, backend, "mole_expert_gmm")
+    u = _tiled_products(buf, tiles, wu, bm, backend, "mole_expert_gmm")
+    h = (act_fn(act)(g) * u).astype(x.dtype)
+    out = _tiled_products(h, tiles, wd, bm, backend, "mole_expert_gmm")
+    w = weights.reshape(-1)[tiles["order"]].astype(jnp.float32)
+    y = jnp.zeros(x.shape, jnp.float32).at[tok].add(
+        out.at[tiles["slot"]].get(mode="fill", fill_value=0).astype(
+            jnp.float32) * w[:, None])
+    return y.astype(x.dtype)

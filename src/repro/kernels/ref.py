@@ -140,22 +140,10 @@ def aug_embed_rows_grouped_ref(
 ) -> jax.Array:
     """Per-row slot-indexed AugE gather (batched decode: one token per row).
 
-    tokens: (R,) int, gidx: (R,), tables: (S, V, d) -> (R, d).
+    tokens: (R,) int, gidx: (R,), tables: (S, V, d) -> (R, d): one gather
+    of R rows, read in place from the stack.
     """
-
-    def step(_, inp):
-        t, i = inp
-        e = jax.lax.dynamic_index_in_dim(tables, i, 0, keepdims=False)
-        return None, e[t]
-
-    _, out = jax.lax.scan(step, None, (tokens, gidx))
-    return out
-
-
-def aug_embed_rows_batched_ref(tokens: jax.Array, tables: jax.Array) -> jax.Array:
-    """Per-row AugE gather, one resident table per row (the identity-order
-    fast case): tokens (R,), tables (R, V, d) -> (R, d)."""
-    return jax.vmap(lambda e, t: e[t])(tables, tokens)
+    return tables[gidx, tokens]
 
 
 def lm_head_rows_grouped_ref(

@@ -35,6 +35,14 @@ network chaos.  Drive it with the load-generating client fleet
     PYTHONPATH=src python -m repro.launch.client --spawn-server --chaos \
         --requests 64 --report fleet-report.json
 
+With ``--arch`` the front door serves LM tenants instead: the developer's
+seeded model, one secret vocab permutation per tenant, and generation
+requests (``lane="decode"``: prompt ids and ``max_new_tokens``) decoded in
+one batched step over ``--rows`` rows:
+
+    PYTHONPATH=src python -m repro.launch.serve --mode serve --port 0 \
+        --arch deepseek_v2_lite_16b --layers 5 --tenants 4 --rows 128
+
 ``--async`` works in the two **local** modes: traffic goes through the async front
 door (``repro.runtime.async_engine``) — a background flusher with a
 ``--max-delay-ms`` latency SLO and per-tenant admission control
@@ -63,7 +71,9 @@ import numpy as np
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.core.deploy import fuse_lm_params
 from repro.data.pipeline import DataConfig, SyntheticLM
-from repro.launch.steps import make_decode_step, make_prefill_step
+from repro.launch.steps import (
+    make_decode_step, make_prefill_step, rows_supported,
+)
 from repro.models.api import Model
 from repro.models.base import MoLeCfg
 
@@ -326,7 +336,7 @@ def run_lm(args) -> np.ndarray:
     # ---- developer side ---------------------------------------------------
     max_len = args.prompt_len + args.gen + 1
     final = np.zeros((args.requests, args.gen), np.int64)
-    use_lane = use_mole and cfg.frontend is None and cfg.family != "audio"
+    use_lane = use_mole and rows_supported(cfg)
     if use_lane:
         # Continuous-batched cross-tenant decode: every request becomes a
         # lane row; all tenants decode in one shared batched step against
@@ -440,9 +450,14 @@ _FLAGS = {
     "--channels": ("channels", 3, ("delivery", "serve")),
     "--out-channels": ("out_channels", 16, ("delivery", "serve")),
     "--image-size": ("image_size", 16, ("delivery", "serve")),
-    # lm-only
-    "--arch": ("arch", None, ("lm",)),
-    "--smoke": ("smoke", False, ("lm",)),
+    # lm (and serve: LM tenants at the front door)
+    "--arch": ("arch", None, ("lm", "serve")),
+    "--smoke": ("smoke", False, ("lm", "serve")),
+    "--layers": ("layers", None, ("serve",)),
+    "--rows": ("rows", 16, ("serve",)),
+    "--max-len": ("max_len", 2048, ("serve",)),
+    "--prompt-buckets": ("prompt_buckets", "32,64,128,256,512,1024",
+                         ("serve",)),
     "--prompt-len": ("prompt_len", 32, ("lm",)),
     "--gen": ("gen", 16, ("lm",)),
     "--mole": ("mole", "token", ("lm",)),
@@ -561,6 +576,21 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--prompt-len", type=int, default=None)
     ap.add_argument("--gen", type=int, default=None)
     ap.add_argument("--mole", default=None, choices=["off", "token"])
+    # serve with --arch: LM tenants behind the front door
+    ap.add_argument("--layers", type=int, default=None,
+                    help="[serve --arch] serve the first N layers of the "
+                         "model (its prefix layers plus whole groups), every "
+                         "width as published")
+    ap.add_argument("--rows", type=int, default=None,
+                    help="[serve --arch] decode rows: sequences decoded "
+                         "together in one batched step")
+    ap.add_argument("--max-len", type=int, default=None,
+                    help="[serve --arch] cache positions per row (prompt "
+                         "plus generated tokens)")
+    ap.add_argument("--prompt-buckets", default=None, metavar="L0,L1,...",
+                    help="[serve --arch] prefill lengths prompts are padded "
+                         "to (one compile each); the largest is the longest "
+                         "prompt served")
     # serve-only options (the network front door; error elsewhere)
     ap.add_argument("--host", default=None,
                     help="[serve] bind address (default 127.0.0.1)")
@@ -601,6 +631,17 @@ def parse_args(argv=None) -> argparse.Namespace:
     args = ap.parse_args(argv)
 
     mode = args.mode or ("lm" if args.arch else "delivery")
+    if mode == "serve" and args.arch is None:
+        for flag in ("--smoke", "--layers", "--rows", "--max-len",
+                     "--prompt-buckets"):
+            if getattr(args, _FLAGS[flag][0]) is not None:
+                ap.error(f"{flag} requires --arch")
+    if mode == "serve" and args.arch is not None:
+        for flag in ("--kappa", "--channels", "--out-channels",
+                     "--image-size", "--warm-batch"):
+            if getattr(args, _FLAGS[flag][0]) is not None:
+                ap.error(f"{flag} describes vision tenants; --arch serves "
+                         f"LM tenants")
     for flag, (dest, _, modes) in _FLAGS.items():
         if mode not in modes and getattr(args, dest) is not None:
             ap.error(

@@ -499,17 +499,87 @@ def developer_kernels(geom, tenants: int, seed: int) -> list[np.ndarray]:
     ]
 
 
-def build_front(args) -> AsyncDeliveryEngine:
-    """Build registry + engine + async front door from serve.py flags:
-    register ``--tenants`` vision tenants, warm the flush path so the first
-    served request doesn't pay compilation, restore the latest snapshot
-    when ``--snapshot-dir`` holds one (same id space across restarts), and
-    fill the per-tenant security-budget line."""
+def lm_config(args):
+    """The LM configuration ``--arch`` names (``--smoke``: its reduced
+    test size), cut to ``--layers`` layers when given: the first layers of
+    the published stack, every width as published."""
+    import dataclasses
+
+    from repro.configs import get_config, get_smoke_config
+
+    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    if args.layers is not None:
+        fixed = len(cfg.prefix_pattern) + len(cfg.suffix_pattern)
+        groups, rem = divmod(args.layers - fixed, len(cfg.block_pattern))
+        if groups < 1 or rem:
+            raise ValueError(
+                f"--layers {args.layers} cannot be {fixed} unscanned layers "
+                f"plus whole groups of {len(cfg.block_pattern)}"
+            )
+        cfg = dataclasses.replace(cfg, n_groups=groups)
+    return cfg
+
+
+def _build_lm_engine(args):
+    """LM tenants: the developer's seeded model (``--arch`` at ``--seed``),
+    its embedding and untied head registered for ``--tenants`` tenants, each
+    with its own secret vocab permutation; a decode lane of ``--rows`` rows
+    over the trunk alone; one warm generation per tenant.  (No security
+    budget line: the vocab permutation is a substitution cipher, see
+    ``core.security``.)"""
+    import jax
+
+    from repro.core.lm import LMSessionRegistry
+    from repro.launch.serve import _weights_of
+    from repro.launch.steps import layer_params, rows_supported
+    from repro.models.api import Model
+    from repro.runtime import (
+        ContinuousDecodeLane, DeliveryRequest, MoLeDeliveryEngine,
+    )
+
+    cfg = lm_config(args)
+    if not rows_supported(cfg) or cfg.tie_embeddings:
+        raise ValueError(f"{cfg.name} cannot be served by the decode lane")
+    model = Model(cfg)
+    params = model.init(jax.random.key(args.seed))
+    # The trunk stays on the device; the embedding and head go to the
+    # tenants' fused tables only.
+    embed = np.asarray(params.pop("embed"), np.float32)
+    head = np.asarray(params.pop("head"), np.float32)
+    params = layer_params(params, cfg, free=True)
+    capacity = args.capacity if args.capacity is not None else args.tenants
+    registry = LMSessionRegistry(cfg.vocab, cfg.d_model, capacity=capacity)
+    weights = _weights_of(args, args.tenants)
+    for i in range(args.tenants):
+        registry.register(f"tenant-{i}", embed, weight=weights[i], head=head)
+    del embed, head
+    buckets = tuple(int(b) for b in args.prompt_buckets.split(","))
+    engine = MoLeDeliveryEngine(
+        lm_registry=registry, backend=args.backend or None,
+        max_rows=8, row_buckets=(1, 2, 4, 8), seq_buckets=buckets,
+    )
+    engine.attach_decode(ContinuousDecodeLane(
+        model, params, registry, rows=args.rows, max_len=args.max_len,
+        backend=args.backend or None, scheduler=engine.scheduler,
+        prompt_buckets=buckets,
+    ))
+    warm = [
+        engine.submit(DeliveryRequest(
+            f"tenant-{i}", np.zeros(buckets[0], np.int32), lane="decode",
+            max_new_tokens=2,
+        ))
+        for i in range(args.tenants)
+    ]
+    return engine, warm, {}
+
+
+def _build_vision_engine(args):
+    """Vision tenants at ``--channels``/``--image-size``/``--out-channels``:
+    each its developer's seeded kernels, secret morph core and channel
+    permutation; a warm request of ``--warm-batch`` images per tenant."""
     from repro.core import ConvGeometry, SessionRegistry
     from repro.core.security import log2_p_m_bruteforce
-    from repro.runtime import (
-        DeliveryRequest, EngineStats, FailureInjector, MoLeDeliveryEngine,
-    )
+    from repro.runtime import DeliveryRequest, MoLeDeliveryEngine
 
     geom = ConvGeometry(alpha=args.channels, beta=args.out_channels,
                         m=args.image_size, p=3)
@@ -523,8 +593,14 @@ def build_front(args) -> AsyncDeliveryEngine:
         registry.register(f"tenant-{i}", k, weight=weights[i])
 
     engine = MoLeDeliveryEngine(registry, backend=args.backend or None)
-    # Warm the (G, B) buckets the fleet's steady state will hit, so served
-    # latency is the flush, not XLA compilation.
+    # Security budget on the served path: the brute-force attack-success
+    # bound for each tenant's morphing secrets (paper §4.2), so --stats
+    # reports privacy next to latency.
+    budget = {
+        t: log2_p_m_bruteforce(sigma=0.5, alpha=geom.alpha, m=geom.m,
+                               kappa=args.kappa)
+        for t in registry.tenant_ids
+    }
     warm = [
         engine.submit(DeliveryRequest(
             f"tenant-{i}",
@@ -532,6 +608,22 @@ def build_front(args) -> AsyncDeliveryEngine:
         ))
         for i in range(args.tenants)
     ]
+    return engine, warm, budget
+
+
+def build_front(args) -> AsyncDeliveryEngine:
+    """Build registry + engine + async front door from serve.py flags:
+    register ``--tenants`` tenants (LM tenants with a decode lane when
+    ``--arch`` names a model, vision tenants otherwise), warm the flush path
+    so the first served request doesn't pay compilation, restore the latest
+    snapshot when ``--snapshot-dir`` holds one (same id space across
+    restarts), and fill the per-tenant security-budget line."""
+    from repro.runtime import EngineStats, FailureInjector
+
+    engine, warm, budget = (_build_lm_engine(args) if args.arch
+                            else _build_vision_engine(args))
+    # Warm the buckets the fleet's steady state will hit, so served latency
+    # is the flush, not XLA compilation.
     engine.flush()
     for rid in warm:
         engine.take(rid)
@@ -571,13 +663,7 @@ def build_front(args) -> AsyncDeliveryEngine:
             # them into the futures we drop.
             print(f"restored snapshot: {len(replayed)} in-flight rids "
                   f"replayed, id space resumed", flush=True)
-    # Security budget on the served path: the brute-force attack-success
-    # bound for each tenant's morphing secrets (paper §4.2), so --stats
-    # reports privacy next to latency.
-    for t in registry.tenant_ids:
-        engine.stats.security_budget_log2[t] = log2_p_m_bruteforce(
-            sigma=0.5, alpha=geom.alpha, m=geom.m, kappa=args.kappa
-        )
+    engine.stats.security_budget_log2.update(budget)
     return front
 
 

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 from typing import Any, Callable
 
 import jax
@@ -52,6 +53,21 @@ class MLACfg:
     qk_nope: int = 128
     qk_rope: int = 64
     v_head: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnCfg:
+    """DeepSeek's YaRN rope scaling (``rope_scaling`` of a checkpoint's
+    config, type "yarn"): rotary frequencies blended between interpolated
+    and extrapolated by a ramp over ``[beta_slow, beta_fast]`` rotations,
+    and attention logits scaled by ``mscale(mscale_all_dim) ** 2``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,6 +129,7 @@ class ModelConfig:
     parallel_block: bool = False          # command-r style attn+ffn in parallel
     post_norm: bool = False               # gemma2 extra post-sublayer norms
     rope_theta: float = 10_000.0
+    rope_scaling: YarnCfg | None = None   # YaRN (DeepSeek-V2); None = plain
     sliding_window: int | None = None
     attn_scale: float | None = None       # None => head_dim ** -0.5
     attn_softcap: float | None = None
@@ -233,9 +250,49 @@ def init_params(key: jax.Array, schema: Any, dtype) -> Any:
             else:
                 fan_in = d.shape[-2] if len(d.shape) >= 2 else d.shape[-1]
                 scale = (1.0 / math.sqrt(fan_in)) if d.scale is None else d.scale
-            v = (jax.random.normal(k, d.shape, jnp.float32) * scale).astype(dt)
+            v = _normal(k, d.shape, scale, dt)
         out.append(v)
     return jax.tree.unflatten(treedef, out)
+
+
+# Leaves above this many elements are drawn block by block: each block by one
+# program straight into the leaf's buffer, so the device never holds more
+# than the leaf and one block's float32 draw.
+_DRAW_SLICE = 2 ** 26
+
+
+def _normal(key: jax.Array, shape: tuple, scale: float, dt) -> jax.Array:
+    """``N(0, scale^2)`` in ``dt``; a large leaf drawn in blocks of at most
+    ``_DRAW_SLICE`` elements (each from its own key), one block at a time,
+    so that a full-size layer stack fits the device while it is drawn and
+    the device's peak does not depend on how far dispatch runs ahead.  A
+    leaf within one block keeps its single draw: its temporaries are one
+    leaf's, and the fused block program rounds the scaling differently, so
+    drawing it that way would change every seeded model's weights."""
+    n = int(np.prod(shape))
+    if len(shape) < 2 or n <= _DRAW_SLICE:
+        return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dt)
+    # Blocks along the first axis whose trailing extent fits a block.
+    axis = next(a for a in range(len(shape))
+                if int(np.prod(shape[a + 1:])) <= _DRAW_SLICE)
+    step = max(1, _DRAW_SLICE // int(np.prod(shape[axis + 1:])))
+    out = jnp.zeros(shape, dt)
+    starts = np.ndindex(*shape[:axis], -(-shape[axis] // step))
+    for i, idx in enumerate(starts):
+        start = (*idx[:-1], idx[-1] * step) + (0,) * (len(shape) - axis - 1)
+        block = ((1,) * axis + (min(step, shape[axis] - start[axis]),)
+                 + tuple(shape[axis + 1:]))
+        out = _draw_into(out, jax.random.fold_in(key, i),
+                         tuple(np.int32(x) for x in start), np.float32(scale),
+                         block=block)
+        out.block_until_ready()
+    return out
+
+
+@partial(jax.jit, static_argnames=("block",), donate_argnums=(0,))
+def _draw_into(out, key, start, scale, *, block):
+    draw = jax.random.normal(key, block, jnp.float32) * scale
+    return jax.lax.dynamic_update_slice(out, draw.astype(out.dtype), start)
 
 
 def stacked(n: int, d: ParamDef) -> ParamDef:

@@ -16,14 +16,14 @@ Every kind provides:
   apply_<kind>(p, h, cfg, rs, cache)          -> (h, cache')
   cache_<kind>(cfg, batch, max_len)           -> ParamDef tree for its cache
 
-``rs`` is a RunState: mode ("full" for train/prefill, "decode"), scalar decode
-position ``t``, optional cross-attention context.  In "full" mode with a cache
+``rs`` is a RunState: mode ("full" for train/prefill, "decode"), decode
+position ``t`` (a scalar; MLA also takes one per row), optional cross-attention
+context.  In "full" mode with a cache
 tree supplied, blocks also *write* their caches (prefill).
 """
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any
 
 import jax
@@ -96,25 +96,21 @@ def schema_moe(cfg: ModelConfig) -> dict:
     return sch
 
 
-def moe_capacity(n_tokens: int, cfg: ModelConfig) -> int:
-    m = cfg.moe
-    c = math.ceil(n_tokens * m.top_k * m.capacity_factor / m.n_routed)
-    return max(8, -(-c // 8) * 8)  # round up to a multiple of 8
-
-
 def apply_moe(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     """MoE FFN dispatcher.
 
     Under a mesh with a "model" axis, uses the shard_map EP implementation
     (each device dispatches only its LOCAL tokens to its LOCAL experts and the
     partial outputs are psum'd over "model" — full data-parallelism preserved;
-    see EXPERIMENTS.md §Perf hillclimb 1).  Without a mesh (smoke tests,
-    single-device runs), falls back to the global capacity-buffer form below.
+    see EXPERIMENTS.md §Perf hillclimb 1).  Without a mesh (serving, smoke
+    tests, single-device runs), the dropless form :func:`moe_dropless`.
     """
     mesh = _ambient_mesh()
     if mesh is not None and "model" in mesh.axis_names:
         return _apply_moe_sharded(p, x, cfg, mesh)
-    return _apply_moe_dense(p, x, cfg)
+    B, S, d = x.shape
+    y, _ = moe_dropless(p, x.reshape(B * S, d), cfg)
+    return y.reshape(B, S, d)
 
 
 def _ambient_mesh():
@@ -127,53 +123,44 @@ def _ambient_mesh():
     return m
 
 
-def _apply_moe_dense(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """Capacity-buffer MoE (GShard-style scatter dispatch), global form.
-
-    x: (B, S, d).  Baseline implementation: correct everywhere, but under
-    SPMD auto-sharding XLA cannot partition the global cumsum/scatter over the
-    data axis and replicates the dispatch (measured 26x useful-compute loss on
-    deepseek_moe_16b x train_4k — the motivation for the sharded form).
-    """
+def moe_route(p: dict, xf: jax.Array, cfg: ModelConfig):
+    """Softmax router, greedy top-k: ``(weights (T, k) f32, experts (T, k))``;
+    the weights are renormalized over the k only when ``norm_topk``."""
     m = cfg.moe
-    B, S, d = x.shape
-    T = B * S
-    xf = x.reshape(T, d)
-
     logits = jnp.einsum(
         "td,de->te", xf.astype(jnp.float32), p["router"].astype(jnp.float32)
     )
     probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, m.top_k)          # (T, k)
+    top_p, top_i = jax.lax.top_k(probs, m.top_k)
     if m.norm_topk:
         top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_p, top_i
 
-    C = moe_capacity(T, cfg)
-    e_flat = top_i.reshape(-1)                            # (T*k,) token-major
-    onehot = jax.nn.one_hot(e_flat, m.n_routed, dtype=jnp.int32)
-    pos = jnp.take_along_axis(
-        jnp.cumsum(onehot, axis=0) - 1, e_flat[:, None], axis=1
-    )[:, 0]                                               # (T*k,) slot in expert
-    keep = pos < C
-    pos_c = jnp.where(keep, pos, C - 1)
 
-    tok_idx = jnp.repeat(jnp.arange(T), m.top_k)
-    gathered = xf[tok_idx] * keep[:, None].astype(xf.dtype)
-    buf = jnp.zeros((m.n_routed, C, d), xf.dtype).at[e_flat, pos_c].add(gathered)
+def moe_dropless(p: dict, xf: jax.Array, cfg: ModelConfig,
+                 backend: str | None = None,
+                 keep: jax.Array | None = None) -> tuple[jax.Array, jax.Array]:
+    """Dropless MoE FFN over tokens ``xf (T, d)``: every (token, expert) pair
+    is served, whatever the routing.  Tokens outside ``keep`` (T,) bool, if
+    given (a decode step's idle rows, a prefill's padding), route nowhere.
 
-    g = L.act_fn(cfg.act)(jnp.einsum("ecd,edf->ecf", buf, p["wg"]))
-    u = jnp.einsum("ecd,edf->ecf", buf, p["wu"])
-    out_buf = jnp.einsum("ecf,efd->ecd", g * u, p["wd"])  # (E, C, d)
+    The pairs are sorted by expert and run as one grouped GEMM per weight
+    (``kernels.ops.expert_ffn``): only the experts some token chose are
+    read.  Returns ``(y (T, d), experts hit)``, the second an int32 scalar.
+    """
+    from ..kernels.ops import expert_ffn
 
-    picked = out_buf[e_flat, pos_c] * keep[:, None].astype(xf.dtype)
-    w = top_p.reshape(-1).astype(xf.dtype)
-    y = jnp.sum(
-        (picked * w[:, None]).reshape(T, m.top_k, d), axis=1
-    )
-
+    m = cfg.moe
+    top_p, top_i = moe_route(p, xf, cfg)
+    y = expert_ffn(xf, top_i, top_p, p["wg"], p["wu"], p["wd"],
+                   act=cfg.act, keep=keep, backend=backend)
+    if keep is not None:
+        top_i = jnp.where(keep[:, None], top_i, m.n_routed)
+    hit = jnp.zeros((m.n_routed,), bool).at[top_i.reshape(-1)].set(
+        True, mode="drop")
     if m.n_shared:
         y = y + apply_ffn(p["shared"], xf, cfg)
-    return y.reshape(B, S, d)
+    return y, jnp.sum(hit, dtype=jnp.int32)
 
 
 def _moe_local_tokens(p_local: dict, xf: jax.Array, cfg: ModelConfig,
@@ -231,7 +218,8 @@ def _apply_moe_sharded(p: dict, x: jax.Array, cfg: ModelConfig, mesh) -> jax.Arr
     dp_size = int(np.prod([dict(zip(mesh.axis_names, mesh.axis_sizes))[a] for a in dp])) if dp else 1
     model_size = dict(zip(mesh.axis_names, mesh.axis_sizes))["model"]
     if (B % max(dp_size, 1) != 0) or (m.n_routed % model_size != 0):
-        return _apply_moe_dense(p, x, cfg)
+        y, _ = moe_dropless(p, x.reshape(B * S, d), cfg)
+        return y.reshape(B, S, d)
     n_local = m.n_routed // model_size
 
     x_spec = P(dp if dp else None, None, None)
@@ -425,13 +413,19 @@ def apply_cross(
 def schema_mla(cfg: ModelConfig) -> dict:
     d, H = cfg.d_model, cfg.n_heads
     a = cfg.mla
+    # The up-projections contract over d and kv_lora, not over the heads
+    # axis init_params would take as fan-in: scaled so, queries and keys
+    # come out near unit variance and the attention is not saturated.
     return {
-        "wq": ParamDef((d, H, a.qk_nope + a.qk_rope), ("embed", "heads", None)),
+        "wq": ParamDef((d, H, a.qk_nope + a.qk_rope), ("embed", "heads", None),
+                       scale=d ** -0.5),
         "w_dkv": ParamDef((d, a.kv_lora), ("embed", "lora")),
         "w_kr": ParamDef((d, a.qk_rope), ("embed", None)),
         "kv_norm": ParamDef((a.kv_lora,), ("lora",), init="zeros"),
-        "w_uk": ParamDef((a.kv_lora, H, a.qk_nope), ("lora", "heads", None)),
-        "w_uv": ParamDef((a.kv_lora, H, a.v_head), ("lora", "heads", None)),
+        "w_uk": ParamDef((a.kv_lora, H, a.qk_nope), ("lora", "heads", None),
+                         scale=a.kv_lora ** -0.5),
+        "w_uv": ParamDef((a.kv_lora, H, a.v_head), ("lora", "heads", None),
+                         scale=a.kv_lora ** -0.5),
         "wo": ParamDef((H, a.v_head, d), ("heads", None, "embed"), scale=0.02),
     }
 
@@ -444,65 +438,114 @@ def cache_mla(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     }
 
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """MLA's attention scale: ``qk_head_dim ** -0.5``, times YaRN's
+    ``mscale(factor, mscale_all_dim) ** 2`` when the rope is stretched."""
+    a = cfg.mla
+    scale = (a.qk_nope + a.qk_rope) ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        m = L.yarn_mscale(y.factor, y.mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
+def _mla_rope(x: jax.Array, positions: jax.Array, cfg: ModelConfig) -> jax.Array:
+    return L.rope(x, positions, cfg.rope_theta, cfg.rope_scaling)
+
+
+def mla_decode_rows(
+    p: dict, h: jax.Array, cfg: ModelConfig, t: jax.Array,
+    ckv: jax.Array, kr: jax.Array,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One new token per row against a read-only latent cache, in the
+    *absorbed* form (``W_uk``/``W_uv`` folded into the score and output
+    einsums).
+
+    h (B, 1, d); t (B,) the position each row writes; ckv (B, T, lora) and
+    kr (B, T, qk_rope) hold each row's positions ``< t`` (later slots are
+    ignored).  The new token attends to the cache and to itself.  Returns
+    ``(out (B, 1, d), ckv_new (B, lora), kr_new (B, qk_rope))``: the caller
+    writes the new entries at ``t``.
+    """
+    a = cfg.mla
+    pos = t[:, None]
+    q = jnp.einsum("bsd,dhk->bshk", h, p["wq"])
+    q_nope, q_rope = q[..., : a.qk_nope], q[..., a.qk_nope :]
+    q_rope = _mla_rope(q_rope, pos, cfg)[:, 0]                       # (B,H,r)
+    ckv_new = L.rms_norm(jnp.einsum("bsd,dl->bsl", h, p["w_dkv"]),
+                         p["kv_norm"])[:, 0]                            # (B,l)
+    kr_new = _mla_rope(jnp.einsum("bsd,dr->bsr", h, p["w_kr"])[:, :, None],
+                       pos, cfg)[:, 0, 0]                               # (B,r)
+    ckv_new, kr_new = ckv_new.astype(ckv.dtype), kr_new.astype(kr.dtype)
+    q_eff = jnp.einsum("bhk,lhk->bhl", q_nope[:, 0], p["w_uk"]).astype(ckv.dtype)
+    q_rope = q_rope.astype(kr.dtype)
+    f32 = jnp.float32
+    s = (jnp.einsum("bhl,btl->bht", q_eff, ckv, preferred_element_type=f32)
+         + jnp.einsum("bhr,btr->bht", q_rope, kr, preferred_element_type=f32))
+    s_self = (jnp.einsum("bhl,bl->bh", q_eff, ckv_new, preferred_element_type=f32)
+              + jnp.einsum("bhr,br->bh", q_rope, kr_new,
+                           preferred_element_type=f32))
+    valid = jnp.arange(ckv.shape[1])[None] < t[:, None]                 # (B,T)
+    s = jnp.where(valid[:, None], s, -jnp.inf)
+    w = jax.nn.softmax(
+        jnp.concatenate([s, s_self[..., None]], axis=-1) * mla_softmax_scale(cfg),
+        axis=-1,
+    )
+    ctx = (jnp.einsum("bht,btl->bhl", w[..., :-1].astype(ckv.dtype), ckv,
+                      preferred_element_type=f32)
+           + w[..., -1:] * ckv_new.astype(f32)[:, None])                # (B,H,l)
+    o = jnp.einsum("bhl,lhv->bhv", ctx.astype(h.dtype), p["w_uv"])
+    out = jnp.einsum("bshv,hvd->bsd", o[:, None].astype(h.dtype), p["wo"])
+    return out, ckv_new, kr_new
+
+
 def apply_mla(
     p: dict, h: jax.Array, cfg: ModelConfig, rs: RunState, cache: dict | None
 ) -> tuple[jax.Array, dict | None]:
     """MLA: full (decompressed) form for training/prefill; *absorbed* form for
-    decode — the cache stores only (c_kv, k_rope) per token (the paper's KV-
-    cache compression), and W_uk/W_uv are folded into the score/output einsums.
+    decode (:func:`mla_decode_rows`) — the cache stores only (c_kv, k_rope)
+    per token (the paper's KV-cache compression).  In decode, ``rs.t`` is one
+    position for the batch or one per row, shape (B,).
     """
     a = cfg.mla
     B = h.shape[0]
     H = cfg.n_heads
-    scale = (a.qk_nope + a.qk_rope) ** -0.5
+
+    if rs.mode == "decode":
+        t = jnp.broadcast_to(jnp.asarray(rs.t, jnp.int32), (B,))
+        out, ckv_new, kr_new = mla_decode_rows(
+            p, h, cfg, t, cache["ckv"], cache["kr"]
+        )
+        rows = jnp.arange(B)
+        new_cache = {"ckv": cache["ckv"].at[rows, t].set(ckv_new),
+                     "kr": cache["kr"].at[rows, t].set(kr_new)}
+        return out, new_cache
 
     q = jnp.einsum("bsd,dhk->bshk", h, p["wq"])
     q_nope, q_rope = q[..., : a.qk_nope], q[..., a.qk_nope :]
-
-    if rs.mode == "decode":
-        t = rs.t
-        q_rope = L.rope(q_rope, jnp.full((B, 1), t), cfg.rope_theta)
-        ckv_new = L.rms_norm(jnp.einsum("bsd,dl->bsl", h, p["w_dkv"]), p["kv_norm"])
-        kr_new = L.rope(
-            jnp.einsum("bsd,dr->bsr", h, p["w_kr"])[:, :, None], jnp.full((B, 1), t),
-            cfg.rope_theta,
-        )[:, :, 0]
-        ckv = jax.lax.dynamic_update_slice_in_dim(cache["ckv"], ckv_new.astype(cache["ckv"].dtype), t, axis=1)
-        kr = jax.lax.dynamic_update_slice_in_dim(cache["kr"], kr_new.astype(cache["kr"].dtype), t, axis=1)
-        # absorbed scores: q_eff = q_nope @ W_uk  -> (B, H, lora)
-        q_eff = jnp.einsum("bshk,lhk->bhl", q_nope, p["w_uk"])
-        s = jnp.einsum("bhl,btl->bht", q_eff.astype(jnp.float32), ckv.astype(jnp.float32))
-        s = s + jnp.einsum("bshr,btr->bht", q_rope.astype(jnp.float32), kr.astype(jnp.float32))
-        s = s * scale
-        valid = jnp.arange(ckv.shape[1]) <= t
-        s = jnp.where(valid[None, None], s, -jnp.inf)
-        w = jax.nn.softmax(s, axis=-1)
-        ctx_l = jnp.einsum("bht,btl->bhl", w, ckv.astype(jnp.float32))  # (B,H,lora)
-        o = jnp.einsum("bhl,lhv->bhv", ctx_l, p["w_uv"])  # absorbed V up-proj
-        o = o[:, None].astype(h.dtype)  # (B,1,H,v)
-        new_cache = {"ckv": ckv, "kr": kr}
-    else:
-        S = h.shape[1]
-        positions = jnp.arange(S)[None]
-        q_rope = L.rope(q_rope, positions, cfg.rope_theta)
-        ckv = L.rms_norm(jnp.einsum("bsd,dl->bsl", h, p["w_dkv"]), p["kv_norm"])
-        kr = L.rope(jnp.einsum("bsd,dr->bsr", h, p["w_kr"])[:, :, None], positions, cfg.rope_theta)
-        k_nope = jnp.einsum("bsl,lhk->bshk", ckv, p["w_uk"])
-        v = jnp.einsum("bsl,lhv->bshv", ckv, p["w_uv"])
-        qf = jnp.concatenate([q_nope, q_rope], axis=-1)
-        kf = jnp.concatenate([k_nope, jnp.broadcast_to(kr, (B, S, H, a.qk_rope))], axis=-1)
-        pad = a.qk_nope + a.qk_rope - a.v_head
-        vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad))) if pad else v
-        o = L.attention(
-            qf, kf, vp, causal=True, logit_cap=None, scale=scale,
-            dense_max_seq=cfg.dense_attn_max_seq, block_kv=cfg.flash_block_kv,
-        )[..., : a.v_head]
-        new_cache = None
-        if cache is not None and rs.write_cache:
-            new_cache = {
-                "ckv": jnp.zeros_like(cache["ckv"]).at[:, :S].set(ckv.astype(cache["ckv"].dtype)),
-                "kr": jnp.zeros_like(cache["kr"]).at[:, :S].set(kr[:, :, 0].astype(cache["kr"].dtype)),
-            }
+    S = h.shape[1]
+    positions = jnp.arange(S)[None]
+    q_rope = _mla_rope(q_rope, positions, cfg)
+    ckv = L.rms_norm(jnp.einsum("bsd,dl->bsl", h, p["w_dkv"]), p["kv_norm"])
+    kr = _mla_rope(jnp.einsum("bsd,dr->bsr", h, p["w_kr"])[:, :, None],
+                   positions, cfg)
+    k_nope = jnp.einsum("bsl,lhk->bshk", ckv, p["w_uk"])
+    v = jnp.einsum("bsl,lhv->bshv", ckv, p["w_uv"])
+    qf = jnp.concatenate([q_nope, q_rope], axis=-1)
+    kf = jnp.concatenate([k_nope, jnp.broadcast_to(kr, (B, S, H, a.qk_rope))], axis=-1)
+    pad = a.qk_nope + a.qk_rope - a.v_head
+    vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad))) if pad else v
+    o = L.attention(
+        qf, kf, vp, causal=True, logit_cap=None, scale=mla_softmax_scale(cfg),
+        dense_max_seq=cfg.dense_attn_max_seq, block_kv=cfg.flash_block_kv,
+    )[..., : a.v_head]
+    new_cache = None
+    if cache is not None and rs.write_cache:
+        new_cache = {
+            "ckv": jnp.zeros_like(cache["ckv"]).at[:, :S].set(ckv.astype(cache["ckv"].dtype)),
+            "kr": jnp.zeros_like(cache["kr"]).at[:, :S].set(kr[:, :, 0].astype(cache["kr"].dtype)),
+        }
 
     out = jnp.einsum("bshv,hvd->bsd", o, p["wo"])
     return out, new_cache

@@ -10,10 +10,12 @@ structured so XLA keeps the working set at ``q_block x kv_block``.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # ---------------------------------------------------------------------------
 # Norms / activations
@@ -61,17 +63,66 @@ def act_fn(kind: str):
 # ---------------------------------------------------------------------------
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor ``0.1 * mscale * ln(factor) + 1``
+    (1 when the context is not stretched)."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_inv_freq(dim: int, theta: float, scaling=None) -> np.ndarray:
+    """Inverse rotary frequencies of a ``dim``-wide rope, ``(dim // 2,)``.
+
+    With ``scaling`` (a :class:`~repro.models.base.YarnCfg`), DeepSeek's
+    YaRN: each frequency blends the interpolated ``f / factor`` and the
+    extrapolated ``f`` by a linear ramp over the dimensions whose rotation
+    count within ``original_max_position`` lies between ``beta_fast`` and
+    ``beta_slow``.
+    """
+    half = dim // 2
+    extra = theta ** (-np.arange(0, half, dtype=np.float64) / half)
+    if scaling is None:
+        return extra.astype(np.float32)
+
+    def corr_dim(rotations: float) -> float:
+        return (dim * math.log(scaling.original_max_position
+                               / (rotations * 2 * math.pi))
+                ) / (2 * math.log(theta))
+
+    low = max(math.floor(corr_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    keep_extra = 1.0 - ramp
+    inv = extra / scaling.factor * (1.0 - keep_extra) + extra * keep_extra
+    return inv.astype(np.float32)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         scaling=None) -> jax.Array:
     """Rotary embedding, half-split convention.
 
-    x: (..., S, H, hd); positions: broadcastable to (..., S).
+    x: (..., S, H, hd); positions: broadcastable to (..., S).  ``scaling``
+    selects YaRN frequencies (:func:`rope_inv_freq`); its cos/sin factor
+    ``mscale(mscale) / mscale(mscale_all_dim)`` is applied too.
     """
     hd = x.shape[-1]
     half = hd // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(rope_inv_freq(hd, theta, scaling))
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., S, half)
     cos = jnp.cos(ang)[..., None, :]  # (..., S, 1, half)
     sin = jnp.sin(ang)[..., None, :]
+    if scaling is not None:
+        m = (yarn_mscale(scaling.factor, scaling.mscale)
+             / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
     out = jnp.concatenate([xf1 * cos - xf2 * sin, xf2 * cos + xf1 * sin], axis=-1)

@@ -43,7 +43,7 @@ from repro.core.d2r import unroll_batch
 __all__ = ["DeliveryRequest", "DeliveryResult", "LANES", "DELIVER_MODES"]
 
 
-LANES = ("rows", "tokens", "features")
+LANES = ("rows", "tokens", "features", "decode")
 DELIVER_MODES = ("tokens", "embed")
 
 
@@ -59,10 +59,15 @@ class DeliveryRequest:
         ``lane="rows"``: images ``(b, alpha, m, m)`` or rows ``(b, F_in)``;
         ``lane="tokens"``: int token sequences ``(b, L)``;
         ``lane="features"``: per-position features ``(b, L, d_in)`` or rows
-        ``(n, d_in)``.
+        ``(n, d_in)``;
+        ``lane="decode"``: one prompt of int token ids, ``(L,)`` or
+        ``(1, L)``.
     lane:
         Which delivery lane serves the payload: ``"rows"`` (vision),
-        ``"tokens"`` (LM discrete), ``"features"`` (LM continuous).
+        ``"tokens"`` (LM discrete), ``"features"`` (LM continuous),
+        ``"decode"`` (LM generation: the prompt is morphed and greedily
+        continued by the engine's decode lane; the result is the generated
+        ids, unmorphed, with each one's logit).
     deliver:
         Tokens lane only — ``"tokens"`` redeems the morphed tokens,
         ``"embed"`` additionally runs the developer-side Aug-Embedding.
@@ -75,6 +80,8 @@ class DeliveryRequest:
     metadata:
         Opaque caller annotations, carried through to the
         :class:`DeliveryResult` untouched.
+    max_new_tokens:
+        Decode lane only (and required there): how many tokens to generate.
     """
 
     tenant_id: str
@@ -84,6 +91,7 @@ class DeliveryRequest:
     priority: int = 0
     deadline_ms: float | None = None
     metadata: Mapping[str, Any] = dataclasses.field(default_factory=dict)
+    max_new_tokens: int | None = None
 
     def __post_init__(self):
         if self.lane not in LANES:
@@ -96,6 +104,20 @@ class DeliveryRequest:
             raise ValueError(
                 f"deliver={self.deliver!r} only applies to lane='tokens' "
                 f"(got lane={self.lane!r})"
+            )
+        if (self.lane == "decode") != (self.max_new_tokens is not None):
+            raise ValueError(
+                "max_new_tokens is required on lane='decode' and applies to "
+                "it only"
+            )
+        if self.max_new_tokens is not None and (
+            isinstance(self.max_new_tokens, bool)
+            or not isinstance(self.max_new_tokens, int)
+            or self.max_new_tokens < 1
+        ):
+            raise ValueError(
+                f"max_new_tokens must be a positive int, got "
+                f"{self.max_new_tokens!r}"
             )
         if isinstance(self.priority, bool) or not isinstance(self.priority, int):
             raise ValueError(f"priority must be an int, got {self.priority!r}")
@@ -126,6 +148,8 @@ class DeliveryResult:
     completed_at: float          # time.monotonic() when a flush published it
     queue_depth_at_submit: int   # engine-wide pending rows just before enqueue
     metadata: Mapping[str, Any] = dataclasses.field(default_factory=dict)
+    # Decode lane: the logit each generated id was chosen with (float32).
+    scores: np.ndarray | None = None
 
     @property
     def latency_ms(self) -> float:
@@ -218,10 +242,32 @@ def _normalize_features(engine, req: DeliveryRequest) -> np.ndarray:
     return data
 
 
+def _normalize_decode(engine, req: DeliveryRequest) -> np.ndarray:
+    lane = getattr(engine, "decode_lane", None)
+    if lane is None:
+        raise ValueError("engine has no decode lane")
+    if req.tenant_id not in engine.lm_registry:
+        raise KeyError(f"unknown LM tenant {req.tenant_id!r}")
+    tokens = np.asarray(req.payload)
+    if tokens.ndim == 2 and tokens.shape[0] == 1:
+        tokens = tokens[0]
+    if tokens.ndim != 1 or not np.issubdtype(tokens.dtype, np.integer):
+        raise ValueError(
+            f"expected one prompt of int token ids, (L,) or (1, L), got "
+            f"{tokens.dtype} {tokens.shape}"
+        )
+    lane.check(tokens.size, req.max_new_tokens)
+    v = engine.lm_registry.vocab
+    if tokens.min() < 0 or tokens.max() >= v:
+        raise ValueError(f"token ids out of range [0, {v})")
+    return tokens.astype(np.int32)[None]
+
+
 _NORMALIZERS = {
     "rows": _normalize_rows,
     "tokens": _normalize_tokens,
     "features": _normalize_features,
+    "decode": _normalize_decode,
 }
 
 
@@ -246,7 +292,8 @@ def normalize(request: DeliveryRequest, engine) -> DeliveryRequest:
 
 def admission_rows(request: DeliveryRequest) -> int:
     """Rows a *normalized* request occupies for admission/quota accounting
-    (images for rows, sequences for tokens, positions for features)."""
+    (images for rows, sequences for tokens and decode, positions for
+    features)."""
     if request.lane == "features":
         return int(
             request.payload.reshape(-1, request.payload.shape[-1]).shape[0]

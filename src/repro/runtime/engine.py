@@ -162,6 +162,15 @@ class EngineStats:
     # written).
     shed_requests: int = 0
     expired_requests: int = 0
+    # Decode lane (runtime/decode.py) counters: batched decode steps run,
+    # rows active summed over those steps, prompt tokens prefilled,
+    # sequences that waited for a slot held by active rows, and distinct
+    # experts hit summed over the steps' MoE layers.
+    decode_steps: int = 0
+    decode_rows: int = 0
+    prefill_tokens: int = 0
+    decode_slot_waits: int = 0
+    experts_hit: int = 0
     timed_out_requests: int = 0
     reconnects: int = 0
     duplicate_hits: int = 0
@@ -346,6 +355,13 @@ class EngineStats:
             f"queue wait: p50={_fmt_ms(self.queue_wait_quantile_ms(0.5))} "
             f"p95={_fmt_ms(self.queue_wait_quantile_ms(0.95))}"
         )
+        if self.decode_steps:
+            lines.append(
+                f"decode: steps={self.decode_steps} rows/step="
+                f"{self.decode_rows / self.decode_steps:.1f} prefill_tokens="
+                f"{self.prefill_tokens} slot_waits={self.decode_slot_waits} "
+                f"experts_hit={self.experts_hit}"
+            )
         admission = (
             f"admission: rejected={self.rejected} blocked={self.blocked}"
         )
@@ -500,8 +516,8 @@ class _WorkItem:
     plan G times, not G plans.
     """
 
-    lane: str                   # "vision" | "tokens" | "features"
-    mb: object                  # runtime.queue.Microbatch
+    lane: str                   # "vision" | "tokens" | "features" | "decode"
+    mb: object                  # runtime.queue.Microbatch (decode: DecodeWork)
     plan: _Plan                 # slot secrets as of this item's coalesce
     want_embed: bool = False    # tokens lane: run the Aug-Embedding gather
     out: object = None          # host results, set by execute_flush
@@ -680,12 +696,31 @@ class MoLeDeliveryEngine:
         self._embed_shape: dict[int, tuple[int, ...]] = {}
         self._req_info: dict[int, _ReqInfo] = {}
         self._done: set[int] = set()
+        # Decode lane (attach_decode): lane seq_id -> request id; the
+        # logits of finished generations until take.
+        self.decode_lane = None
+        self._decode_rid: dict[int, int] = {}
+        self._scores: dict[int, np.ndarray] = {}
+
+    def attach_decode(self, lane) -> None:
+        """Serve ``lane="decode"`` requests through ``lane``, a
+        :class:`~repro.runtime.decode.ContinuousDecodeLane` over this
+        engine's LM registry and scheduler: each request's prompt is morphed
+        by the lane's submit (a permutation, host state: no slot needed) and
+        generated in the lane, one batched decode step per flush round."""
+        if lane.registry is not self.lm_registry:
+            raise ValueError("the decode lane must share the LM registry")
+        if lane.queue.scheduler is not self.scheduler:
+            raise ValueError("the decode lane must share the scheduler")
+        self.decode_lane = lane
 
     @property
     def pending_rows(self) -> int:
-        """Unscheduled rows across every lane (rows == sequences for tokens)."""
+        """Unscheduled rows across every lane (rows == sequences for tokens
+        and decode)."""
         lanes = (self.queue, self.token_queue, self.embed_queue)
-        return sum(q.pending_rows for q in lanes if q is not None)
+        n = sum(q.pending_rows for q in lanes if q is not None)
+        return n + (len(self.decode_lane.queue) if self.decode_lane else 0)
 
     def _registry_of(self, tenant_id: str):
         """The registry holding ``tenant_id`` (vision first, then LM; None
@@ -870,6 +905,15 @@ class MoLeDeliveryEngine:
             )
             self._request_shape[rid] = (req.payload.shape[0], g.beta, g.n, g.n)
             n_rows = req.payload.shape[0]
+        elif req.lane == "decode":
+            rid = self._id_alloc() if rid is None else rid
+            sid = self.decode_lane.submit(
+                req.tenant_id, req.payload, req.max_new_tokens,
+                priority=req.priority,
+            )
+            self._decode_rid[sid] = rid
+            self._request_shape[rid] = req.payload.shape
+            n_rows = 1
         elif req.lane == "tokens":
             reg = self.lm_registry
             rid = self.token_queue.submit(
@@ -976,12 +1020,13 @@ class MoLeDeliveryEngine:
         # again).
         now = time.monotonic()
         for s in mb.slices:
-            info = self._req_info.get(s.request_id)
-            if info is not None and info.coalesced_at is None:
-                info.coalesced_at = now
-                self.stats.record_queue_wait_ms(
-                    (now - info.submitted_at) * 1e3
-                )
+            self._note_taken(s.request_id, now)
+
+    def _note_taken(self, rid: int, now: float) -> None:
+        info = self._req_info.get(rid)
+        if info is not None and info.coalesced_at is None:
+            info.coalesced_at = now
+            self.stats.record_queue_wait_ms((now - info.submitted_at) * 1e3)
 
     def _coalesce(self, vision_live: bool, lm_live: bool) -> _FlushWork | None:
         """Coalesce the live lanes' pending rows into work items (None when
@@ -1006,6 +1051,7 @@ class MoLeDeliveryEngine:
                      self._refresh_lm_plan)
                 )
         clamped = 0
+        unseated: set[str] = set()     # tenants refused a slot this round
         # WFQ lag sampled pre-coalesce: the spread the scheduler is about
         # to work off.  (Post-coalesce everything served is near-level.)
         # One sample per flush — the clock is engine-wide, not per-lane.
@@ -1017,16 +1063,20 @@ class MoLeDeliveryEngine:
         # starve forever).  slot_for activates (and LRU-touches) each tenant
         # on lookup, so evicted tenants transparently regain a slot;
         # max_groups caps a microbatch at `capacity` distinct tenants so
-        # activations within one coalesce round can never evict each other.
-        # The plan re-sync after each coalesce pins the slots that
-        # microbatch's gidx was built against (see _WorkItem).
+        # activations within one coalesce round can never evict each other,
+        # and seat (can_seat) leaves a tenant queued whose slot would have to
+        # come from a tenant the decode lane pins.  The plan re-sync after
+        # each coalesce pins the slots that microbatch's gidx was built
+        # against (see _WorkItem).
         live = list(lanes)
         while live and len(work.items) < cap:
             for entry in list(live):
                 if len(work.items) >= cap:
                     break
                 lane, queue, reg, refresh = entry
-                mb = queue.coalesce(reg.slot_for, max_groups=reg.capacity)
+                mb = queue.coalesce(reg.slot_for, max_groups=reg.capacity,
+                                    seat=reg.can_seat)
+                unseated |= queue.unseated
                 if mb is None:
                     live.remove(entry)
                     continue
@@ -1041,6 +1091,18 @@ class MoLeDeliveryEngine:
                     for s in mb.slices
                 )
                 work.items.append(_WorkItem(lane, mb, refresh(), want_embed))
+        if lm_live and self.decode_lane is not None and self.decode_lane.busy:
+            # While a tokens-lane tenant waits for a slot that decode rows
+            # pin, the lane admits no row, so the pins drain.
+            lane = self.decode_lane
+            waits = lane.slot_waits
+            step = lane.begin_step(hold=bool(unseated))
+            self.stats.decode_slot_waits += lane.slot_waits - waits
+            if step is not None:
+                now = time.monotonic()
+                for sid in step.admitted:
+                    self._note_taken(self._decode_rid[sid], now)
+                work.items.append(_WorkItem("decode", step, step.plan))
         if not work.items:
             return None
         if clamped:
@@ -1107,9 +1169,12 @@ class MoLeDeliveryEngine:
             # and fetch each in turn: the device pipelines the microbatches
             # instead of idling between them, and runs item i+1 while item
             # i's output is copied to the host.
-            with span("mole.flush.dispatch"):
-                outs = [self._dispatch(item) for item in work.items]
-            for item, out in zip(work.items, outs):
+            rows = [item for item in work.items if item.lane != "decode"]
+            outs = []
+            if rows:
+                with span("mole.flush.dispatch"):
+                    outs = [self._dispatch(item) for item in rows]
+            for item, out in zip(rows, outs):
                 with span("mole.flush.wait"):
                     jax.block_until_ready(out)
                 with span("mole.flush.fetch"):
@@ -1121,6 +1186,11 @@ class MoLeDeliveryEngine:
                         )
                     else:
                         item.out = np.asarray(out)
+            # The decode step last: its prefills and step follow the
+            # microbatches on the device, and it fetches its own tokens.
+            for item in work.items:
+                if item.lane == "decode":
+                    self.decode_lane.run_step(item.mb)
         dt_ms = timing.ms
         # Straggler watch: a device phase far above the running EMA flags
         # this flush as degraded (hung interconnect, preempted accelerator).
@@ -1146,9 +1216,26 @@ class MoLeDeliveryEngine:
                     self._publish_rows(item, done, self._finish_vision)
                 elif item.lane == "tokens":
                     self._publish_tokens(item, done)
+                elif item.lane == "decode":
+                    self._publish_decode(item, done)
                 else:
                     self._publish_rows(item, done, self._finish_features)
         return done
+
+    def _publish_decode(self, item: _WorkItem,
+                        done: dict[int, np.ndarray]) -> None:
+        rec, st = item.mb.record, self.stats
+        st.decode_steps += 1
+        st.decode_rows += rec["rows"]
+        st.prefill_tokens += sum(n for n, _, _ in rec["prefills"])
+        st.experts_hit += sum(rec["hits"])
+        lane = self.decode_lane
+        for sid in lane.finish_step(item.mb):
+            rid = self._decode_rid.pop(sid)
+            ids, scores = lane.take_result(sid)
+            self._results[rid] = done[rid] = ids
+            self._scores[rid] = scores
+            self._mark_done(rid)
 
     def _mark_done(self, rid: int) -> None:
         """Stamp completion: the request's latency (with its priority) lands
@@ -1207,7 +1294,8 @@ class MoLeDeliveryEngine:
             ]
             if s.req_offset + s.n_rows == shape[0]:
                 # Strip the sequence padding back to the true length.
-                done[rid] = np.ascontiguousarray(buf[:, : shape[1]])
+                out = np.ascontiguousarray(buf[:, : shape[1]])
+                done[rid] = out
                 self._results[rid] = done[rid]
                 self._mark_done(rid)
 
@@ -1251,6 +1339,7 @@ class MoLeDeliveryEngine:
                 f"taken ({len(self._done)} completed requests await take())"
             )
         out = self._results.pop(request_id)
+        scores = self._scores.pop(request_id, None)
         self._request_shape.pop(request_id, None)
         self._token_deliver.pop(request_id, None)
         self._embed_shape.pop(request_id, None)
@@ -1262,7 +1351,7 @@ class MoLeDeliveryEngine:
             deliver=req.deliver, priority=req.priority, payload=out,
             submitted_at=info.submitted_at, completed_at=info.completed_at,
             queue_depth_at_submit=info.queue_depth_at_submit,
-            metadata=req.metadata,
+            metadata=req.metadata, scores=scores,
         )
 
     def take(self, request_id: int) -> np.ndarray:
@@ -1296,6 +1385,7 @@ class MoLeDeliveryEngine:
         self._embed_shape.clear()
         self._req_info.clear()
         self._done.clear()
+        self._scores.clear()
 
     def _rebuild_queues(self) -> None:
         """Replace every lane's queue with an empty twin (same buckets, same
@@ -1304,6 +1394,11 @@ class MoLeDeliveryEngine:
         ``_req_info`` is the only state the recovery paths trust."""
         from .queue import RequestQueue, TokenQueue
 
+        # Decode requests replay from their prompts: the lane drops its
+        # sequences, and the replay submits them to it again.
+        self._decode_rid.clear()
+        if self.decode_lane is not None:
+            self.decode_lane.reset()
         if self.queue is not None:
             # release() hands the dead queue's backlog references back to
             # the shared scheduler — otherwise the engine-wide clock would
@@ -1388,12 +1483,15 @@ class MoLeDeliveryEngine:
                 "rid": rid, "tenant": req.tenant_id, "lane": req.lane,
                 "deliver": req.deliver, "priority": req.priority,
                 "deadline_ms": req.deadline_ms, "metadata": md, "done": done,
+                "max_new_tokens": req.max_new_tokens,
                 "submitted_at": info.submitted_at,
                 "completed_at": info.completed_at,
                 "queue_depth": info.queue_depth_at_submit,
             })
             if done:
                 arrays[f"req/{rid:08d}/result"] = self._results[rid]
+                if rid in self._scores:
+                    arrays[f"req/{rid:08d}/scores"] = self._scores[rid]
             else:
                 arrays[f"req/{rid:08d}/payload"] = np.asarray(req.payload)
         self.stats.snapshots += 1
@@ -1445,8 +1543,11 @@ class MoLeDeliveryEngine:
         for desc in meta["requests"]:
             rid = int(desc["rid"])
             md = desc.get("metadata") or {}
+            max_new = desc.get("max_new_tokens")
             if desc["done"]:
                 self._results[rid] = arrays[f"req/{rid:08d}/result"]
+                if f"req/{rid:08d}/scores" in arrays:
+                    self._scores[rid] = arrays[f"req/{rid:08d}/scores"]
                 self._done.add(rid)
                 self._req_info[rid] = _ReqInfo(
                     request=DeliveryRequest(
@@ -1454,6 +1555,7 @@ class MoLeDeliveryEngine:
                         deliver=desc["deliver"],
                         priority=int(desc["priority"]),
                         deadline_ms=desc["deadline_ms"], metadata=md,
+                        max_new_tokens=max_new,
                     ),
                     submitted_at=desc["submitted_at"],
                     queue_depth_at_submit=int(desc["queue_depth"]),
@@ -1465,6 +1567,7 @@ class MoLeDeliveryEngine:
                     lane=desc["lane"], deliver=desc["deliver"],
                     priority=int(desc["priority"]),
                     deadline_ms=desc["deadline_ms"], metadata=md,
+                    max_new_tokens=max_new,
                 )
                 self._enqueue_normalized(req, rid=rid, count_stats=False)
                 info = self._req_info[rid]

@@ -63,7 +63,7 @@ import collections
 import dataclasses
 import heapq
 import itertools
-from typing import Callable, Iterable, Mapping
+from typing import Callable, Collection, Iterable, Mapping
 
 import numpy as np
 
@@ -444,6 +444,7 @@ class RequestQueue:
         # bucket per coalesce).  Entries whose rid left _live are stale.
         self._id_heap: list[int] = []
         self._pending_rows = 0                      # running unscheduled rows
+        self.unseated: frozenset[str] = frozenset()   # see coalesce
 
     def __len__(self) -> int:
         return len(self._live)
@@ -606,6 +607,7 @@ class RequestQueue:
         self,
         tenant_index: Mapping[str, int] | Callable[[str], int],
         max_groups: int | None = None,
+        seat: Callable[[str, Collection[str]], bool] | None = None,
     ) -> Microbatch | None:
         """Pack pending rows into one padded microbatch, WFQ-fairly.
 
@@ -614,8 +616,12 @@ class RequestQueue:
         side effect — see ``SessionRegistry.slot_for``).  ``max_groups`` caps
         the number of groups below the largest group bucket — the engine
         passes its registry capacity so one microbatch never asks for more
-        resident tenants than there are slots.  Returns None when the queue
-        is empty.
+        resident tenants than there are slots.  ``seat(tenant, seated)``
+        (``SlotRegistry.can_seat``) says whether a tenant can get a slot
+        without evicting a pinned tenant or one of those already seated in
+        this microbatch; a tenant it refuses stays queued, uncharged, and is
+        listed in ``unseated``.  Returns None when the queue is empty or no
+        backlogged tenant can be seated.
 
         Group selection order is the WFQ order: repeatedly serve one
         ``max_rows``-chunk from the backlogged tenant with the smallest
@@ -623,6 +629,7 @@ class RequestQueue:
         scheduler — so a saturated microbatch splits its groups across
         tenants in proportion to their engine-wide weights.
         """
+        self.unseated = frozenset()
         if not self._live:
             return None
         lookup = tenant_index if callable(tenant_index) else tenant_index.__getitem__
@@ -633,10 +640,27 @@ class RequestQueue:
         )
         sched = self.scheduler
         chunks: list[tuple[str, list[tuple[QueuedRequest, int, int]]]] = []
+        # Slot lookups happen once per tenant, in WFQ service order, before
+        # its chunk leaves the queue — slot_for may activate an evicted
+        # tenant, and that must follow the order the scheduler grants
+        # service in.
+        slot_of: dict[str, int] = {}
+        unseated: set[str] = set()
         while len(chunks) < max_groups:
             tenant = self._pick_lane()
+            if tenant in unseated:
+                tenant = min(
+                    (t for t in self._backlogs if t not in unseated),
+                    key=lambda t: (sched.peek(t).vtime, self._backlogs[t][0][1]),
+                    default=None,
+                )
             if tenant is None:
                 break
+            if tenant not in slot_of:
+                if seat is not None and not seat(tenant, slot_of):
+                    unseated.add(tenant)
+                    continue
+                slot_of[tenant] = lookup(tenant)
             rec = sched.peek(tenant)
             # The served chunk's start tag is the global virtual time: lanes
             # waking from idle resume here instead of at 0.  Advanced while
@@ -648,6 +672,7 @@ class RequestQueue:
             chunks.append((tenant, runs))
 
         sched.prune()
+        self.unseated = frozenset(unseated)
 
         if not chunks:
             return None
@@ -655,14 +680,7 @@ class RequestQueue:
         # Slot-sorted coalescing: order groups by their registry slot so the
         # grouped kernels see monotone indices (adjacent groups sharing a
         # slot reuse the resident secret tile, and the full-table microbatch
-        # degenerates to gidx == arange).  Slot lookups happen once per
-        # tenant, in WFQ service order, *before* sorting — slot_for may
-        # activate an evicted tenant, and that must follow the order the
-        # scheduler actually granted service in.
-        slot_of: dict[str, int] = {}
-        for tenant, _ in chunks:
-            if tenant not in slot_of:
-                slot_of[tenant] = lookup(tenant)
+        # degenerates to gidx == arange).
         chunks.sort(key=lambda c: slot_of[c[0]])  # stable: WFQ order in a slot
 
         largest = max(sum(n for _, _, n in runs) for _, runs in chunks)
@@ -745,6 +763,7 @@ class TokenQueue:
         self.service_lane = service_lane
         self._queues: dict[int, RequestQueue] = {}   # seq bucket -> lane
         self._ensured_groups: set[int] = set()
+        self.unseated: frozenset[str] = frozenset()   # see coalesce
 
     def __len__(self) -> int:
         return sum(len(q) for q in self._queues.values())
@@ -824,18 +843,26 @@ class TokenQueue:
         self,
         tenant_index: Mapping[str, int] | Callable[[str], int],
         max_groups: int | None = None,
+        seat: Callable[[str, Collection[str]], bool] | None = None,
     ) -> Microbatch | None:
         """One padded ``(G, B, L_bucket)`` microbatch from the seq bucket
-        whose head-of-line request is oldest; None when nothing is pending."""
-        live = [
-            (q.oldest_pending_id, q)
-            for q in self._queues.values()
-            if q.oldest_pending_id is not None
-        ]
-        if not live:
-            return None
-        _, lane = min(live, key=lambda kv: kv[0])
-        return lane.coalesce(tenant_index, max_groups)
+        whose head-of-line request is oldest (the next oldest where ``seat``
+        lets none of its tenants in); None when nothing can be served.
+        ``unseated`` lists the tenants ``seat`` refused on the way."""
+        live = sorted(
+            (q for q in self._queues.values() if q.oldest_pending_id is not None),
+            key=lambda q: q.oldest_pending_id,
+        )
+        unseated: set[str] = set()
+        for lane in live:
+            mb = lane.coalesce(tenant_index, max_groups, seat)
+            unseated |= lane.unseated
+            if mb is not None:
+                break
+        else:
+            mb = None
+        self.unseated = frozenset(unseated)
+        return mb
 
 
 @dataclasses.dataclass
@@ -956,9 +983,22 @@ class FairAdmissionQueue:
         self._pending += 1
         return sid
 
-    def take(self) -> AdmittedSequence | None:
-        """Dequeue the next sequence under WFQ, or None when empty."""
+    def waiting_ids(self) -> list[int]:
+        """seq_ids of every queued sequence."""
+        return [e[2].seq_id for blog in self._backlogs.values() for e in blog]
+
+    def take(self, admissible=None) -> AdmittedSequence | None:
+        """Dequeue the next sequence under WFQ, or None when empty.
+
+        ``admissible(tenant_id)`` is the decode lane's slot rule.  The
+        least-served tenant is never passed over: when the rule refuses it,
+        None comes back and nobody is admitted until it is accepted, so a
+        tenant waiting for a slot cannot be starved by tenants holding one.
+        """
         tenant = _pick_backlogged(self._pick, self._backlogs, self.scheduler)
+        if tenant is not None and admissible is not None and not admissible(
+                tenant):
+            return None
         if tenant is None:
             return None
         sched = self.scheduler

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 from jax.profiler import TraceAnnotation
 
-__all__ = ["SPANS", "span"]
+__all__ = ["DECODE_SPANS", "SPANS", "span"]
 
 SPANS = (
     # Engine flush phases (runtime/engine.py), timed by EngineStats.phase.
@@ -31,7 +31,15 @@ SPANS = (
 )
 
 
+# The decode lane's (runtime/decode.py), inside mole.flush.device: opened
+# only where LM tenants generate, so apart from the served path's list.
+DECODE_SPANS = (
+    "mole.decode.prefill",  # one joiner's prefill and join, dispatched
+    "mole.decode.step",     # one batched decode step, to its tokens on host
+)                           # (the step's own dispatch, wait and fetch spans)
+
+
 def span(name: str) -> TraceAnnotation:
-    """A host span named ``name``, one of :data:`SPANS`, as a context
-    manager."""
+    """A host span named ``name``, one of :data:`SPANS` or
+    :data:`DECODE_SPANS`, as a context manager."""
     return TraceAnnotation(name)

@@ -21,7 +21,11 @@ Kinds:
     the **same** rid, which is what lets the server keep delivery
     exactly-once) and ``age_ms`` (time the request has already spent
     client-side — deadline propagation without trusting cross-host clocks).
+    A decode-lane request carries a prompt's token ids as its payload and
+    ``max_new_tokens`` in its header.
   * ``KIND_RES``  server -> client: the delivered payload + trace fields.
+    A decode-lane result's payload is the generated ids (int32, unmorphed)
+    and its header's ``scores`` the logit each id was chosen with.
   * ``KIND_REJ``  server -> client: a **typed** rejection (``REJECT_CODES``)
     — overload sheds, expired deadlines, drains, and malformed requests are
     protocol outcomes, not dropped connections.
@@ -284,6 +288,8 @@ def encode_request(req: DeliveryRequest, rid: str,
         "metadata": dict(req.metadata),
         **meta,
     }
+    if req.max_new_tokens is not None:
+        header["max_new_tokens"] = int(req.max_new_tokens)
     return encode_frame(KIND_REQ, header, body)
 
 
@@ -321,6 +327,7 @@ def decode_request(header: Mapping[str, Any],
         priority=header.get("priority", 0),
         deadline_ms=header.get("deadline_ms"),
         metadata=metadata,
+        max_new_tokens=header.get("max_new_tokens"),
     )
     return rid, float(age), req
 
@@ -336,6 +343,7 @@ class WireResult:
     latency_ms: float            # server-side admission -> publish latency
     payload: np.ndarray
     metadata: Mapping[str, Any] = dataclasses.field(default_factory=dict)
+    scores: np.ndarray | None = None   # decode lane: each id's logit
 
 
 def encode_result_parts(rid: str,
@@ -354,6 +362,11 @@ def encode_result_parts(rid: str,
         "metadata": dict(result.metadata),
         **meta,
     }
+    if result.scores is not None:
+        # float32 values as JSON numbers: repr of their float64 widening is
+        # exact, so the client reads back the same float32 bits.
+        header["scores"] = [float(x) for x in np.asarray(result.scores,
+                                                         np.float32)]
     return frame_head(KIND_RES, header, body.nbytes), body
 
 
@@ -380,6 +393,8 @@ def decode_result(header: Mapping[str, Any], payload: bytes) -> WireResult:
         latency_ms=float(header.get("latency_ms", 0.0)),
         payload=_decode_array(header, payload),
         metadata=header.get("metadata", {}) or {},
+        scores=(None if header.get("scores") is None
+                else np.asarray(header["scores"], np.float32)),
     )
 
 

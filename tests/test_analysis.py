@@ -51,6 +51,7 @@ FIXTURE_EXPECTATIONS = [
      {("exception-leak", 8), ("assert-leak", 13)}),
     ("wire_header_leak.py", {("wire-leak", 8), ("wire-leak", 12)}),
     ("wire_result_parts_leak.py", {("wire-leak", 9), ("wire-leak", 13)}),
+    ("decode_frames_leak.py", {("scores-leak", 10), ("wire-leak", 15)}),
     ("declassified_snapshot.py", {("serialized-secret", 14)}),
     ("lock_violation.py",
      {("held-forbidden", 17), ("held-forbidden", 21),

@@ -165,3 +165,34 @@ def test_token_lanes_grouped_match_gather_oracle(rng, backend, name):
         np.asarray(ref.aug_embed_batched_ref(tokens, tables[gidx])),
         atol=0,
     )
+
+
+@pytest.mark.parametrize("groups", [[2, 2, 0, 2, 5, 0, 2, 2, 2], [1] * 9],
+                         ids=["uneven", "one_group"])
+def test_tiled_group_gemm_serves_rows_grouped_into_padded_tiles(rng, groups):
+    """The ragged kernel behind the dropless experts and the grouped
+    Aug-head: rows sorted by group into whole 8-row tiles, each tile times
+    its group's matrix, tiles past the used count zero, every row equal to
+    its own group's product."""
+    from repro.kernels.ops import group_tiles, tile_count
+    from repro.kernels.grouped import tiled_group_gemm
+
+    g = jnp.asarray(groups, jnp.int32)
+    x = jnp.asarray(rng.standard_normal((g.shape[0], 256)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((6, 256, 384)), jnp.float32)
+    bm = 8
+    tiles = group_tiles(g, 6, bm)
+    n = tile_count(g.shape[0], 6, bm)
+    buf = jnp.zeros((n * bm, 256), jnp.float32).at[tiles["slot"]].set(
+        x[tiles["order"]])
+    out = tiled_group_gemm(buf, tiles["tile_group"], tiles["n_used"], w,
+                           bm=bm, bn=128, bk=128, interpret=True,
+                           name="mole_test_gmm")
+    used = int(tiles["n_used"][0])
+    counts = np.bincount(groups)
+    assert used == int(np.sum(-(-counts // bm))) and used <= n
+    np.testing.assert_array_equal(np.asarray(out[used * bm:]), 0.0)
+    got = np.asarray(out)[np.asarray(tiles["slot"])]
+    want = np.einsum("rk,rkn->rn", np.asarray(x)[np.asarray(tiles["order"])],
+                     np.asarray(w)[np.asarray(g)[np.asarray(tiles["order"])]])
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)

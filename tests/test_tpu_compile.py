@@ -1,7 +1,8 @@
 """Compile the served delivery step for a TPU v5e that is described, not
 attached: the paper's VGG-16/CIFAR first layer (alpha=3, m=32, beta=64,
 p=3 -> a 3072 x 65536 Aug-Conv matrix per slot) through the grouped Pallas
-kernels, and the decode head at deepseek-7B's width.  The TPU compiler
+kernels, the decode head at deepseek-7B's width, and DeepSeek-V2-Lite's
+dropless routed experts at a 128-row decode step.  The TPU compiler
 refuses here what interpret mode accepts: a block off the (8, 128) tiling,
 more VMEM than a kernel may use, a program larger than the chip.
 
@@ -17,7 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import ConvGeometry
-from repro.kernels.ops import _lm_head_rows_grouped
+from repro.kernels.ops import _expert_ffn, _lm_head_rows_grouped
 from repro.runtime.engine import _delivery_step
 
 PAPER = ConvGeometry(alpha=3, beta=64, m=32, p=3)
@@ -110,3 +111,24 @@ def test_lm_head_compiles_at_decode_width(one_chip):
     ).compile()
     assert _custom_calls(compiled) == 1
     assert compiled.memory_analysis().argument_size_in_bytes < HBM_BYTES
+
+
+def test_expert_ffn_compiles_at_deepseek_v2_lite_widths(one_chip):
+    """The dropless routed experts of a 128-row decode step: 64 experts of
+    width 1,408 at d=2,048, top-6, in bf16 -- three grouped GEMMs, each the
+    kernel named ``mole_expert_gmm``."""
+    rows, k, d, f, experts = 128, 6, 2048, 1408, 64
+    bf16 = jnp.bfloat16
+    compiled = _expert_ffn.lower(
+        _spec((rows, d), bf16, one_chip),
+        _spec((rows, k), jnp.int32, one_chip),
+        _spec((rows, k), jnp.float32, one_chip),
+        _spec((experts, d, f), bf16, one_chip),
+        _spec((experts, d, f), bf16, one_chip),
+        _spec((experts, f, d), bf16, one_chip),
+        act="swiglu", backend="pallas",
+    ).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 3
+    assert all("%mole_expert_gmm" in ln for ln in calls)
