@@ -190,7 +190,8 @@ def run_delivery(args) -> dict:
     stats = engine.stats
     latency = (
         f"  latency:     p50={stats.p50_ms:7.2f}ms p95={stats.p95_ms:7.2f}ms "
-        f"(SLO max_delay={args.max_delay_ms}ms, {stats.flushes} flushes)\n"
+        f"(SLO max_delay={args.max_delay_ms}ms, {stats.flushes} flushes, "
+        f"{stats.rounds_overlapped} overlapped)\n"
         if args.use_async else ""
     )
     if args.use_async and (args.snapshot_dir or args.inject_failure):

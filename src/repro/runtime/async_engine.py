@@ -23,14 +23,21 @@ latency-SLO'd, admission-controlled front door over it:
     misbehaving tenant is throttled without stalling anyone else's clock.
     Both outcomes land in ``EngineStats`` per tenant
     (``rejected_by_tenant`` / ``blocked_by_tenant``).
-  * **Double-buffered flushing** — a flush is three engine phases
+  * **Flush rounds two deep** — a round is three engine phases
     (``begin_flush`` coalesce / ``execute_flush`` device / ``publish_flush``
-    scatter) and the flusher holds ``self._cv`` only for the first and last:
-    ``begin_flush`` drains the queues into private work items, so while the
-    jitted device step runs *outside the lock*, submitters keep enqueuing
-    into the now-empty queues.  Submit latency no longer scales with flush
-    duration (``EngineStats.submit_stalls`` + submit-wait quantiles make
-    that observable).
+    scatter and commit), and ``self._cv`` is held only for the coalesce and
+    the commit.  ``begin_flush`` drains the queues into private work items,
+    so submitters keep enqueuing into the now-empty queues while the device
+    runs.  Once round n's device phase is done, a publisher thread
+    publishes it — scattering its rows into request buffers off the lock,
+    committing them under it, resolving its futures — while the flusher
+    coalesces round n+1 (when one is due) and runs its device phase.  A
+    round that carries a decode step is neither coalesced nor dispatched
+    before the previous round has published (the step reads what that
+    publish leaves); ``EngineStats.rounds_overlapped`` counts the rounds
+    dispatched while the previous one was publishing.  Submit latency does
+    not scale with flush duration (``EngineStats.submit_stalls`` +
+    submit-wait quantiles make that observable).
   * **Latency accounting** — submit→publish completion latency lands in
     ``EngineStats`` (``p50_ms`` / ``p95_ms`` over a sliding window, split per
     request priority), along with per-phase flush timing
@@ -46,10 +53,13 @@ latency-SLO'd, admission-controlled front door over it:
 
 Thread-safety contract: the wrapped engine/queue/registry are only ever
 touched while ``self._cv`` is held (by submitters for the engine enqueue, by
-the flusher for ``begin_flush``/``publish_flush``/``take_result``) — except
-``execute_flush``, which by design touches only its work items and immutable
-plan snapshots.  Request normalization (payload validation/conversion) runs
-*outside* the lock.  Future callbacks fire outside the lock.
+the flusher for ``begin_flush``, by the publisher for the commit of
+``publish_flush`` and ``take_result``) — except ``execute_flush``, which by
+design touches only its work items and immutable plan snapshots, and the
+scatter of ``publish_flush``, which writes only the buffers of partial
+requests that the publisher thread alone owns.  Request normalization
+(payload validation/conversion) runs *outside* the lock.  Future callbacks
+fire outside the lock, on the publisher thread.
 """
 from __future__ import annotations
 
@@ -57,7 +67,7 @@ import heapq
 import logging
 import threading
 import time
-from concurrent.futures import Future
+from concurrent.futures import Future, ThreadPoolExecutor
 # Python < 3.11 raises a concurrent.futures-specific TimeoutError from
 # Future.result(); 3.11+ aliases it to the builtin.  Catch the one that is
 # actually raised, whichever interpreter runs us.
@@ -207,6 +217,11 @@ class AsyncDeliveryEngine:
         self._cancelled: set[int] = set()
         self._force_flush = False
         self._closed = False
+        # Publishes round n (scatter, commit, resolve) while the flusher
+        # runs round n+1's device phase; one round at a time, in order.
+        self._publisher = ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix="mole-delivery-publisher"
+        )
         self._flusher = threading.Thread(
             target=self._supervise, name="mole-delivery-flusher", daemon=True
         )
@@ -571,179 +586,256 @@ class AsyncDeliveryEngine:
         fatal: :meth:`_mark_dead` fails the in-flight futures with
         :class:`EngineDeadError` and subsequent submits raise immediately.
         """
-        while True:
-            try:
-                self._run()
-                return
-            except SimulatedFailure as e:
-                if self._restarts >= self.max_restarts:
+        try:
+            while True:
+                try:
+                    self._run()
+                    return
+                except SimulatedFailure as e:
+                    if self._restarts >= self.max_restarts:
+                        self._mark_dead(e)
+                        return
+                    self._restarts += 1
+                    with self._cv:
+                        self.engine.requeue_inflight()
+                        # Re-arm: the replayed backlog should flush promptly.
+                        self._force_flush = bool(self._futures)
+                        self._cv.notify_all()
+                except BaseException as e:
                     self._mark_dead(e)
                     return
-                self._restarts += 1
-                with self._cv:
-                    self.engine.requeue_inflight()
-                    # Re-arm: the replayed backlog should flush promptly.
-                    self._force_flush = bool(self._futures)
-                    self._cv.notify_all()
-            except BaseException as e:
-                self._mark_dead(e)
-                return
+        finally:
+            self._publisher.shutdown(wait=False)
 
     # -- the flusher thread ---------------------------------------------------
-    def _oldest_deadline(self) -> float | None:
+    def _oldest_deadline(self, skip=frozenset()) -> float | None:
         # Peek the deadline heap, lazily discarding entries whose request
         # already completed (rid no longer in _submitted_at) — amortized
         # O(log n) per request instead of an O(n) min-scan per wake.  The
         # heap holds absolute per-request deadlines, so a request submitted
         # with a tight ``deadline_ms`` surfaces ahead of older requests
-        # running on the engine-wide SLO.
+        # running on the engine-wide SLO.  Entries of ``skip`` (requests
+        # whose last rows an unpublished round already carries) are set
+        # aside for the peek and put back.
         heap = self._deadline_heap
-        while heap and heap[0][1] not in self._submitted_at:
-            heapq.heappop(heap)
-        if not heap:
-            return None
-        return heap[0][0]
+        held = []
+        while heap and (heap[0][1] not in self._submitted_at
+                        or heap[0][1] in skip):
+            entry = heapq.heappop(heap)
+            if entry[1] in self._submitted_at:
+                held.append(entry)
+        oldest = heap[0][0] if heap else None
+        for entry in held:
+            heapq.heappush(heap, entry)
+        return oldest
 
-    def _should_flush(self, now: float) -> bool:
+    def _should_flush(self, now: float, unpublished=None) -> bool:
+        """A round is due: forced, closing, a microbatch's worth of rows
+        pending, or a pending request's deadline passed.  Requests whose
+        last rows the ``unpublished`` round carries have no rows pending."""
         if not self._futures:
             return False
         if self._force_flush or self._closed:
             return True
         if self.engine.pending_rows >= self.flush_rows:
             return True
-        deadline = self._oldest_deadline()
+        deadline = self._oldest_deadline(
+            frozenset() if unpublished is None else unpublished.complete
+        )
         return deadline is not None and now >= deadline
 
     def _run(self) -> None:
-        while True:
-            error: BaseException | None = None
-            work = None
-            with self._cv:
-                with span("mole.flusher.idle"):
-                    while not self._should_flush(time.monotonic()):
-                        if self._closed and not self._futures:
-                            return
-                        deadline = self._oldest_deadline()
-                        timeout = (
-                            None if deadline is None
-                            else max(0.0, deadline - time.monotonic())
-                        )
-                        self._cv.wait(timeout=timeout)
-                self._force_flush = False
-                # Phase 1 under the lock: coalesce the queues into private
-                # work items.  Afterwards the queues are empty — the second
-                # buffer — and submitters fill them while phase 2 runs.
+        """The flusher loop, two rounds deep: while round n publishes on
+        the publisher thread (its scatter off the lock, its commit under
+        it, then its futures), this thread coalesces round n+1 under the
+        lock and runs its device phase.  Round n+1 is handed to the
+        publisher once round n has published, so at most two rounds are in
+        flight and they publish in order.  A round that carries a decode
+        step is neither coalesced nor dispatched before the previous
+        round's publish: its step reads what that publish leaves in the
+        decode lane.  When no round is due, round n finishes publishing
+        and the flusher idles until one is.
+        """
+        publishing = None   # (round, Future of its publish)
+        try:
+            while True:
                 try:
-                    work = self.engine.begin_flush()
-                except SimulatedFailure:
-                    raise   # recoverable: handled by _supervise, not here
-                except Exception as e:  # pragma: no cover - defensive
-                    error = e
-            # Phase 2 OUTSIDE the lock: the jitted device step (the long
-            # pole of a flush) runs while submitters keep acquiring _cv, so
-            # submit latency no longer scales with flush duration.
-            if error is None and work is not None:
-                try:
-                    self.engine.execute_flush(work)
-                except SimulatedFailure:
-                    raise   # recoverable: handled by _supervise, not here
-                except Exception as e:
-                    error = e
-            resolved: list[tuple[Future, object]] = []
-            failed: list[tuple[Future, BaseException]] = []
-            with self._cv:
-                done: dict = {}
-                if error is None and work is not None:
-                    # Phase 3 under the lock: scatter results into the
-                    # engine's per-request buffers (cheap bookkeeping).
-                    try:
-                        done = self.engine.publish_flush(work)
-                    except SimulatedFailure:
-                        raise   # recoverable: handled by _supervise
-                    except Exception as e:  # pragma: no cover - defensive
-                        error = e
-                if error is not None:
-                    # A failed flush must not strand waiters: fail everything
-                    # in flight and reset the accounting — including the
-                    # wrapped engine's queued rows and result buffers, which
-                    # would otherwise be coalesced by a later flush into
-                    # results nobody can take().  (Requests submitted during
-                    # phase 2 fail too: their rows may already be coalesced
-                    # into the failed work items.)
-                    failed = [(f, error) for f in self._futures.values()]
-                    # Every caught error is re-surfaced into the waiters'
-                    # futures below (or an EngineDeadError on the next
-                    # submit); the log carries the error *class* only —
-                    # `str(error)` may embed repr'd request payloads.
-                    _log.error(
-                        "flush round failed with %s: failing %d waiter(s)",
-                        type(error).__name__, len(failed),
-                    )
-                    self.engine.stats.flush_failures += 1
-                    self._futures.clear()
-                    self._submitted_at.clear()
-                    self._deadline_heap.clear()
-                    self._rid_tenant.clear()
-                    self._inflight_rows.clear()
-                    self._cancelled.clear()  # their engine state resets too
-                    self.engine.reset_pending()
-                else:
-                    for rid in done:
-                        # A rid submitted to the sync engine directly (mixed
-                        # API use) completes here too but is not ours to
-                        # resolve — leave its result for engine.take().
-                        fut = self._futures.pop(rid, None)
-                        if fut is None:
-                            if rid in self._cancelled:
-                                # The waiter gave up (cancel-on-timeout):
-                                # pop-and-drop the result so it doesn't
-                                # strand in the engine's buffers.
-                                self._cancelled.discard(rid)
-                                self.engine.take_result(rid)
-                            continue
-                        self._submitted_at.pop(rid)
-                        tenant, n_rows = self._rid_tenant.pop(rid)
-                        self._inflight_rows[tenant] -= n_rows
-                        if not self._inflight_rows[tenant]:
-                            del self._inflight_rows[tenant]
-                        # Completion latency (p50/p95, split per priority)
-                        # was recorded by the engine at publish time.
-                        resolved.append((fut, self.engine.take_result(rid)))
-                self._resolving += len(resolved) + len(failed)
-            # Resolve outside the lock: user callbacks must not deadlock us.
-            # set_running_or_notify_cancel() guards against futures the
-            # caller cancelled (e.g. after a result() timeout) — resolving
-            # those would raise InvalidStateError and kill this thread.
-            with span("mole.flush.resolve"):
-                for fut, feats in resolved:
-                    if fut.set_running_or_notify_cancel():
-                        fut.set_result(feats)
-                for fut, err in failed:
-                    if fut.set_running_or_notify_cancel():
-                        fut.set_exception(err)
-            # Notify only after the futures are resolved, so a drain()er
-            # waking on an empty in-flight table can rely on .result()
-            # being immediate.
-            with self._cv:
-                self._resolving -= len(resolved) + len(failed)
-                self._cv.notify_all()  # quota freed / drain() progress
-            # Predictive prefetch in the inter-round slack: stage tenants
-            # the arrival predictor expects before their burst lands.  Under
-            # the lock (slot assignment + plan patches mutate engine state),
-            # but after futures resolved — waiters never wait on staging.
-            if self.prefetch_horizon_ms is not None and error is None:
-                with self._cv:
-                    if self._dead is None and not self._closed:
-                        self.engine.predictive_prefetch(self.prefetch_horizon_ms)
-            # Supervised snapshotting between flush rounds: the image is
-            # captured under the lock (a consistent cut — publish has
-            # completed, nothing is half-scattered) but written *off* it,
-            # so disk I/O never blocks submitters.
-            if self._snapshotter is not None and error is None and work:
-                self._rounds += 1
-                if self._rounds % self.snapshot_every == 0:
+                    if publishing is not None and publishing[0].serial:
+                        publishing = self._published(publishing)
+                    work = None
                     with self._cv:
-                        snap = self.engine.snapshot()
-                        self._snapshot_step += 1
-                        step = self._snapshot_step
-                    snap.save(self._snapshotter, step)
+                        if self._wait_for_round(publishing):
+                            self._force_flush = False
+                            # Coalesce the queues into private work items.
+                            # Afterwards the queues are empty — the second
+                            # buffer — and submitters fill them meanwhile.
+                            work = self.engine.begin_flush()
+                        elif publishing is None:
+                            return          # closed, nothing in flight
+                    if publishing is not None and (work is None or work.serial):
+                        publishing = self._published(publishing)
+                    if work is None:
+                        continue
+                    if publishing is not None and not publishing[1].done():
+                        self.engine.stats.rounds_overlapped += 1
+                    # The device phase OUTSIDE the lock: submitters keep
+                    # acquiring _cv while the jitted steps run.
+                    self.engine.execute_flush(work)
+                    self._published(publishing)
+                    publishing = (work, self._publisher.submit(self._publish,
+                                                               work))
+                    publishing[1].add_done_callback(self._wake)
+                except SimulatedFailure:
+                    raise   # recoverable: _supervise requeues the rounds
+                except Exception as e:
+                    self._settle(publishing, e)
+                    publishing = None
+                    self._fail_inflight(e)
+        except BaseException as e:
+            # Recovery and teardown reset engine state the publisher writes:
+            # let the publish in flight finish first.
+            self._settle(publishing, e)
+            raise
+
+    def _wake(self, _future) -> None:
+        with self._cv:
+            self._cv.notify_all()   # the flusher may wait on this publish
+
+    @staticmethod
+    def _published(publishing) -> None:
+        """Wait for a round's publish; raise what it raised."""
+        if publishing is not None:
+            publishing[1].result()
+
+    @staticmethod
+    def _settle(publishing, failure: BaseException) -> None:
+        """Wait for a round's publish, whatever its outcome: the flusher is
+        already handling ``failure``, so another failure of the publish is
+        only logged (its class: the message may embed request payloads)."""
+        if publishing is not None:
+            error = publishing[1].exception()
+            if error is not None and error is not failure:
+                _log.error("the publish in flight failed too, with %s",
+                           type(error).__name__)
+
+    # analysis: requires-lock(_cv)
+    def _wait_for_round(self, publishing) -> bool:
+        """Caller holds ``self._cv``.  Wait until a round is due (True), or,
+        with none due, until the round ``publishing`` has published or,
+        with none publishing, the front door is closed with nothing in
+        flight (False)."""
+        unpublished = None if publishing is None else publishing[0]
+        skip = frozenset() if unpublished is None else unpublished.complete
+        with span("mole.flusher.idle"):
+            while not self._should_flush(time.monotonic(), unpublished):
+                if publishing is None:
+                    if self._closed and not self._futures:
+                        return False
+                elif publishing[1].done():
+                    return False
+                deadline = self._oldest_deadline(skip)
+                timeout = (
+                    None if deadline is None
+                    else max(0.0, deadline - time.monotonic())
+                )
+                self._cv.wait(timeout=timeout)
+        return True
+
+    def _publish(self, work) -> None:
+        """Publish one executed round, on the publisher thread: the engine
+        scatters its rows off the lock and commits them under it; then pop
+        the completed requests' futures and resolve them outside the
+        lock."""
+        done = self.engine.publish_flush(work, lock=self._cv)
+        resolved: list[tuple[Future, object]] = []
+        with self._cv:
+            for rid in done:
+                # A rid submitted to the sync engine directly (mixed API
+                # use) completes here too but is not ours to resolve —
+                # leave its result for engine.take().
+                fut = self._futures.pop(rid, None)
+                if fut is None:
+                    if rid in self._cancelled:
+                        # The waiter gave up (cancel-on-timeout): pop-and-
+                        # drop the result so it doesn't strand in the
+                        # engine's buffers.
+                        self._cancelled.discard(rid)
+                        self.engine.take_result(rid)
+                    continue
+                self._submitted_at.pop(rid)
+                tenant, n_rows = self._rid_tenant.pop(rid)
+                self._inflight_rows[tenant] -= n_rows
+                if not self._inflight_rows[tenant]:
+                    del self._inflight_rows[tenant]
+                # Completion latency (p50/p95, split per priority) was
+                # recorded by the engine at publish time.
+                resolved.append((fut, self.engine.take_result(rid)))
+            self._resolving += len(resolved)
+        # Resolve outside the lock: user callbacks must not deadlock us.
+        # set_running_or_notify_cancel() guards against futures the caller
+        # cancelled (e.g. after a result() timeout) — resolving those would
+        # raise InvalidStateError and kill this thread.
+        with span("mole.flush.resolve"):
+            for fut, result in resolved:
+                if fut.set_running_or_notify_cancel():
+                    fut.set_result(result)
+        # Notify only after the futures are resolved, so a drain()er waking
+        # on an empty in-flight table can rely on .result() being immediate.
+        with self._cv:
+            self._resolving -= len(resolved)
+            self._cv.notify_all()  # quota freed / drain() progress
+        # Predictive prefetch in the inter-round slack: stage tenants the
+        # arrival predictor expects before their burst lands.  Under the
+        # lock (slot assignment + plan patches mutate engine state), but
+        # after futures resolved — waiters never wait on staging.  A round
+        # in flight keeps the plan snapshot it was coalesced against.
+        if self.prefetch_horizon_ms is not None:
+            with self._cv:
+                if self._dead is None and not self._closed:
+                    self.engine.predictive_prefetch(self.prefetch_horizon_ms)
+        # Supervised snapshotting between flush rounds: the image is
+        # captured under the lock (a consistent cut — the round's commit has
+        # completed, and a round in flight is still pending, so it replays)
+        # but written *off* it, so disk I/O never blocks submitters.
+        if self._snapshotter is not None:
+            self._rounds += 1
+            if self._rounds % self.snapshot_every == 0:
+                with self._cv:
+                    snap = self.engine.snapshot()
+                    self._snapshot_step += 1
+                    step = self._snapshot_step
+                snap.save(self._snapshotter, step)
+
+    def _fail_inflight(self, error: Exception) -> None:
+        """A failed flush must not strand waiters: fail everything in
+        flight and reset the accounting — including the wrapped engine's
+        queued rows and result buffers, which would otherwise be coalesced
+        by a later flush into results nobody can take().  (Requests
+        submitted during the round fail too: their rows may already be
+        coalesced into the failed work items.)"""
+        with self._cv:
+            failed = list(self._futures.values())
+            # Every caught error is re-surfaced into the waiters' futures
+            # below (or an EngineDeadError on the next submit); the log
+            # carries the error *class* only — `str(error)` may embed
+            # repr'd request payloads.
+            _log.error(
+                "flush round failed with %s: failing %d waiter(s)",
+                type(error).__name__, len(failed),
+            )
+            self.engine.stats.flush_failures += 1
+            self._futures.clear()
+            self._submitted_at.clear()
+            self._deadline_heap.clear()
+            self._rid_tenant.clear()
+            self._inflight_rows.clear()
+            self._cancelled.clear()  # their engine state resets too
+            self.engine.reset_pending()
+            self._resolving += len(failed)
+        with span("mole.flush.resolve"):
+            for fut in failed:
+                if fut.set_running_or_notify_cancel():
+                    fut.set_exception(error)
+        with self._cv:
+            self._resolving -= len(failed)
+            self._cv.notify_all()

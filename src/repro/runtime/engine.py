@@ -46,10 +46,11 @@ assert on).
 **Phase-split flushing.**  :meth:`MoLeDeliveryEngine.flush` is three phases —
 :meth:`begin_flush` (coalesce every lane's pending rows into microbatch work
 items), :meth:`execute_flush` (run the jitted device steps), and
-:meth:`publish_flush` (scatter results back to per-request buffers).  The
-sync ``flush()`` just chains them; the async front door calls them
-separately so only coalesce/publish run under its lock and the device step
-never blocks submitters (``repro.runtime.async_engine``).
+:meth:`publish_flush` (scatter results into per-request buffers, then commit
+the completed ones).  The sync ``flush()`` chains them round by round; the
+async front door holds its lock only for the coalesce and the commit, and
+runs round n+1's device phase while round n publishes
+(``repro.runtime.async_engine``).
 
 This class is **not** thread-safe; ``repro.runtime.async_engine`` layers a
 lock, a background deadline flusher, and admission control on top.
@@ -134,6 +135,9 @@ class EngineStats:
     rows_padded: int = 0        # zero rows added by bucketing
     microbatches: int = 0
     flushes: int = 0
+    # Flush rounds the async front door dispatched while the previous
+    # round was still publishing (its flush rounds run two deep).
+    rounds_overlapped: int = 0
     rejected: int = 0           # requests refused by admission control
     blocked: int = 0            # submits that waited on quota backpressure
     # Padding groups whose slot index hit the clamp bound during coalescing:
@@ -330,6 +334,7 @@ class EngineStats:
         lines = [
             f"requests={self.requests} rows_in={self.rows_in} "
             f"microbatches={self.microbatches} flushes={self.flushes} "
+            f"rounds_overlapped={self.rounds_overlapped} "
             f"padding={self.padding_fraction:.0%} "
             f"padding_clamps={self.padding_clamp_count}",
             f"completion latency: p50={_fmt_ms(self.p50_ms)} "
@@ -520,6 +525,9 @@ class _WorkItem:
     mb: object                  # runtime.queue.Microbatch (decode: DecodeWork)
     plan: _Plan                 # slot secrets as of this item's coalesce
     want_embed: bool = False    # tokens lane: run the Aug-Embedding gather
+    # rid -> (rows, result shape) of each request the item carries rows of,
+    # read at coalesce so the scatter reads no engine dict.
+    shapes: dict = dataclasses.field(default_factory=dict)
     out: object = None          # host results, set by execute_flush
 
 
@@ -537,10 +545,28 @@ class _ReqInfo:
 @dataclasses.dataclass
 class _FlushWork:
     """The coalesced work items one flush hands from phase to phase; holds
-    everything execute_flush needs so it never touches mutable engine or
-    registry state."""
+    everything execute_flush and publish_flush's scatter need so they never
+    touch mutable engine or registry state."""
 
     items: list
+    # Requests whose last rows this round carries (rows lanes; a decode
+    # round's finishers are known only once its step has run).
+    complete: set = dataclasses.field(default_factory=set)
+
+    @property
+    def serial(self) -> bool:
+        """The round carries a decode step, which reads what the previous
+        round's publish left in the decode lane (``finish_step``): it is
+        never dispatched ahead of that publish."""
+        return any(item.lane == "decode" for item in self.items)
+
+
+def _finish_vision(buf: np.ndarray, shape: tuple) -> np.ndarray:
+    return np.asarray(reroll_batch(buf, shape[1], shape[2]))
+
+
+def _finish_features(buf: np.ndarray, shape: tuple) -> np.ndarray:
+    return buf.reshape(shape)
 
 
 # Shape/static-arg tuples seen by actual traces of the jitted delivery steps.
@@ -690,7 +716,12 @@ class MoLeDeliveryEngine:
         # traffic (serve.py --mode lm, the benchmark sweep) never pays the
         # upload or the device memory.
         self._embed_tables_needed = False
-        self._results: dict[int, np.ndarray] = {}
+        self._results: dict[int, np.ndarray] = {}   # completed, until take
+        # Row buffers of requests a round has published only part of.  Only
+        # the thread that calls publish_flush touches it (the async front
+        # door's publisher), so the scatter runs outside the front door's
+        # lock.
+        self._partial: dict[int, np.ndarray] = {}
         self._request_shape: dict[int, tuple[int, ...]] = {}
         self._token_deliver: dict[int, str] = {}   # rid -> "tokens" | "embed"
         self._embed_shape: dict[int, tuple[int, ...]] = {}
@@ -1090,7 +1121,15 @@ class MoLeDeliveryEngine:
                     self._token_deliver[s.request_id] == "embed"
                     for s in mb.slices
                 )
-                work.items.append(_WorkItem(lane, mb, refresh(), want_embed))
+                shapes = {s.request_id: self._result_shape(lane, s.request_id)
+                          for s in mb.slices}
+                work.complete.update(
+                    s.request_id for s in mb.slices
+                    if s.req_offset + s.n_rows == shapes[s.request_id][0]
+                )
+                work.items.append(
+                    _WorkItem(lane, mb, refresh(), want_embed, shapes)
+                )
         if lm_live and self.decode_lane is not None and self.decode_lane.busy:
             # While a tokens-lane tenant waits for a slot that decode rows
             # pin, the lane admits no row, so the pins drain.
@@ -1114,6 +1153,12 @@ class MoLeDeliveryEngine:
                 clamped, self.stats.padding_clamp_count,
             )
         return work
+
+    def _result_shape(self, lane: str, rid: int) -> tuple[int, tuple]:
+        """A request's row count and the shape of its finished result."""
+        shape = self._request_shape[rid]
+        return shape[0], (self._embed_shape[rid] if lane == "features"
+                          else shape)
 
     def begin_flush(self) -> _FlushWork | None:
         """Phase 1 (cheap, engine-state-mutating): coalesce pending rows
@@ -1201,25 +1246,38 @@ class MoLeDeliveryEngine:
                 self.stats.flushes, dt_ms, self.straggler.ema * 1e3,
             )
 
-    def publish_flush(self, work: _FlushWork) -> dict[int, np.ndarray]:
-        """Phase 3 (cheap, engine-state-mutating): scatter executed results
-        into per-request buffers and mark completed requests done.  Runs
-        under the async front door's lock."""
+    def publish_flush(self, work: _FlushWork,
+                      lock=None) -> dict[int, np.ndarray]:
+        """Phase 3: scatter the round's results into per-request buffers,
+        then commit: completed requests move into the result table and are
+        marked done.  Returns {request_id: result} of the round's completed
+        requests.
+
+        The scatter writes only the buffers of partial requests, which the
+        calling thread owns, so the async front door runs it outside its
+        lock; ``lock`` (the front door's) is held for the commit alone.
+        """
         # Injected *before* any scatter: publish is all-or-nothing per
         # round, so recovery never sees a half-published flush.
         if self.injector is not None:
             self.injector.maybe_fail_phase("publish")
         done: dict[int, np.ndarray] = {}
         with self.stats.phase("publish"):
-            for item in work.items:
-                if item.lane == "vision":
-                    self._publish_rows(item, done, self._finish_vision)
-                elif item.lane == "tokens":
-                    self._publish_tokens(item, done)
-                elif item.lane == "decode":
-                    self._publish_decode(item, done)
-                else:
-                    self._publish_rows(item, done, self._finish_features)
+            with span("mole.flush.scatter"):
+                for item in work.items:
+                    if item.lane == "vision":
+                        self._scatter_rows(item, done, _finish_vision)
+                    elif item.lane == "features":
+                        self._scatter_rows(item, done, _finish_features)
+                    elif item.lane == "tokens":
+                        self._scatter_tokens(item, done)
+            with lock if lock is not None else contextlib.nullcontext():
+                for rid, out in done.items():
+                    self._results[rid] = out
+                    self._mark_done(rid)
+                for item in work.items:
+                    if item.lane == "decode":
+                        self._publish_decode(item, done)
         return done
 
     def _publish_decode(self, item: _WorkItem,
@@ -1250,54 +1308,45 @@ class MoLeDeliveryEngine:
                 priority=info.request.priority,
             )
 
-    def _finish_vision(self, rid: int, buf: np.ndarray) -> np.ndarray:
-        shape = self._request_shape[rid]
-        return np.asarray(reroll_batch(buf, shape[1], shape[2]))
-
-    def _finish_features(self, rid: int, buf: np.ndarray) -> np.ndarray:
-        return buf.reshape(self._embed_shape[rid])
-
-    def _publish_rows(self, item: _WorkItem, done: dict[int, np.ndarray],
+    def _scatter_rows(self, item: _WorkItem, done: dict[int, np.ndarray],
                       finish) -> None:
         out = item.out
         for s in item.mb.slices:
-            shape = self._request_shape[s.request_id]
-            buf = self._results.setdefault(
-                s.request_id,
-                np.empty((shape[0], out.shape[-1]), np.float32),
-            )
+            rid = s.request_id
+            rows, shape = item.shapes[rid]
+            buf = self._partial.get(rid)
+            if buf is None:
+                buf = self._partial[rid] = np.empty((rows, out.shape[-1]),
+                                                    np.float32)
             buf[s.req_offset : s.req_offset + s.n_rows] = out[
                 s.group, s.group_offset : s.group_offset + s.n_rows
             ]
-            if s.req_offset + s.n_rows == shape[0]:
-                done[s.request_id] = finish(s.request_id, buf)
-                self._results[s.request_id] = done[s.request_id]
-                self._mark_done(s.request_id)
+            if s.req_offset + s.n_rows == rows:
+                done[rid] = finish(self._partial.pop(rid), shape)
 
-    def _publish_tokens(self, item: _WorkItem,
+    def _scatter_tokens(self, item: _WorkItem,
                         done: dict[int, np.ndarray]) -> None:
         morphed, feats = item.out
         seq = item.mb.x.shape[2]     # this lane's padded sequence bucket
         for s in item.mb.slices:
             rid = s.request_id
-            shape = self._request_shape[rid]   # (b, L) or (b, L, d)
-            embed = self._token_deliver[rid] == "embed"
-            buf = self._results.get(rid)
+            rows, shape = item.shapes[rid]   # (b, L) or, embedded, (b, L, d)
+            embed = len(shape) == 3
+            buf = self._partial.get(rid)
             if buf is None:
-                buf = self._results[rid] = (
-                    np.empty((shape[0], seq, feats.shape[-1]), np.float32)
-                    if embed else np.empty((shape[0], seq), np.int32)
+                buf = self._partial[rid] = (
+                    np.empty((rows, seq, feats.shape[-1]), np.float32)
+                    if embed else np.empty((rows, seq), np.int32)
                 )
             src = feats if embed else morphed
             buf[s.req_offset : s.req_offset + s.n_rows] = src[
                 s.group, s.group_offset : s.group_offset + s.n_rows
             ]
-            if s.req_offset + s.n_rows == shape[0]:
+            if s.req_offset + s.n_rows == rows:
                 # Strip the sequence padding back to the true length.
-                out = np.ascontiguousarray(buf[:, : shape[1]])
-                done[rid] = out
-                self._results[rid] = done[rid]
-                self._mark_done(rid)
+                done[rid] = np.ascontiguousarray(
+                    self._partial.pop(rid)[:, : shape[1]]
+                )
 
     def flush(self) -> dict[int, np.ndarray]:
         """Run every pending request (all lanes) through padded microbatches.
@@ -1326,7 +1375,7 @@ class MoLeDeliveryEngine:
             if request_id in self._request_shape:
                 n_rows = self._request_shape[request_id][0]
                 state = (
-                    "partially delivered" if request_id in self._results
+                    "partially delivered" if request_id in self._partial
                     else "queued"
                 )
                 raise KeyError(
@@ -1380,6 +1429,7 @@ class MoLeDeliveryEngine:
         """
         self._rebuild_queues()
         self._results.clear()
+        self._partial.clear()
         self._request_shape.clear()
         self._token_deliver.clear()
         self._embed_shape.clear()
@@ -1591,9 +1641,9 @@ class MoLeDeliveryEngine:
         ids in submission order.
         """
         self._rebuild_queues()
+        self._partial.clear()              # drop partial row buffers
         pending = sorted(set(self._req_info) - self._done)
         for rid in pending:
-            self._results.pop(rid, None)   # drop partial row buffers
             info = self._req_info[rid]
             self._enqueue_normalized(
                 info.request, rid=rid, count_stats=False

@@ -20,7 +20,8 @@ SPANS = (
     "mole.flush.dispatch",  # uploads and calls into the jitted steps, all items
     "mole.flush.wait",      # one item: block until its device output is ready
     "mole.flush.fetch",     # one item: copy its ready output to the host
-    "mole.flush.publish",   # publish_flush: scatter results into requests
+    "mole.flush.publish",   # publish_flush: the scatter below, then the commit
+    "mole.flush.scatter",   # results into request buffers, off the lock
     # The async flusher thread (runtime/async_engine.py).
     "mole.flusher.idle",    # waiting for rows or a deadline: no flush is due
     "mole.flush.resolve",   # resolving the round's futures and callbacks
