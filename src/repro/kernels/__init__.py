@@ -9,19 +9,17 @@
   wkv6        — chunked RWKV-6 linear-attention scan (rwkv6_3b long-context)
 
 Each kernel has a pure-jnp oracle in ``ref.py``; ``ops.py`` holds the jit'd
-public wrappers and the backend dispatch.
+public wrappers and is the one place that chooses what runs: the kernel on
+the Pallas backends, its reference on ``jnp`` (``dispatch.py`` resolves the
+backend name).
 """
 from .dispatch import BACKENDS, resolve_backend
 from .ops import (
     aug_conv_forward,
-    aug_conv_forward_batched,
     aug_conv_forward_grouped,
-    aug_embed_batched,
     aug_embed_grouped,
     morph_rows,
-    morph_rows_batched,
     morph_rows_grouped,
-    token_morph_batched,
     token_morph_grouped,
 )
 from .wkv6 import wkv6_chunked
@@ -31,14 +29,10 @@ __all__ = [
     "BACKENDS",
     "resolve_backend",
     "aug_conv_forward",
-    "aug_conv_forward_batched",
     "aug_conv_forward_grouped",
-    "aug_embed_batched",
     "aug_embed_grouped",
     "morph_rows",
-    "morph_rows_batched",
     "morph_rows_grouped",
-    "token_morph_batched",
     "token_morph_grouped",
     "wkv6_chunked",
     "ref",

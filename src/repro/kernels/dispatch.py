@@ -5,8 +5,11 @@ Three backends implement the same math for every public kernel entry point:
   ``pallas``     compiled Pallas kernels (``interpret=False``) — real TPU.
   ``interpret``  Pallas kernels in interpret mode — CPU validation of the
                  kernel bodies themselves (slow: the grid runs in Python).
-  ``jnp``        pure-jnp reference (``kernels.ref``) — XLA-fused; the fast
-                 correct path on CPU.
+  ``jnp``        pure-jnp reference (``kernels.ref``) — XLA-fused; the CPU
+                 path.
+
+This module only names and resolves the backend; ``kernels.ops`` decides
+what each entry point runs for it, one implementation per backend.
 
 Resolution order for ``resolve_backend(None)``:
 

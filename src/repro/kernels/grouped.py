@@ -2,10 +2,10 @@
 
 The engine's microbatch carries a ``(G,)`` vector of *slot indices* into the
 registry's stacked per-tenant secrets (``cores (S, q, q)``, ``augs
-(S, K, N)``).  The batched GEMM in ``aug_gemm.py`` (vmapped over groups)
-needs the per-group secrets materialized as ``(G, ...)`` arrays first — an
-HBM gather copy that ROADMAP measured as the difference between 0.8x and
-4.9x vs per-request delivery at 16 tenants whenever ``gidx != arange(S)``.
+(S, K, N)``).  The GEMM in ``aug_gemm.py`` vmapped over groups would need
+the per-group secrets materialized as ``(G, ...)`` arrays first — an HBM
+gather copy that ROADMAP measured as the difference between 0.8x and 4.9x
+vs per-request delivery at 16 tenants whenever ``gidx != arange(S)``.
 
 These kernels make the hot path **gather-free**: the slot-index vector is
 scalar-prefetched into SMEM (``pltpu.PrefetchScalarGridSpec``), and each

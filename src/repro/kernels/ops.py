@@ -4,13 +4,20 @@ Backend selection (see :mod:`repro.kernels.dispatch`):
 
   * ``pallas``     — compiled Pallas (real TPU),
   * ``interpret``  — Pallas interpret mode (CPU kernel validation),
-  * ``jnp``        — pure-jnp reference (``ref.py``; the fast CPU path).
+  * ``jnp``        — pure-jnp reference (``ref.py``; the CPU path).
 
 ``backend=None`` resolves via ``REPRO_KERNEL_BACKEND`` / hardware auto-detect.
+Callers only pass ``backend`` through: this module alone decides what runs,
+one implementation per backend and entry point, the same program for every
+slot-index layout.
 On the two Pallas backends every GEMM entry point runs its kernel, never the
 reference, at every width: the row axis is zero-padded up to the kernels'
 row tile (and the padding sliced off the result), and a weight axis off the
-128-lane grid is one whole-axis block.
+128-lane grid is one whole-axis block.  The grouped delivery GEMMs pad their
+rows the same way on ``jnp``: XLA:CPU fuses a one-row product in the
+reference's scan into a loop that sums in another order than the unbatched
+product, so an unpadded one-row microbatch would differ from per-request
+delivery in the last bit.
 
 The morph ``x @ blockdiag(core, ..., core)`` (paper eq. 2-4) runs as the
 same GEMM kernel as Aug-Conv: cut each row of ``kappa * q`` features into
@@ -35,12 +42,8 @@ from .grouped import grouped_aug_gemm, tiled_group_gemm
 __all__ = [
     "morph_rows",
     "aug_conv_forward",
-    "morph_rows_batched",
-    "aug_conv_forward_batched",
     "morph_rows_grouped",
     "aug_conv_forward_grouped",
-    "token_morph_batched",
-    "aug_embed_batched",
     "token_morph_grouped",
     "aug_embed_grouped",
     "aug_embed_rows_grouped",
@@ -124,41 +127,6 @@ def _gemm(t, w, backend):
     )[:B]
 
 
-def morph_rows_batched(
-    x: jax.Array, cores: jax.Array, kappa: int, backend: str | None = None
-) -> jax.Array:
-    """Per-group morphing: x (G, B, kappa*q) with cores (G, q, q).
-
-    Each group carries one tenant's secret core; Pallas backends vmap the
-    GEMM kernel over the group axis.
-    """
-    return _morph_rows_batched(x, cores, int(kappa), resolve_backend(backend))
-
-
-@partial(jax.jit, static_argnames=("kappa", "backend"))
-def _morph_rows_batched(x, cores, kappa, backend):
-    if backend == "jnp":
-        return ref.block_diag_matmul_batched_ref(x, cores, kappa)
-    G, q = x.shape[0], cores.shape[-1]
-    return jax.vmap(partial(_gemm, backend=backend))(
-        x.reshape(G, -1, q), cores
-    ).reshape(x.shape)
-
-
-def aug_conv_forward_batched(
-    t: jax.Array, c_acs: jax.Array, backend: str | None = None
-) -> jax.Array:
-    """Per-group Aug-Conv forward: t (G, B, K) @ c_acs (G, K, N) -> (G, B, N)."""
-    return _aug_conv_forward_batched(t, c_acs, resolve_backend(backend))
-
-
-@partial(jax.jit, static_argnames=("backend",))
-def _aug_conv_forward_batched(t, c_acs, backend):
-    if backend == "jnp":
-        return ref.aug_gemm_batched_ref(t, c_acs)
-    return jax.vmap(partial(_gemm, backend=backend))(t, c_acs)
-
-
 def _safe_gidx(gidx: jax.Array, n_slots: int) -> jax.Array:
     """Clamp slot indices into the stacked-secret range.
 
@@ -170,28 +138,6 @@ def _safe_gidx(gidx: jax.Array, n_slots: int) -> jax.Array:
     zeros regardless of whose secret they hit.
     """
     return jnp.clip(gidx.astype(jnp.int32), 0, n_slots - 1)
-
-
-def _with_arange_fast_case(gidx, n_slots, fast, general, *operands):
-    """Value-level fast case for the jnp grouped fallbacks.
-
-    When the microbatch spans the full slot table in slot order (the
-    slot-sorted steady state: ``gidx == arange(S)``), the per-group secrets
-    are the stacked array itself, and XLA's batched einsum reads it in place
-    with full threading — measurably faster on CPU than the scan of dynamic
-    slices.  The check is a ``lax.cond`` on the *values*, inside one
-    compiled graph: unlike the engine's old host-side ``identity_gather``
-    static flag there is nothing to re-trace when traffic shifts between
-    layouts, and the Pallas backends never need it (their index maps read
-    in place for any ``gidx``).  Statically skipped unless ``G == S`` —
-    ``arange(G)`` cannot cover a larger table.
-    """
-    if gidx.shape[0] != n_slots:
-        return general(*operands)
-    return jax.lax.cond(
-        jnp.array_equal(gidx, jnp.arange(n_slots, dtype=gidx.dtype)),
-        fast, general, *operands,
-    )
 
 
 def morph_rows_grouped(
@@ -220,12 +166,8 @@ def _morph_rows_grouped(x, gidx, cores, kappa, backend):
         return _grouped_gemm(
             x.reshape(G, -1, q), gidx, cores, backend, "mole_morph_grouped"
         ).reshape(x.shape)
-    return _with_arange_fast_case(
-        gidx, cores.shape[0],
-        lambda x_, g_: ref.block_diag_matmul_batched_ref(x_, cores, kappa),
-        lambda x_, g_: ref.block_diag_matmul_grouped_ref(x_, g_, cores, kappa),
-        x, gidx,
-    )
+    x, B, _ = _pad_rows(x, 1)
+    return ref.block_diag_matmul_grouped_ref(x, gidx, cores, kappa)[:, :B]
 
 
 def aug_conv_forward_grouped(
@@ -246,12 +188,8 @@ def _aug_conv_forward_grouped(t, gidx, c_acs, backend):
     gidx = _safe_gidx(gidx, c_acs.shape[0])
     if backend != "jnp":
         return _grouped_gemm(t, gidx, c_acs, backend, "mole_aug_conv_grouped")
-    return _with_arange_fast_case(
-        gidx, c_acs.shape[0],
-        lambda t_, g_: ref.aug_gemm_batched_ref(t_, c_acs),
-        lambda t_, g_: ref.aug_gemm_grouped_ref(t_, g_, c_acs),
-        t, gidx,
-    )
+    t, B, _ = _pad_rows(t, 1)
+    return ref.aug_gemm_grouped_ref(t, gidx, c_acs)[:, :B]
 
 
 def _grouped_gemm(t, gidx, ws, backend, name):
@@ -265,46 +203,19 @@ def _grouped_gemm(t, gidx, ws, backend, name):
     )[:, :B]
 
 
-def token_morph_batched(
-    tokens: jax.Array, perms: jax.Array, backend: str | None = None
-) -> jax.Array:
-    """Per-group token morphing: tokens (G, B, L) with perms (G, V).
-
-    The LM delivery-engine hot path.  Discrete morphing is a dynamic gather
-    — memory-bound, no MACs — so every backend routes to XLA's native gather
-    (the Pallas kernels in this package exist for the GEMM-shaped paths;
-    hand-rolling a TPU gather here would only re-derive what Mosaic emits).
-    The ``backend`` flag is still resolved/validated so call sites stay
-    uniform with the GEMM entry points.
-    """
-    resolve_backend(backend)
-    return ref.token_morph_batched_ref(tokens, perms)
-
-
-def aug_embed_batched(
-    tokens: jax.Array, tables: jax.Array, backend: str | None = None
-) -> jax.Array:
-    """Per-group Aug-Embedding forward: morphed tokens (G, B, L) gathered
-    from per-group (V, d) tables -> (G, B, L, d).
-
-    Like :func:`token_morph_batched`, a gather on every backend — "gather
-    stays a gather: zero runtime overhead" (``core.lm``).
-    """
-    resolve_backend(backend)
-    return ref.aug_embed_batched_ref(tokens, tables)
-
-
 def token_morph_grouped(
     tokens: jax.Array, gidx: jax.Array, perms: jax.Array,
     backend: str | None = None,
 ) -> jax.Array:
     """Slot-indexed token morphing: tokens (G, B, L), gidx (G,), perms (S, V).
 
-    The LM twin of :func:`morph_rows_grouped`: each scan step dynamic-slices
-    one slot's permutation out of the stacked ``(S, V)`` table, so the
-    ``(G, V)`` per-microbatch permutation copy is never materialized.  A
-    gather-of-gathers is still memory-bound with no MACs, so every backend
-    routes to the XLA formulation (see :func:`token_morph_batched`).
+    The LM twin of :func:`morph_rows_grouped`: one gather reads each
+    group's tokens through its slot's permutation in place from the stacked
+    ``(S, V)`` table, so the ``(G, V)`` per-microbatch permutation copy is
+    never materialized.  Discrete morphing is a gather — memory-bound, no
+    MACs — so every backend runs the XLA gather (hand-rolling a TPU gather
+    would only re-derive what Mosaic emits); ``backend`` is still resolved
+    and validated so call sites stay uniform with the GEMM entry points.
     """
     resolve_backend(backend)
     return _token_morph_grouped(tokens, gidx, perms)
@@ -313,12 +224,7 @@ def token_morph_grouped(
 @jax.jit
 def _token_morph_grouped(tokens, gidx, perms):
     gidx = _safe_gidx(gidx, perms.shape[0])
-    return _with_arange_fast_case(
-        gidx, perms.shape[0],
-        lambda t_, g_: ref.token_morph_batched_ref(t_, perms),
-        lambda t_, g_: ref.token_morph_grouped_ref(t_, g_, perms),
-        tokens, gidx,
-    )
+    return perms[gidx[:, None, None], tokens]
 
 
 def aug_embed_grouped(
@@ -327,7 +233,9 @@ def aug_embed_grouped(
 ) -> jax.Array:
     """Slot-indexed Aug-Embedding: morphed tokens (G, B, L) gathered from the
     stacked ``(S, V, d)`` tables via gidx (G,) -> (G, B, L, d), without the
-    ``(G, V, d)`` per-microbatch table copy (the largest secret stack)."""
+    ``(G, V, d)`` per-microbatch table copy (the largest secret stack).
+    Like :func:`token_morph_grouped`, one gather on every backend — "gather
+    stays a gather: zero runtime overhead" (``core.lm``)."""
     resolve_backend(backend)
     return _aug_embed_grouped(tokens, gidx, tables)
 
@@ -335,12 +243,7 @@ def aug_embed_grouped(
 @jax.jit
 def _aug_embed_grouped(tokens, gidx, tables):
     gidx = _safe_gidx(gidx, tables.shape[0])
-    return _with_arange_fast_case(
-        gidx, tables.shape[0],
-        lambda t_, g_: ref.aug_embed_batched_ref(t_, tables),
-        lambda t_, g_: ref.aug_embed_grouped_ref(t_, g_, tables),
-        tokens, gidx,
-    )
+    return tables[gidx[:, None, None], tokens]
 
 
 def aug_embed_rows_grouped(
@@ -380,8 +283,7 @@ def lm_head_rows_grouped(
     its rows, in place, and a slot no row uses is not read; the jnp
     backend mirrors ``models.stack.lm_head``'s dtype semantics exactly
     (contraction in ``h.dtype``) so batched decode emits bit-identical
-    logits, with the identity arrangement contracting against the stack in
-    place as one batched einsum.  ``keep`` (R,) bool, if given, spares the
+    logits.  ``keep`` (R,) bool, if given, spares the
     Pallas backends the other rows (their logits are zeros there), so a
     slot only such rows use is not read.
     """
@@ -399,12 +301,7 @@ def _lm_head_rows_grouped(h, gidx, heads, backend, keep=None):
         out = _tiled_gemm(h, gidx, heads, bm, backend, "mole_lm_head_grouped",
                           out_dtype=jnp.float32, keep=keep)
         return out.astype(h.dtype)
-    return _with_arange_fast_case(
-        gidx, heads.shape[0],
-        lambda h_, g_: ref.lm_head_rows_batched_ref(h_, heads),
-        lambda h_, g_: ref.lm_head_rows_grouped_ref(h_, g_, heads),
-        h, gidx,
-    )
+    return ref.lm_head_rows_grouped_ref(h, gidx, heads)
 
 
 # -- ragged groups: rows sorted by group, padded to whole tiles -------------------

@@ -45,9 +45,8 @@ def aug_gemm_batched_ref(t: jax.Array, c_acs: jax.Array) -> jax.Array:
 # secrets and never materialize the (G, ...) per-group copy: a lax.scan over
 # the group axis dynamic-slices exactly one slot's secret per step, so peak
 # extra memory is one secret tile (not G of them) and XLA runs each step as a
-# plain GEMM/gather.  This is both the correctness oracle for the Pallas
-# kernels in ``grouped.py`` and the fast CPU path — on CPU it beats the
-# einsum-over-gathered-weights formulation even for the identity index.
+# plain GEMM.  They are both the correctness oracle for the Pallas kernels in
+# ``grouped.py`` and the jnp backend's implementation, for every index vector.
 
 def block_diag_matmul_grouped_ref(
     x: jax.Array, gidx: jax.Array, cores: jax.Array, kappa: int
@@ -84,55 +83,10 @@ def aug_gemm_grouped_ref(
     return out
 
 
-def token_morph_grouped_ref(
-    tokens: jax.Array, gidx: jax.Array, perms: jax.Array
-) -> jax.Array:
-    """Slot-indexed token morphing: tokens (G, B, L), gidx (G,), perms (S, V)."""
-
-    def step(_, inp):
-        tg, i = inp
-        p = jax.lax.dynamic_index_in_dim(perms, i, 0, keepdims=False)
-        return None, p[tg]
-
-    _, out = jax.lax.scan(step, None, (tokens, gidx))
-    return out
-
-
-def aug_embed_grouped_ref(
-    tokens: jax.Array, gidx: jax.Array, tables: jax.Array
-) -> jax.Array:
-    """Slot-indexed Aug-Embedding: tokens (G, B, L), gidx (G,),
-    tables (S, V, d) -> (G, B, L, d)."""
-
-    def step(_, inp):
-        tg, i = inp
-        e = jax.lax.dynamic_index_in_dim(tables, i, 0, keepdims=False)
-        return None, e[tg]
-
-    _, out = jax.lax.scan(step, None, (tokens, gidx))
-    return out
-
-
 def aug_gemm_ref(t: jax.Array, c_ac: jax.Array) -> jax.Array:
     return jnp.dot(
         t.astype(jnp.float32), c_ac.astype(jnp.float32)
     ).astype(t.dtype)
-
-
-def token_morph_batched_ref(tokens: jax.Array, perms: jax.Array) -> jax.Array:
-    """Per-group token morphing: each group gathers its own vocab permutation.
-
-    tokens: (G, B, L) int; perms: (G, V) int -> morphed (G, B, L) int.
-    """
-    return jax.vmap(lambda p, t: p[t])(perms, tokens)
-
-
-def aug_embed_batched_ref(tokens: jax.Array, tables: jax.Array) -> jax.Array:
-    """Per-group Aug-Embedding forward: each group has its own (V, d) table.
-
-    tokens: (G, B, L) int; tables: (G, V, d) -> features (G, B, L, d).
-    """
-    return jax.vmap(lambda e, t: e[t])(tables, tokens)
 
 
 def aug_embed_rows_grouped_ref(
@@ -164,12 +118,6 @@ def lm_head_rows_grouped_ref(
 
     _, out = jax.lax.scan(step, None, (h, gidx))
     return out
-
-
-def lm_head_rows_batched_ref(h: jax.Array, heads: jax.Array) -> jax.Array:
-    """Per-row LM-head GEMM, one resident head per row (fast case):
-    h (R, d), heads (R, d, V) -> (R, V), contraction in ``h.dtype``."""
-    return jnp.einsum("rd,rdv->rv", h, heads.astype(h.dtype))
 
 
 def wkv6_ref(

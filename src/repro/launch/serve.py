@@ -113,7 +113,7 @@ def run_delivery(args) -> dict:
                         m=args.image_size, p=3)
     # Default the slot capacity to the tenant count: an exactly-sized slot
     # table keeps the steady-state "all tenants active" microbatch free of
-    # padding groups (and on CPU, on the in-place arange fast case).
+    # padding groups.
     capacity = args.capacity if args.capacity is not None else args.tenants
     registry = SessionRegistry(geom, kappa=args.kappa, capacity=capacity)
     fan_in = geom.alpha * geom.p * geom.p

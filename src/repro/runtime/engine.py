@@ -22,14 +22,16 @@ Groups never mix tenants, so tenant A's rows are only ever morphed with
 tenant A's secrets — the isolation property asserted in
 ``tests/test_engine.py`` / ``tests/test_lm_engine.py``.
 
-Kernel backend selection follows ``repro.kernels.dispatch``: the slot-indexed
-grouped Pallas kernels (``kernels.grouped``) on TPU, the scan-based jnp
-reference on CPU — a flag, not the old hard-coded ``interpret=True``.  Every
-lane reads per-tenant secrets **in place** from the stacked ``(S, ...)``
-slot arrays (``kernels.ops.morph_rows_grouped`` and friends): there is no
-per-microbatch ``secrets[gidx]`` gather copy and no identity-order special
-case — out-of-order, duplicate, and partial-table microbatches cost the
-same as the slot-ordered steady state.
+The engine does not choose kernels: it resolves ``backend`` once
+(``repro.kernels.dispatch``) and hands it to its jitted steps, and
+``repro.kernels.ops`` runs one implementation per backend — the
+slot-indexed grouped Pallas kernels (``kernels.grouped``) on TPU, the
+scan-based jnp reference on CPU.  Every lane reads per-tenant secrets **in
+place** from the stacked ``(S, ...)`` slot arrays
+(``kernels.ops.morph_rows_grouped`` and friends): there is no per-microbatch
+``secrets[gidx]`` gather copy and no identity-order special case —
+out-of-order, duplicate, and partial-table microbatches run the same
+program as the slot-ordered steady state.
 
 Under an active mesh the group axis is sharded over the data-parallel axes
 (``repro.sharding.rules.delivery_rules`` / ``hints.hint``); on a single
@@ -73,7 +75,6 @@ import numpy as np
 from repro.core.d2r import reroll_batch
 from repro.core.lm import LMSessionRegistry
 from repro.core.protocol import SessionRegistry
-from repro.kernels import ref as kref
 from repro.kernels.dispatch import resolve_backend
 from repro.kernels.ops import (
     aug_conv_forward_grouped,
@@ -141,8 +142,9 @@ class EngineStats:
     rejected: int = 0           # requests refused by admission control
     blocked: int = 0            # submits that waited on quota backpressure
     # Padding groups whose slot index hit the clamp bound during coalescing:
-    # such groups read a real tenant's secrets with all-zero rows (harmless,
-    # sliced away) but signal a sparse-table layout CPU serving pays for.
+    # such a group reads the last slot's secrets for all-zero rows (harmless,
+    # sliced away); the count shows how often the group bucket outgrew the
+    # slot table.
     padding_clamp_count: int = 0
     # Resilience counters: flushes whose device phase the straggler monitor
     # flagged as slow, flush rounds that failed all their waiters, engine
@@ -430,16 +432,9 @@ class _Plan:
 
     version: int
     arrays: dict[str, jax.Array]    # name -> (S, ...) stacked per-slot secret
-    # name -> per-slot device arrays, kept only for lanes named in
-    # ``_sync_plan(..., keep_slots=)``.  The small-batch dispatch path and
-    # the decode lane's row prefill index single slots on the host; slicing
-    # the (S, ...) stack per call would copy, so those lanes pay 2x device
-    # memory to keep the unstacked views resident.
-    slots: dict[str, tuple] = dataclasses.field(default_factory=dict)
 
 
-def _sync_plan(plan, registry, slot_fns: dict[str, Callable[[int], np.ndarray]],
-               keep_slots: tuple[str, ...] = ()):
+def _sync_plan(plan, registry, slot_fns: dict[str, Callable[[int], np.ndarray]]):
     """Bring a device plan up to ``registry.version``.
 
     ``slot_fns`` maps each stacked-array name to the registry's per-slot
@@ -447,11 +442,8 @@ def _sync_plan(plan, registry, slot_fns: dict[str, Callable[[int], np.ndarray]],
     shapes are stable, so neither the scatter nor the jitted delivery steps
     retrace on tenant churn, and the (S, ...) stacks are copied once, not
     once per slot.  A full rebuild happens only when the changelog has been
-    trimmed or capacity grew (auto-capacity doubling).
-
-    Lanes named in ``keep_slots`` additionally retain the per-slot device
-    arrays in ``plan.slots[name]`` (tuple of S arrays).  Patches build a new
-    tuple rather than mutating, so earlier ``_WorkItem`` snapshots keep the
+    trimmed or capacity grew (auto-capacity doubling).  A patch builds new
+    arrays rather than mutating, so earlier ``_WorkItem`` snapshots keep the
     secrets they were coalesced against.
     """
     if plan is not None and plan.version != registry.version:
@@ -465,45 +457,24 @@ def _sync_plan(plan, registry, slot_fns: dict[str, Callable[[int], np.ndarray]],
             plan = dataclasses.replace(plan, version=registry.version)
         else:
             idx = jnp.asarray(slots, jnp.int32)
-            fresh = {
-                name: {s: jnp.asarray(fn(s)) for s in slots}
-                for name, fn in slot_fns.items() if name in keep_slots
-            }
             plan = _Plan(
                 version=registry.version,
                 arrays={
                     name: plan.arrays[name].at[idx].set(
-                        jnp.stack(list(fresh[name].values()))
-                        if name in keep_slots
-                        else np.stack([fn(s) for s in slots])
+                        np.stack([fn(s) for s in slots])
                     )
                     for name, fn in slot_fns.items()
                 },
-                slots={
-                    name: tuple(
-                        fresh[name].get(s, old)
-                        for s, old in enumerate(plan.slots[name])
-                    )
-                    for name in plan.slots
-                },
             )
     if plan is None:
-        per_slot = {
-            name: tuple(
-                jnp.asarray(fn(s)) for s in range(registry.capacity)
-            )
-            for name, fn in slot_fns.items() if name in keep_slots
-        }
         plan = _Plan(
             version=registry.version,
             arrays={
-                name: jnp.stack(per_slot[name]) if name in keep_slots
-                else jnp.asarray(
+                name: jnp.asarray(
                     np.stack([fn(s) for s in range(registry.capacity)])
                 )
                 for name, fn in slot_fns.items()
             },
-            slots=per_slot,
         )
     return plan
 
@@ -856,11 +827,7 @@ class MoLeDeliveryEngine:
     def _refresh_plan(self) -> _Plan:
         reg = self.registry
         plan = _sync_plan(
-            self._plan, reg,
-            {"cores": reg.slot_core, "augs": reg.slot_aug},
-            # The small-batch path indexes single slots on the host; only
-            # the jnp backend routes there (Pallas shapes stay grouped).
-            keep_slots=("cores", "augs") if self.backend == "jnp" else (),
+            self._plan, reg, {"cores": reg.slot_core, "augs": reg.slot_aug}
         )
         if plan is not self._plan:
             self._plan = plan
@@ -878,16 +845,13 @@ class MoLeDeliveryEngine:
         slot_fns = {"perms": reg.slot_perm}
         if self._embed_tables_needed:
             slot_fns["aug_embeds"] = reg.slot_aug_embedding
-        keep = ()
         if reg.has_embed_lane:
             slot_fns["embed_cores"] = reg.slot_embed_core
             slot_fns["aug_projs"] = reg.slot_aug_projection
-            if self.backend == "jnp":
-                keep = ("embed_cores", "aug_projs")
         prev = self._lm_plan
         if prev is not None and set(prev.arrays) != set(slot_fns):
             prev = None   # lane set changed (first embed request): rebuild
-        plan = _sync_plan(prev, reg, slot_fns, keep_slots=keep)
+        plan = _sync_plan(prev, reg, slot_fns)
         if plan is not self._lm_plan:
             self._lm_plan = plan
             for q in (self.token_queue, self.embed_queue):
@@ -977,37 +941,8 @@ class MoLeDeliveryEngine:
         return rid
 
     # -- the jitted hot paths ------------------------------------------------
-    def _small_batch(self, gidx: np.ndarray, n_rows: int, plan: _Plan,
-                     lane: str) -> bool:
-        """Route tiny microbatches to the unrolled per-slot step.
-
-        The grouped jnp reference is a scan of dynamic slices over the
-        stacked secrets: on CPU that slice is a copy (~1.3 GB/s) while the
-        GEMMs it feeds run at ~21 GB/s, so at B <= 8 the flush is
-        copy-bound and *slower than per-request dispatch* (the b8/t16
-        0.25x regression).  The unrolled step takes the per-slot device
-        arrays as arguments instead — zero slicing — and wins there, but
-        loses to the scan at B >= 16 (G dispatches of tiny GEMMs) and to
-        the in-place batched einsum when ``gidx`` is the identity
-        arrangement (the G == S steady state the fast case serves), so
-        both keep the grouped path.
-        """
-        if self.backend != "jnp" or lane not in plan.slots or n_rows > 8:
-            return False
-        g, s = gidx.shape[0], len(plan.slots[lane])
-        if g > 16:
-            return False
-        return not (g == s and np.array_equal(gidx, np.arange(s)))
-
     def _execute(self, x: np.ndarray, gidx: np.ndarray,
                  plan: _Plan) -> jax.Array:
-        if self._small_batch(gidx, x.shape[1], plan, "cores"):
-            return _delivery_step_small(
-                jnp.asarray(x),
-                tuple(plan.slots["cores"][g] for g in gidx),
-                tuple(plan.slots["augs"][g] for g in gidx),
-                self.registry.kappa,
-            )
         return _delivery_step(
             jnp.asarray(x), jnp.asarray(gidx),
             plan.arrays["cores"], plan.arrays["augs"],
@@ -1027,13 +962,6 @@ class MoLeDeliveryEngine:
                           plan: _Plan) -> jax.Array:
         # The continuous LM lane *is* the vision math (m^2 -> 1): same jitted
         # step, with the registry's embedding cores / fused projections.
-        if self._small_batch(gidx, x.shape[1], plan, "embed_cores"):
-            return _delivery_step_small(
-                jnp.asarray(x),
-                tuple(plan.slots["embed_cores"][g] for g in gidx),
-                tuple(plan.slots["aug_projs"][g] for g in gidx),
-                self.lm_registry.kappa,
-            )
         return _delivery_step(
             jnp.asarray(x), jnp.asarray(gidx),
             plan.arrays["embed_cores"], plan.arrays["aug_projs"],
@@ -1701,26 +1629,3 @@ def _lm_delivery_step(tokens, gidx, perms, aug_embeds, backend: str,
         return morphed, None
     feats = aug_embed_grouped(morphed, gidx, aug_embeds, backend=backend)
     return morphed, hint(feats, "dp")
-
-
-# analysis: forbids-lock(_cv)
-@partial(jax.jit, static_argnames=("kappa",))
-def _delivery_step_small(x, cores: tuple, augs: tuple, kappa: int):
-    """Small-batch sibling of :func:`_delivery_step`: per-group secrets as
-    separate arguments, groups unrolled.
-
-    x: (G, B, F_in); cores / augs: G-tuples of (q, q) / (F_in, F_out) —
-    the per-slot device arrays :func:`_sync_plan` keeps alongside the
-    stacks.  Same per-group reference math as the scan path (bit-identical
-    output); what changes is only how each group's secrets reach it: as
-    pre-sliced arguments, not ``dynamic_slice`` copies out of the stack.
-    Retraces per distinct (shape, G, kappa) — G is bucketized and routing
-    caps it at 16, so the trace set stays small.
-    """
-    _TRACES[("small", x.shape, len(cores), kappa)] += 1
-    x = hint(x, "dp")
-    outs = []
-    for g in range(x.shape[0]):
-        t = kref.block_diag_matmul_ref(x[g], cores[g], kappa)
-        outs.append(kref.aug_gemm_ref(t, augs[g]))
-    return hint(jnp.stack(outs), "dp")

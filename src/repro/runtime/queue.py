@@ -679,8 +679,7 @@ class RequestQueue:
 
         # Slot-sorted coalescing: order groups by their registry slot so the
         # grouped kernels see monotone indices (adjacent groups sharing a
-        # slot reuse the resident secret tile, and the full-table microbatch
-        # degenerates to gidx == arange).
+        # slot reuse the resident secret tile).
         chunks.sort(key=lambda c: slot_of[c[0]])  # stable: WFQ order in a slot
 
         largest = max(sum(n for _, _, n in runs) for _, runs in chunks)
@@ -700,14 +699,12 @@ class RequestQueue:
                 cursor += n
                 n_real_rows += n
         # Padding groups carry their own group index, clamped to the slot
-        # table bound (max_groups == registry capacity in engine use):
-        # all-zero rows make their output zeros regardless of whose secrets
-        # they hit, and a dense prefix of active slots plus padding
-        # degenerates to gidx == arange — the in-place fast case on the jnp
-        # backend (the grouped kernels cost the same either way).  Clamps
-        # are counted so the engine can surface them (padding_clamp_count):
-        # a clamped group reads a real tenant's secrets with zero rows —
-        # harmless, but a sparse-table CPU serving regression worth seeing.
+        # table bound (max_groups == registry capacity in engine use), so
+        # every index is in range and a dense prefix of active slots plus
+        # padding stays monotone.  All-zero rows make their output zeros
+        # regardless of whose secrets they hit.  Clamps are counted so the
+        # engine can surface them (padding_clamp_count): a clamped group
+        # reads the last slot's secrets for zero rows.
         pad = np.arange(len(chunks), G, dtype=np.int32)
         gidx[len(chunks):] = np.minimum(pad, max_groups - 1)
         n_clamped = int(np.count_nonzero(pad > max_groups - 1))

@@ -9,7 +9,7 @@ import pytest
 
 from repro.core import ConvGeometry, SessionRegistry, morph
 from repro.core.morphing import unmorph
-from repro.kernels import morph_rows_batched, aug_conv_forward_batched, ref
+from repro.kernels import aug_conv_forward_grouped, morph_rows_grouped, ref
 from repro.kernels.dispatch import resolve_backend
 from repro.runtime import (
     DeliveryRequest,
@@ -406,19 +406,22 @@ def test_take_unflushed_request_id_raises_pending_context(rng):
 
 
 # ---------------------------------------------------------------------------
-# batched kernel dispatch (CPU path) vs protocol-level morphing
+# grouped kernel dispatch on the full slot table (G == S, gidx = arange)
+# vs protocol-level morphing
 # ---------------------------------------------------------------------------
 
 def test_batched_dispatch_matches_protocol_morph(rng):
-    """morph_rows_batched (jnp backend) == per-group morphing.morph."""
+    """morph_rows_grouped (jnp backend) over a full slot table in slot
+    order == per-group morphing.morph."""
     from repro.core.morphing import make_core
 
     kappa, q, G, B = 2, 16, 3, 5
     cores = [make_core(rng, kappa * q, kappa) for _ in range(G)]
     x = rng.standard_normal((G, B, kappa * q)).astype(np.float32)
-    got = morph_rows_batched(
-        jnp.asarray(x), jnp.asarray(np.stack([c.matrix for c in cores])),
-        kappa, backend="jnp",
+    got = morph_rows_grouped(
+        jnp.asarray(x), jnp.arange(G, dtype=jnp.int32),
+        jnp.asarray(np.stack([c.matrix for c in cores])), kappa,
+        backend="jnp",
     )
     for g in range(G):
         want = np.asarray(morph(jnp.asarray(x[g]), cores[g]))
@@ -426,14 +429,15 @@ def test_batched_dispatch_matches_protocol_morph(rng):
 
 
 def test_batched_dispatch_backends_agree(rng):
-    """jnp reference vs Pallas interpret on a tileable batched shape."""
+    """jnp reference vs Pallas interpret on a tileable full-table shape."""
     G, B, kappa, q = 2, 8, 2, 128
+    gidx = jnp.arange(G, dtype=jnp.int32)
     x = jnp.asarray(rng.standard_normal((G, B, kappa * q)).astype(np.float32))
     cores = jnp.asarray(
         (rng.standard_normal((G, q, q)) / np.sqrt(q)).astype(np.float32)
     )
-    got_jnp = morph_rows_batched(x, cores, kappa, backend="jnp")
-    got_int = morph_rows_batched(x, cores, kappa, backend="interpret")
+    got_jnp = morph_rows_grouped(x, gidx, cores, kappa, backend="jnp")
+    got_int = morph_rows_grouped(x, gidx, cores, kappa, backend="interpret")
     np.testing.assert_allclose(
         np.asarray(got_int), np.asarray(got_jnp), atol=1e-4
     )
@@ -443,8 +447,8 @@ def test_batched_dispatch_backends_agree(rng):
         (rng.standard_normal((G, 256, 128)) / 16).astype(np.float32)
     )
     np.testing.assert_allclose(
-        np.asarray(aug_conv_forward_batched(t, c, backend="interpret")),
-        np.asarray(aug_conv_forward_batched(t, c, backend="jnp")),
+        np.asarray(aug_conv_forward_grouped(t, gidx, c, backend="interpret")),
+        np.asarray(aug_conv_forward_grouped(t, gidx, c, backend="jnp")),
         atol=1e-4,
     )
 
@@ -455,16 +459,17 @@ def test_batched_ref_fallback_for_nontileable(rng):
     10-wide core as one whole-axis block and 3 rows padded to the 8-row
     tile."""
     G, B, kappa, q = 2, 3, 3, 10
+    gidx = jnp.arange(G, dtype=jnp.int32)
     x = jnp.asarray(rng.standard_normal((G, B, kappa * q)).astype(np.float32))
     cores = jnp.asarray(rng.standard_normal((G, q, q)).astype(np.float32))
     want = np.asarray(ref.block_diag_matmul_batched_ref(x, cores, kappa))
     np.testing.assert_allclose(
-        np.asarray(morph_rows_batched(x, cores, kappa, backend="jnp")),
+        np.asarray(morph_rows_grouped(x, gidx, cores, kappa, backend="jnp")),
         want, atol=1e-5,
     )
-    interp = partial(morph_rows_batched, kappa=kappa, backend="interpret")
-    assert "pallas_call" in str(jax.make_jaxpr(interp)(x, cores))
-    got = interp(x, cores)
+    interp = partial(morph_rows_grouped, kappa=kappa, backend="interpret")
+    assert "pallas_call" in str(jax.make_jaxpr(interp)(x, gidx, cores))
+    got = interp(x, gidx, cores)
     assert got.shape == (G, B, kappa * q)
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-4)
 
@@ -553,8 +558,8 @@ def test_queue_coalesce_orders_groups_by_slot():
 
 
 def test_queue_dense_prefix_padding_keeps_arange():
-    """Active slots 0..k plus padding degenerate to gidx == arange — the
-    layout the jnp backend's in-place fast case keys on."""
+    """Padding groups after a dense prefix of active slots 0..k carry their
+    own group index, so gidx stays in range and monotone (== arange)."""
     q = RequestQueue(4, max_rows=8, row_buckets=(1, 2, 4, 8),
                      group_buckets=(1, 2, 4))
     for tenant in ("a", "b", "c"):
