@@ -117,6 +117,7 @@ def _custom_calls_per_step(engine, shapes) -> dict:
         text = _delivery_step.lower(
             jax.ShapeDtypeStruct((G, B, g.in_features), f32),
             jax.ShapeDtypeStruct((G,), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
             jax.ShapeDtypeStruct((S, q, q), f32),
             jax.ShapeDtypeStruct((S, g.in_features, g.out_features), f32),
             kappa=kappa, backend=engine.backend,

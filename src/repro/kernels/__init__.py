@@ -3,9 +3,10 @@
   aug_gemm    — tiled GEMM: developer-side Aug-Conv forward T @ C^{ac}
                 (eq. 5), and provider-side morphing (eq. 2-4) on rows cut
                 to the core width
-  grouped     — slot-indexed grouped GEMMs: the gather-free delivery hot path
-                (per-tenant secrets read in place from the stacked slot table
-                via scalar-prefetched index maps)
+  grouped     — the slot-indexed grouped GEMM (``tiled_group_gemm``): the
+                delivery steps' morph and Aug-Conv, the routed experts and
+                the Aug-head, each group's matrix read in place from its
+                stack via scalar-prefetched index maps
   wkv6        — chunked RWKV-6 linear-attention scan (rwkv6_3b long-context)
 
 Each kernel has a pure-jnp oracle in ``ref.py``; ``ops.py`` holds the jit'd

@@ -1,36 +1,31 @@
-"""Pallas TPU kernels: slot-indexed grouped GEMMs for the delivery engine.
+"""Pallas TPU kernel: the slot-indexed grouped GEMM of the delivery engine.
 
-The engine's microbatch carries a ``(G,)`` vector of *slot indices* into the
-registry's stacked per-tenant secrets (``cores (S, q, q)``, ``augs
-(S, K, N)``).  The GEMM in ``aug_gemm.py`` vmapped over groups would need
-the per-group secrets materialized as ``(G, ...)`` arrays first — an HBM
-gather copy that ROADMAP measured as the difference between 0.8x and 4.9x
-vs per-request delivery at 16 tenants whenever ``gidx != arange(S)``.
+``tiled_group_gemm`` multiplies rows sorted by group, padded to whole
+``bm``-row tiles, each tile by its group's matrix read **in place** from a
+stacked ``(S, K, N)`` array: the tile-to-group vector is scalar-prefetched
+into SMEM (``pltpu.PrefetchScalarGridSpec``), and each grid instance's
+``index_map`` reads its tile's group out of it to DMA the matrix's blocks
+straight from the stack, so no ``(G, K, N)`` per-group copy ever exists.
+Out-of-order, duplicate, and partial-table group vectors all cost the same.
+A tile's group matrix is read once per tile; tiles past the used count hold
+no rows: they skip the product, and their weight index stays on one block,
+so they stream no matrix.
 
-These kernels make the hot path **gather-free**: the slot-index vector is
-scalar-prefetched into SMEM (``pltpu.PrefetchScalarGridSpec``), and each
-grid instance's ``index_map`` reads its group's slot out of it to DMA the
-tenant's secret tile **directly from the stacked array** — no ``(G, ...)``
-copy ever exists.  Out-of-order, duplicate, and partial-table index vectors
-all cost the same as the identity; monotone indices (the queue slot-sorts
-microbatches) additionally let Mosaic reuse a resident tile when adjacent
-groups share a slot.
+It serves every grouped product:
 
-``grouped_aug_gemm`` serves both delivery GEMMs: Aug-Conv on ``(G, B, K)``
-rows, and the morph on rows cut to the core width, ``(G, B * kappa, q)``
-(``ops.morph_rows_grouped``).  Its grid mirrors the unbatched kernel with a
-leading group dimension: (G, B/bm, N/bn, K/bk).
-
-``tiled_group_gemm`` is the ragged form: rows sorted by group and padded to
-whole ``bm``-row tiles, each tile multiplied by its group's matrix.  It
-serves the dropless MoE (tokens grouped by expert, the kernel named
-``mole_expert_gmm``) and the batched decode's Aug-head (rows grouped by
-tenant slot): a group's matrix is read once per tile, and groups no row
-chose are never read.
+* the rows step's morph and Aug-Conv (``ops.morph_rows_grouped``, kernel
+  ``mole_morph_grouped``, and ``ops.aug_conv_forward_grouped``,
+  ``mole_aug_conv_grouped``): each group of a microbatch is whole row
+  tiles already, and its padding groups are the unused tiles, so their
+  slots are not read;
+* the dropless MoE (tokens grouped by expert, ``mole_expert_gmm``) and the
+  batched decode's Aug-head (rows grouped by tenant slot,
+  ``mole_lm_head_grouped``).
 
 The contraction axis stays innermost ("arbitrary"), accumulated in an fp32
-VMEM scratch; the group axis is "arbitrary" too because its block mapping
-depends on the prefetched scalars.
+VMEM scratch; the tile axis is "arbitrary" too because its block mapping
+depends on the prefetched scalars.  A tile re-streams its group's matrix
+even where the previous tile had the same group.
 """
 from __future__ import annotations
 
@@ -43,79 +38,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .aug_gemm import mxu_dot
 
-__all__ = [
-    "grouped_aug_gemm",
-    "tiled_group_gemm",
-]
-
-
-def _aug_kernel(gidx_ref, t_ref, c_ref, o_ref, acc_ref, *, n_kk: int):
-    del gidx_ref
-    kk = pl.program_id(3)
-
-    @pl.when(kk == 0)
-    def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    acc_ref[...] += mxu_dot(t_ref[0], c_ref[0])
-
-    @pl.when(kk == n_kk - 1)
-    def _():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
-
-
-def grouped_aug_gemm(
-    t: jax.Array,        # (G, B, K) morphed rows
-    gidx: jax.Array,     # (G,) int32 slot index per group
-    c_acs: jax.Array,    # (S, K, N) stacked per-slot Aug-Conv matrices
-    *,
-    bm: int = 128,
-    bn: int = 128,
-    bk: int = 512,
-    interpret: bool,
-    name: str | None = None,
-) -> jax.Array:
-    """Per-group Aug-Conv forward ``t[g] @ c_acs[gidx[g]]``, secrets in place.
-
-    The grouped twin of ``aug_gemm.aug_gemm`` — this is the GEMM whose
-    ``(G, K, N)`` weight gather dominated the non-identity delivery path.
-    ``name`` names the kernel, and with it the operation in a device trace,
-    whatever jitted wrapper calls it.
-    """
-    G, B, K = t.shape
-    N = c_acs.shape[-1]
-    assert c_acs.shape[1] == K, (t.shape, c_acs.shape)
-    bm, bn, bk = min(bm, B), min(bn, N), min(bk, K)
-    assert B % bm == 0 and N % bn == 0 and K % bk == 0, (B, bm, N, bn, K, bk)
-    n_kk = K // bk
-
-    grid = (G, B // bm, N // bn, n_kk)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(
-                (1, bm, bk), lambda g, i, j, kk, gidx_ref: (g, i, kk)
-            ),
-            pl.BlockSpec(
-                (1, bk, bn), lambda g, i, j, kk, gidx_ref: (gidx_ref[g], kk, j)
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, bm, bn), lambda g, i, j, kk, gidx_ref: (g, i, j)
-        ),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_aug_kernel, n_kk=n_kk),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((G, B, N), t.dtype),
-        interpret=interpret,
-        name=name,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "parallel", "parallel", "arbitrary")
-        ),
-    )(gidx, t, c_acs)
+__all__ = ["tiled_group_gemm"]
 
 
 def _tiled_kernel(tg_ref, nu_ref, x_ref, w_ref, o_ref, acc_ref, *, n_kk: int):

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from . import ref
 from .aug_gemm import aug_gemm
 from .dispatch import pallas_interpret, resolve_backend
-from .grouped import grouped_aug_gemm, tiled_group_gemm
+from .grouped import tiled_group_gemm
 
 __all__ = [
     "morph_rows",
@@ -142,7 +142,7 @@ def _safe_gidx(gidx: jax.Array, n_slots: int) -> jax.Array:
 
 def morph_rows_grouped(
     x: jax.Array, gidx: jax.Array, cores: jax.Array, kappa: int,
-    backend: str | None = None,
+    backend: str | None = None, n_real: jax.Array | None = None,
 ) -> jax.Array:
     """Slot-indexed morphing: x (G, B, kappa*q), gidx (G,), cores (S, q, q).
 
@@ -151,56 +151,89 @@ def morph_rows_grouped(
     prefetched index_map DMAs slot ``gidx[g]``'s core tile directly, and the
     jnp reference dynamic-slices one core per ``lax.scan`` step — so no
     ``(G, q, q)`` copy is ever materialized, for *any* index vector
-    (out-of-order, duplicate, partial-table, or the identity).
+    (out-of-order, duplicate, partial-table, or the identity).  ``n_real``
+    (1,) int32, if given, counts the live groups: the groups past it are
+    padding, their output is zeros, and the Pallas backends do not read
+    their slots.
     """
     return _morph_rows_grouped(
-        x, gidx, cores, int(kappa), resolve_backend(backend)
+        x, gidx, cores, int(kappa), resolve_backend(backend), n_real
     )
 
 
 @partial(jax.jit, static_argnames=("kappa", "backend"))
-def _morph_rows_grouped(x, gidx, cores, kappa, backend):
+def _morph_rows_grouped(x, gidx, cores, kappa, backend, n_real=None):
     gidx = _safe_gidx(gidx, cores.shape[0])
     if backend != "jnp":
         G, q = x.shape[0], cores.shape[-1]
-        return _grouped_gemm(
-            x.reshape(G, -1, q), gidx, cores, backend, "mole_morph_grouped"
+        return _live_groups_gemm(
+            x.reshape(G, -1, q), gidx, n_real, cores, backend,
+            "mole_morph_grouped",
         ).reshape(x.shape)
     x, B, _ = _pad_rows(x, 1)
-    return ref.block_diag_matmul_grouped_ref(x, gidx, cores, kappa)[:, :B]
+    out = ref.block_diag_matmul_grouped_ref(x, gidx, cores, kappa)[:, :B]
+    return _zero_padding_groups(out, n_real)
 
 
 def aug_conv_forward_grouped(
     t: jax.Array, gidx: jax.Array, c_acs: jax.Array,
-    backend: str | None = None,
+    backend: str | None = None, n_real: jax.Array | None = None,
 ) -> jax.Array:
     """Slot-indexed Aug-Conv forward: t (G, B, K), gidx (G,), c_acs (S, K, N).
 
     This is the GEMM whose per-microbatch ``(G, K, N)`` weight gather was
     the non-identity delivery cost (ROADMAP: 0.8x vs 4.9x at 16 tenants);
-    here the slot table is read in place on every backend.
+    here the slot table is read in place on every backend.  ``n_real`` as
+    in :func:`morph_rows_grouped`: a padding group reads no slot.
     """
-    return _aug_conv_forward_grouped(t, gidx, c_acs, resolve_backend(backend))
+    return _aug_conv_forward_grouped(
+        t, gidx, c_acs, resolve_backend(backend), n_real
+    )
 
 
 @partial(jax.jit, static_argnames=("backend",))
-def _aug_conv_forward_grouped(t, gidx, c_acs, backend):
+def _aug_conv_forward_grouped(t, gidx, c_acs, backend, n_real=None):
     gidx = _safe_gidx(gidx, c_acs.shape[0])
     if backend != "jnp":
-        return _grouped_gemm(t, gidx, c_acs, backend, "mole_aug_conv_grouped")
+        return _live_groups_gemm(
+            t, gidx, n_real, c_acs, backend, "mole_aug_conv_grouped"
+        )
     t, B, _ = _pad_rows(t, 1)
-    return ref.aug_gemm_grouped_ref(t, gidx, c_acs)[:, :B]
+    out = ref.aug_gemm_grouped_ref(t, gidx, c_acs)[:, :B]
+    return _zero_padding_groups(out, n_real)
 
 
-def _grouped_gemm(t, gidx, ws, backend, name):
-    """``t[g] (B, K) @ ws[gidx[g]] (K, N)`` through the grouped Pallas
-    kernel named ``name``, the stacked weights read in place."""
-    bn, bk = _gemm_tiles(*ws.shape[1:])
+def _zero_padding_groups(out, n_real):
+    """``out`` (G, ...) with the groups from ``n_real[0]`` on set to zero."""
+    if n_real is None:
+        return out
+    live = jnp.arange(out.shape[0]) < n_real[0]
+    return jnp.where(live.reshape((-1,) + (1,) * (out.ndim - 1)), out, 0)
+
+
+def _live_groups_gemm(t, gidx, n_real, ws, backend, name):
+    """``t[g] (B, K) @ ws[gidx[g]] (K, N)`` for the live groups, through
+    the tiled kernel named ``name``, the stacked weights read in place.
+
+    The microbatch is already a tile layout: each group's rows, padded to
+    the row tile, are whole ``bm``-row tiles, so ``(G, B, K)`` is read as
+    ``(G * B, K)`` with no copy.  The groups from ``n_real[0]`` on (all
+    live when None) are padding: their tiles are past the used count, so
+    their output is zeros, and they name the last live group's slot, so
+    the kernel streams no matrix for them.
+    """
+    G = t.shape[0]
     t, B, bm = _pad_rows(t, 1)
-    return grouped_aug_gemm(
-        t, gidx, ws, bm=bm, bn=bn, bk=bk, interpret=pallas_interpret(backend),
-        name=name,
-    )[:, :B]
+    per = t.shape[1] // bm                      # row tiles a group
+    if n_real is None:
+        n_real = jnp.full((1,), G, jnp.int32)
+    n_real = n_real.astype(jnp.int32)
+    last = gidx[jnp.clip(n_real[0] - 1, 0, G - 1)]
+    slots = jnp.where(jnp.arange(G) < n_real[0], gidx, last)
+    tiles = {"tile_group": jnp.repeat(slots, per), "n_used": n_real * per}
+    out = _tiled_products(t.reshape(G * t.shape[1], -1), tiles, ws, bm,
+                          backend, name)
+    return out.reshape(G, t.shape[1], -1)[:, :B]
 
 
 def token_morph_grouped(

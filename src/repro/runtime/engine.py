@@ -142,10 +142,14 @@ class EngineStats:
     rejected: int = 0           # requests refused by admission control
     blocked: int = 0            # submits that waited on quota backpressure
     # Padding groups whose slot index hit the clamp bound during coalescing:
-    # such a group reads the last slot's secrets for all-zero rows (harmless,
-    # sliced away); the count shows how often the group bucket outgrew the
+    # such a group names the last slot (the rows step reads no padding
+    # group's slot); the count shows how often the group bucket outgrew the
     # slot table.
     padding_clamp_count: int = 0
+    # Padding groups of the rows steps (vision and features lanes): the
+    # step is told the live group count, and on the Pallas backends it does
+    # not read a padding group's slot.
+    padding_groups_skipped: int = 0
     # Resilience counters: flushes whose device phase the straggler monitor
     # flagged as slow, flush rounds that failed all their waiters, engine
     # snapshots taken, and restores performed.
@@ -338,7 +342,8 @@ class EngineStats:
             f"microbatches={self.microbatches} flushes={self.flushes} "
             f"rounds_overlapped={self.rounds_overlapped} "
             f"padding={self.padding_fraction:.0%} "
-            f"padding_clamps={self.padding_clamp_count}",
+            f"padding_clamps={self.padding_clamp_count} "
+            f"padding_groups_skipped={self.padding_groups_skipped}",
             f"completion latency: p50={_fmt_ms(self.p50_ms)} "
             f"p95={_fmt_ms(self.p95_ms)}",
         ]
@@ -941,10 +946,13 @@ class MoLeDeliveryEngine:
         return rid
 
     # -- the jitted hot paths ------------------------------------------------
-    def _execute(self, x: np.ndarray, gidx: np.ndarray,
-                 plan: _Plan) -> jax.Array:
+    def _execute(self, x: np.ndarray, gidx: np.ndarray, plan: _Plan,
+                 n_real: int | None = None) -> jax.Array:
+        """The rows step over ``x`` (G, B, F): the groups from index
+        ``n_real`` on (None: none) are padding, and their slots are not
+        read."""
         return _delivery_step(
-            jnp.asarray(x), jnp.asarray(gidx),
+            jnp.asarray(x), jnp.asarray(gidx), _live_count(x, n_real),
             plan.arrays["cores"], plan.arrays["augs"],
             self.registry.kappa, self.backend,
         )
@@ -959,11 +967,11 @@ class MoLeDeliveryEngine:
         )
 
     def _execute_features(self, x: np.ndarray, gidx: np.ndarray,
-                          plan: _Plan) -> jax.Array:
+                          plan: _Plan, n_real: int | None = None) -> jax.Array:
         # The continuous LM lane *is* the vision math (m^2 -> 1): same jitted
         # step, with the registry's embedding cores / fused projections.
         return _delivery_step(
-            jnp.asarray(x), jnp.asarray(gidx),
+            jnp.asarray(x), jnp.asarray(gidx), _live_count(x, n_real),
             plan.arrays["embed_cores"], plan.arrays["aug_projs"],
             self.lm_registry.kappa, self.backend,
         )
@@ -1041,6 +1049,9 @@ class MoLeDeliveryEngine:
                     continue
                 self._note_microbatch(mb)
                 clamped += mb.n_clamped_padding
+                if lane != "tokens":
+                    self.stats.padding_groups_skipped += (
+                        mb.x.shape[0] - mb.n_real_groups)
                 # One token microbatch may mix "tokens" and "embed"
                 # requests; the Aug-Embedding gather runs only when someone
                 # asked for features (a static flag — at most two traces
@@ -1119,12 +1130,14 @@ class MoLeDeliveryEngine:
         """Start one work item's jitted step; returns its device output."""
         mb = item.mb
         if item.lane == "vision":
-            return self._execute(mb.x, mb.group_tenant, item.plan)
+            return self._execute(mb.x, mb.group_tenant, item.plan,
+                                 mb.n_real_groups)
         if item.lane == "tokens":
             return self._execute_tokens(
                 mb.x, mb.group_tenant, item.want_embed, item.plan
             )
-        return self._execute_features(mb.x, mb.group_tenant, item.plan)
+        return self._execute_features(mb.x, mb.group_tenant, item.plan,
+                                      mb.n_real_groups)
 
     # analysis: forbids-lock(_cv)
     def execute_flush(self, work: _FlushWork) -> None:
@@ -1580,12 +1593,21 @@ class MoLeDeliveryEngine:
         return pending
 
 
+def _live_count(x: np.ndarray, n_real: int | None) -> jax.Array:
+    """The rows step's live group count as a (1,) int32 array: an argument
+    of the step, not a static value, so one trace serves every count."""
+    n = x.shape[0] if n_real is None else n_real
+    return jnp.asarray(np.array([n], np.int32))
+
+
 # analysis: forbids-lock(_cv)
 @partial(jax.jit, static_argnames=("kappa", "backend"))
-def _delivery_step(x, gidx, cores, augs, kappa: int, backend: str):
+def _delivery_step(x, gidx, n_real, cores, augs, kappa: int, backend: str):
     """morph + Aug forward for one padded microbatch, single compiled graph.
 
-    x: (G, B, F_in); gidx: (G,); cores: (S, q, q); augs: (S, F_in, F_out).
+    x: (G, B, F_in); gidx: (G,); n_real: (1,) int32, the live groups (the
+    rest are padding: zeros out, their slots not read); cores: (S, q, q);
+    augs: (S, F_in, F_out).
     Serves both the vision rows lane (Aug-Conv) and the continuous LM lane
     (fused input projections) — the same math, per the paper's m^2 -> 1
     reduction.  The group axis is the natural data-parallel shard axis
@@ -1598,9 +1620,11 @@ def _delivery_step(x, gidx, cores, augs, kappa: int, backend: str):
     """
     _TRACES[(x.shape, gidx.shape, cores.shape, kappa, backend)] += 1
     x = hint(x, "dp")
-    morphed = morph_rows_grouped(x, gidx, cores, kappa, backend=backend)
+    morphed = morph_rows_grouped(x, gidx, cores, kappa, backend=backend,
+                                 n_real=n_real)
     morphed = hint(morphed, "dp")
-    feats = aug_conv_forward_grouped(morphed, gidx, augs, backend=backend)
+    feats = aug_conv_forward_grouped(morphed, gidx, augs, backend=backend,
+                                     n_real=n_real)
     return hint(feats, "dp")
 
 

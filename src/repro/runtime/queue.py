@@ -677,9 +677,10 @@ class RequestQueue:
         if not chunks:
             return None
 
-        # Slot-sorted coalescing: order groups by their registry slot so the
-        # grouped kernels see monotone indices (adjacent groups sharing a
-        # slot reuse the resident secret tile).
+        # Slot-sorted coalescing: groups in registry-slot order, a tenant's
+        # chunks in WFQ order within its slot.  Adjacent groups that share a
+        # slot gain nothing from it: the grouped kernel streams a group's
+        # whole matrix for each of its row tiles.
         chunks.sort(key=lambda c: slot_of[c[0]])  # stable: WFQ order in a slot
 
         largest = max(sum(n for _, _, n in runs) for _, runs in chunks)
@@ -700,11 +701,11 @@ class RequestQueue:
                 n_real_rows += n
         # Padding groups carry their own group index, clamped to the slot
         # table bound (max_groups == registry capacity in engine use), so
-        # every index is in range and a dense prefix of active slots plus
-        # padding stays monotone.  All-zero rows make their output zeros
-        # regardless of whose secrets they hit.  Clamps are counted so the
-        # engine can surface them (padding_clamp_count): a clamped group
-        # reads the last slot's secrets for zero rows.
+        # every index is in range.  The engine passes n_real_groups to the
+        # rows step, which reads no padding group's slot and returns zeros
+        # for it.  Clamps are counted so the engine can surface them
+        # (padding_clamp_count): they show the group bucket outgrew the
+        # slot table.
         pad = np.arange(len(chunks), G, dtype=np.int32)
         gidx[len(chunks):] = np.minimum(pad, max_groups - 1)
         n_clamped = int(np.count_nonzero(pad > max_groups - 1))

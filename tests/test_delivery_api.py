@@ -403,6 +403,29 @@ def test_per_priority_latency_quantiles(rng):
     assert nan != nan
 
 
+def test_padding_groups_skipped_counts_the_padding_groups(rng):
+    """A microbatch of 3 of 4 tenants buckets to G = 4: the rows step is
+    told one group is padding, and the engine counts it next to the
+    clamps."""
+    reg = _registry(rng, tenants=4, capacity=4)
+    eng = MoLeDeliveryEngine(reg, group_buckets=(1, 2, 4))
+    datas = {t: _data(rng, 1) for t in reg.tenant_ids[:3]}
+    for t, d in datas.items():
+        eng.submit(DeliveryRequest(t, d))
+    work = eng.begin_flush()
+    (item,) = work.items
+    assert (item.mb.x.shape[0], item.mb.n_real_groups) == (4, 3)
+    assert eng.stats.padding_groups_skipped == 1
+    eng.execute_flush(work)
+    done = eng.publish_flush(work)
+    assert "padding_clamps=0 padding_groups_skipped=1" in eng.stats.summary()
+    for s in item.mb.slices:
+        tenant = reg.tenant_ids[int(item.mb.group_tenant[s.group])]
+        want = np.asarray(reg.session(tenant).deliver(
+            jnp.asarray(datas[tenant])))
+        np.testing.assert_allclose(done[s.request_id], want, atol=1e-5)
+
+
 def test_padding_clamp_count_surfaces_in_stats(rng):
     """Coalescing a G bucket past max_groups clamps padding indices — the
     engine must count it (padding_clamp_count) instead of staying silent."""

@@ -240,6 +240,27 @@ def test_registration_into_free_slot_does_not_retrace(rng):
     assert delivery_trace_count() == n0
 
 
+def test_live_group_count_does_not_retrace(rng):
+    """The rows step takes the live group count as an array: one (G, B)
+    shape traces once whatever the count, and the groups past the count
+    come back zero."""
+    reg = _registry(rng, tenants=4, kappa=2, capacity=4)
+    eng = MoLeDeliveryEngine(reg)
+    plan = eng._refresh_plan()
+    G, B = 4, 3
+    x = rng.standard_normal((G, B, GEOM.in_features)).astype(np.float32)
+    gidx = np.arange(G, dtype=np.int32)
+    n0 = delivery_trace_count()
+    full = np.asarray(eng._execute(x, gidx, plan))
+    n1 = delivery_trace_count()
+    assert n1 - n0 <= 1
+    for n in (1, 2, 3, 4):
+        got = np.asarray(eng._execute(x, gidx, plan, n))
+        np.testing.assert_array_equal(got[:n], full[:n])
+        np.testing.assert_array_equal(got[n:], 0.0)
+    assert delivery_trace_count() == n1
+
+
 def test_eviction_churn_traces_at_most_once_per_bucket(rng):
     """Register/evict/re-activate through a full registry: _delivery_step is
     traced at most once per (bucket, kappa) shape over the whole churn."""

@@ -4,7 +4,8 @@ index-vector shape the delivery engine can produce — identity, partial table
 (T < capacity), out-of-order, duplicate slots — on both backend legs (jnp
 reference and Pallas interpret), plus row padding and the refusal of
 whole-axis blocks for widths off the 128-lane tile on the Pallas backends,
-and the padding-index clamp."""
+the padding-index clamp, and padding groups past the live count, which
+read no slot."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,7 +18,7 @@ from repro.kernels import (
     ref,
     token_morph_grouped,
 )
-from repro.kernels.grouped import grouped_aug_gemm
+from repro.kernels.grouped import tiled_group_gemm
 
 BACKENDS = ("jnp", "interpret")
 
@@ -125,10 +126,25 @@ def test_grouped_clamps_out_of_range_padding_index(rng, backend):
     assert np.all(got[1] == 0.0)
 
 
+def _rows_tiled(t, gidx, w, bm, bn, bk):
+    """The rows lane's layout on the tiled kernel: ``t`` (G, R, K), each
+    group ``R // bm`` whole row tiles, read as (G * R, K) and multiplied by
+    its slot's matrix."""
+    G, R, K = t.shape
+    per = R // bm
+    out = tiled_group_gemm(
+        t.reshape(G * R, K), jnp.repeat(gidx, per),
+        jnp.array([G * per], jnp.int32), w, bm=bm, bn=bn, bk=bk,
+        interpret=True, name="mole_test_rows",
+    )
+    return out.reshape(G, R, -1)
+
+
 @pytest.mark.parametrize("name", sorted(GIDX_CASES))
 def test_grouped_pallas_kernels_match_ref_directly(rng, name):
-    """The raw Pallas kernels (scalar-prefetched index maps, interpret mode)
-    against the scan reference — no dispatch layer in between."""
+    """The raw Pallas kernel (scalar-prefetched index maps, interpret mode)
+    in the rows lane's layout, several row, contraction and output blocks
+    a group, against the scan reference — no dispatch layer in between."""
     gidx_np = np.array(GIDX_CASES[name], np.int32)
     G, S = len(gidx_np), 6
     gidx = jnp.asarray(gidx_np)
@@ -139,10 +155,8 @@ def test_grouped_pallas_kernels_match_ref_directly(rng, name):
     cores = jnp.asarray(
         (rng.standard_normal((S, q, q)) / np.sqrt(q)).astype(np.float32)
     )
-    got = grouped_aug_gemm(
-        x.reshape(G, B * kappa, q), gidx, cores, bm=8, bn=64, bk=64,
-        interpret=True,
-    ).reshape(G, B, kappa * q)
+    got = _rows_tiled(x.reshape(G, B * kappa, q), gidx, cores,
+                      bm=8, bn=64, bk=64).reshape(G, B, kappa * q)
     want = ref.block_diag_matmul_grouped_ref(x, gidx, cores, kappa)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
 
@@ -151,9 +165,41 @@ def test_grouped_pallas_kernels_match_ref_directly(rng, name):
     c_acs = jnp.asarray(
         (rng.standard_normal((S, K, N)) / 16).astype(np.float32)
     )
-    got = grouped_aug_gemm(t, gidx, c_acs, bm=8, bn=64, bk=128, interpret=True)
+    got = _rows_tiled(t, gidx, c_acs, bm=8, bn=64, bk=128)
     want = ref.aug_gemm_grouped_ref(t, gidx, c_acs)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_padding_groups_read_no_slot(rng, backend):
+    """Groups past ``n_real`` are padding: their output is zeros and their
+    slots are not multiplied.  The slots only the padding groups name hold
+    NaN, and the padding groups' rows are not even zero: the result is
+    finite, the live groups equal the gather oracle, the padding zero."""
+    G, B, kappa, q, K, N, S, n_real = 4, 5, 2, 128, 256, 128, 6, 2
+    gidx = jnp.asarray(np.array([1, 3, 5, 0], np.int32))  # pads: 5, 0
+    cores = rng.standard_normal((S, q, q)).astype(np.float32) / np.sqrt(q)
+    c_acs = rng.standard_normal((S, K, N)).astype(np.float32) / 16
+    cores[[5, 0]] = np.nan
+    c_acs[[5, 0]] = np.nan
+    cores, c_acs = jnp.asarray(cores), jnp.asarray(c_acs)
+    x = jnp.asarray(rng.standard_normal((G, B, kappa * q)).astype(np.float32))
+    t = jnp.asarray(rng.standard_normal((G, B, K)).astype(np.float32))
+    live = jnp.array([n_real], jnp.int32)
+    cases = [
+        (morph_rows_grouped(x, gidx, cores, kappa, backend=backend,
+                            n_real=live),
+         ref.block_diag_matmul_batched_ref(x[:n_real], cores[gidx[:n_real]],
+                                           kappa)),
+        (aug_conv_forward_grouped(t, gidx, c_acs, backend=backend,
+                                  n_real=live),
+         ref.aug_gemm_batched_ref(t[:n_real], c_acs[gidx[:n_real]])),
+    ]
+    for got, want in cases:
+        got = np.asarray(got)
+        assert np.all(np.isfinite(got))
+        np.testing.assert_allclose(got[:n_real], np.asarray(want), atol=1e-4)
+        np.testing.assert_array_equal(got[n_real:], 0.0)
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
@@ -187,7 +233,6 @@ def test_tiled_group_gemm_serves_rows_grouped_into_padded_tiles(rng, groups):
     its group's matrix, tiles past the used count zero, every row equal to
     its own group's product."""
     from repro.kernels.ops import group_tiles, tile_count
-    from repro.kernels.grouped import tiled_group_gemm
 
     g = jnp.asarray(groups, jnp.int32)
     x = jnp.asarray(rng.standard_normal((g.shape[0], 256)), jnp.float32)

@@ -55,6 +55,7 @@ def _compile_step(sharding, geom, kappa, rows):
     return _delivery_step.lower(
         _spec((GROUPS, rows, f_in), f32, sharding),
         _spec((GROUPS,), jnp.int32, sharding),
+        _spec((1,), jnp.int32, sharding),
         _spec((SLOTS, q, q), f32, sharding),
         _spec((SLOTS, f_in, f_out), f32, sharding),
         kappa=kappa, backend="pallas",
